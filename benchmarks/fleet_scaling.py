@@ -13,10 +13,13 @@ stacked rack planes) evaluated three ways:
     ``EpochAnalyzer.analyze_batch`` dispatch per rack per fraction (K host
     round-trips), the way K independent sessions would price their racks.
 
-All paths are warmed before timing (compile excluded).  Virtual devices
-share this machine's physical cores, so the sharded win is real scheduling
-and cache-locality headroom, not extra silicon; the record includes the
-physical core count so readers can calibrate.
+All paths are warmed before timing (compile excluded).  The script runs on
+whatever devices JAX finds and records them; for the CPU smoke, set
+``JAX_PLATFORMS=cpu`` and
+``XLA_FLAGS=--xla_force_host_platform_device_count=8`` in the environment.
+Virtual CPU devices share the machine's physical cores, so a sharded win
+there is scheduling and cache-locality headroom, not extra silicon; the
+record includes the physical core count so readers can calibrate.
 
 The capacity-planning output — the paper's stranding question at rack
 scale — is the frontier curve: stranded GB recovered (bytes the hosts no
@@ -42,11 +45,6 @@ import os
 import platform
 import sys
 import time
-
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-_FLAG = "--xla_force_host_platform_device_count=8"
-if _FLAG not in os.environ.get("XLA_FLAGS", ""):
-    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") + " " + _FLAG).strip()
 
 import numpy as np
 
@@ -201,6 +199,8 @@ def main(argv=None) -> int:
         "python": sys.version.split()[0],
         "physical_cores": os.cpu_count(),
         "jax_devices": n_dev,
+        "device": jax.devices()[0].platform,
+        "device_kind": jax.devices()[0].device_kind,
         "racks": R,
         "hosts_per_rack": HOSTS_PER_RACK,
         "n_hosts": n_hosts,

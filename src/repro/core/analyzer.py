@@ -50,13 +50,14 @@ Choosing ``impl``:
 
   * ``'inline'`` — fused cascade as pure XLA ops; fastest on CPU/GPU, the
     default, and the recommended production path everywhere.
-  * ``'pallas'`` — the fused multi-stage TPU kernel (one kernel launch per
-    epoch cascade).  Its scan phase follows the proven single-switch kernel,
-    but the inter-stage merge uses in-kernel gather/scatter that has only
-    been validated in interpret mode (this container has no TPU); treat the
-    compiled path as experimental until exercised on TPU hardware.
+  * ``'pallas'`` — the cascade's stage scan as a compiled TPU kernel (one
+    launch per switch stage; the inter-stage merges stay in XLA, shared
+    with ``'inline'``).  It compiles for a described v5e
+    (``tests/test_tpu_compile.py``) and matches ``'inline'`` on a v5e chip
+    (``chip_smoke.py``); which of the two is faster is not measured yet.
+    Single-epoch and ``analyze_batch`` dispatches only.
   * ``'pallas_interpret'`` — same kernel body via the Pallas interpreter;
-    slow, used by tests/benchmarks to validate the kernel on CPU.
+    slow, used by tests to validate the kernel on CPU.
   * ``'ref'`` (``analyze_ref``) — numpy float64; the oracle, not jitted.
 
 ``fused=False`` preserves the pre-fusion per-switch argsort loop; it exists
@@ -79,6 +80,10 @@ from ..analysis.annotations import axes
 from .aot import AotDispatchCache
 from .events import EventStager, MemEvents
 from .topology import FlatTopology
+
+# f32 contractions at full precision: a TPU's default f32 dot rounds its
+# operands to bf16 (2^-9 relative), far outside the oracle tolerances
+_HI = jax.lax.Precision.HIGHEST
 
 __all__ = [
     "ChainPlan",
@@ -622,7 +627,7 @@ def _analyze_pipeline_jax(
         pool_onehot = (
             pool1[:, None] == jnp.arange(V, dtype=pool1.dtype)
         ).astype(f32)
-        per_pool_lat = jnp.einsum("n,np->p", per_event_lat, pool_onehot)
+        per_pool_lat = jnp.einsum("n,np->p", per_event_lat, pool_onehot, precision=_HI)
         latency = per_event_lat.sum()
 
         # congestion: compact suffix cascade (merge + scan fused)
@@ -646,7 +651,7 @@ def _analyze_pipeline_jax(
         wp = jax.ops.segment_sum(
             jnp.where(real, nbytes_e, 0.0), key, num_segments=n_windows * V
         ).reshape(n_windows, V)
-        wbytes = wp @ route  # [n_windows, S]
+        wbytes = jnp.matmul(wp, route, precision=_HI)  # [n_windows, S]
         bw_safe = jnp.where(switch_bw > 0, switch_bw, 1.0)
         stretch = jnp.maximum(wbytes / bw_safe[None, :] - bww1, 0.0)
         stretch = jnp.where(switch_bw[None, :] > 0, stretch, 0.0)
@@ -742,7 +747,7 @@ def _analyze_jax(
         # one-hot contraction: XLA CPU scatter-add (segment_sum) costs ~10x
         # more than an [N, P] einsum at pool counts this small
         pool_onehot = (pool[:, None] == jnp.arange(P, dtype=pool.dtype)).astype(f32)
-        per_pool_lat = jnp.einsum("n,np->p", per_event_lat, pool_onehot)
+        per_pool_lat = jnp.einsum("n,np->p", per_event_lat, pool_onehot, precision=_HI)
     else:
         per_pool_lat = jax.ops.segment_sum(per_event_lat, pool, num_segments=P)
     latency = per_event_lat.sum()
@@ -750,7 +755,7 @@ def _analyze_jax(
         per_host_lat = latency[None]
     else:
         host_onehot = (host[:, None] == jnp.arange(n_hosts, dtype=host.dtype)).astype(f32)
-        per_host_lat = jnp.einsum("n,nh->h", per_event_lat, host_onehot)
+        per_host_lat = jnp.einsum("n,nh->h", per_event_lat, host_onehot, precision=_HI)
 
     big = jnp.asarray(jnp.finfo(f32).max / 4, f32)
     t_cur = jnp.where(valid, t, big)
@@ -809,11 +814,7 @@ def _analyze_jax(
                 per_host_cong = psd.sum(axis=0)
                 congestion = per_switch_cong.sum()
             per_class_cong = congestion[None]
-            # the Pallas kernel always runs the conservative merge schedule, so
-            # its slot order never matches input order
-            has_merges = impl != "inline" or merge_plan is None or any(
-                len(ops) for ops in merge_plan
-            )
+            has_merges = merge_plan is None or any(len(ops) for ops in merge_plan)
         if has_merges:
             # bandwidth runs in final slot order; gather payloads through
             # the cascade's permutation (slot k held input event slot_idx[k])
@@ -833,12 +834,14 @@ def _analyze_jax(
             jnp.where(valid_e, nbytes_e, 0.0), key, num_segments=n_windows * V
         ).reshape(n_windows, V)
         if n_hosts == 1:
-            wbytes = wp @ route  # [W, S]
+            wbytes = jnp.matmul(wp, route, precision=_HI)  # [W, S]
             wbytes_h = None
         else:
             wph = wp.reshape(n_windows, n_hosts, P)
             route_h = route.reshape(n_hosts, P, S)
-            wbytes_h = jnp.einsum("whp,hps->whs", wph, route_h)  # [W, H, S]
+            wbytes_h = jnp.einsum(
+                "whp,hps->whs", wph, route_h, precision=_HI,
+            )  # [W, H, S]
             wbytes = wbytes_h.sum(axis=1)
     else:
         # -- congestion: legacy per-stage argsort loop (seed baseline) ------ #
@@ -899,7 +902,7 @@ def _analyze_jax(
     else:
         # window stretch attributed to hosts by their byte share in the window
         denom = jnp.maximum(wbytes, jnp.asarray(1e-30, f32))
-        per_host_bw = jnp.einsum("ws,whs->h", stretch / denom, wbytes_h)
+        per_host_bw = jnp.einsum("ws,whs->h", stretch / denom, wbytes_h, precision=_HI)
 
     return (
         latency, congestion, bandwidth,
@@ -1210,12 +1213,16 @@ def _analyze_sweep_jax(
         per_event_lat = jnp.where(valid_e, per_event_lat, 0.0)
         latency = per_event_lat.sum()
         pool_onehot = (pool_e[:, :, None] == jnp.arange(P, dtype=pool_e.dtype)).astype(f32)
-        per_pool_lat = jnp.einsum("bn,bnp->p", per_event_lat, pool_onehot)
+        per_pool_lat = jnp.einsum(
+            "bn,bnp->p", per_event_lat, pool_onehot, precision=_HI,
+        )
         if n_hosts == 1:
             per_host_lat = latency[None]
         else:
             host_onehot = (host_e[:, :, None] == jnp.arange(n_hosts, dtype=host_e.dtype)).astype(f32)
-            per_host_lat = jnp.einsum("bn,bnh->h", per_event_lat, host_onehot)
+            per_host_lat = jnp.einsum(
+                "bn,bnh->h", per_event_lat, host_onehot, precision=_HI,
+            )
 
         # congestion: shared with every scenario of the same cascade
         psd = psd_u[u]  # [B, Sst] | [B, Sst, H] | [B, Sst, H, C] (qos_on)
@@ -1255,12 +1262,12 @@ def _analyze_sweep_jax(
             num_segments=B * n_windows * V,
         ).reshape(B, n_windows, V)
         if n_hosts == 1:
-            wbytes = wp @ route  # [B, W, S]
+            wbytes = jnp.matmul(wp, route, precision=_HI)  # [B, W, S]
             wbytes_h = None
         else:
             wph = wp.reshape(B, n_windows, n_hosts, P)
             route_h = route.reshape(n_hosts, P, S)
-            wbytes_h = jnp.einsum("bwhp,hps->bwhs", wph, route_h)
+            wbytes_h = jnp.einsum("bwhp,hps->bwhs", wph, route_h, precision=_HI)
             wbytes = wbytes_h.sum(axis=2)
         # bw <= 0 means an unconstrained component (analyze_ref skips it);
         # unguarded 0/0 windows would poison totals with NaN
@@ -1275,7 +1282,9 @@ def _analyze_sweep_jax(
             per_host_bw = bandwidth[None]
         else:
             denom = jnp.maximum(wbytes, jnp.asarray(1e-30, f32))
-            per_host_bw = jnp.einsum("bws,bwhs->h", stretch / denom, wbytes_h)
+            per_host_bw = jnp.einsum(
+                "bws,bwhs->h", stretch / denom, wbytes_h, precision=_HI,
+            )
 
         return (
             latency, congestion, bandwidth,

@@ -29,13 +29,17 @@ compilation cache so executables survive process restarts.
 
 from __future__ import annotations
 
+import os
 import threading
 import weakref
+from pathlib import Path
 from typing import Any, Callable, Dict, Hashable, Tuple
 
 import jax
 
 __all__ = ["AotDispatchCache", "install_persistent_cache"]
+
+_REPO_CACHE = Path(__file__).resolve().parents[3] / ".jax_cache"
 
 
 class AotDispatchCache:
@@ -94,19 +98,20 @@ class AotDispatchCache:
         return not hit
 
 
-def install_persistent_cache(path: str) -> bool:
-    """Point JAX's persistent compilation cache at ``path``.
+def install_persistent_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
 
-    Compiled modules are then written to disk and reloaded across process
-    restarts, so even the *first* dispatch of a fresh server skips XLA
-    compilation for shapes it has served before.  Returns False (instead
-    of raising) on JAX builds without the config knobs.
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX has already taken the
+    directory from it and this sets none.  Otherwise the cache goes to the
+    fixed path ``<repo>/.jax_cache`` (listed in ``.gitignore``): the path is
+    part of the cache key, so a directory that moved would never hit.
+    Compiled modules are then reloaded across process restarts, so a fresh
+    server's first dispatch skips XLA compilation for shapes it has served
+    before.
     """
-    try:
-        jax.config.update("jax_compilation_cache_dir", str(path))
-        # default thresholds skip "cheap" compiles; a serving loop wants
-        # every executable persisted
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    except (AttributeError, ValueError):
-        return False
-    return True
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", str(_REPO_CACHE))
+    # default thresholds skip "cheap" compiles; a serving loop wants every
+    # executable persisted
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return jax.config.jax_compilation_cache_dir

@@ -8,13 +8,12 @@
 """
 
 from . import ops, ref
-from .congestion import congestion_cascade, congestion_cascade_hosts, congestion_scan
+from .congestion import congestion_cascade, congestion_scan
 from .flash_attention import flash_attention
 from .ssd_scan import ssd_scan
 
 __all__ = [
     "congestion_cascade",
-    "congestion_cascade_hosts",
     "congestion_scan",
     "flash_attention",
     "ops",

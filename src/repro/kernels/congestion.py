@@ -1,4 +1,4 @@
-"""Pallas TPU kernels for the congestion serial-queue scan (paper §3, delay 2).
+"""Pallas TPU kernel for the congestion serial-queue scan (paper §3, delay 2).
 
 The Timing Analyzer's hot loop is, per switch, the FIFO queue
 ``out_i = max(arr_i, out_{i-1} + STT)`` over the time-sorted events that
@@ -7,50 +7,52 @@ traverse the switch.  The closed form
     out_i = cummax(arr_i − STT·rank_i) + STT·rank_i,   rank = cumsum(mask) − 1
 
 turns it into two prefix scans (a cumsum over the mask and a cummax over the
-shifted arrivals), which map onto the TPU VPU as log₂(B) lane-shift/max steps
+shifted arrivals), which map onto the TPU VPU as log₂(B) lane-rotate steps
 per block plus a scalar carry between sequential grid steps.
 
-Two kernels:
+One kernel body (:func:`_stage_kernel`) runs one switch stage over one
+time-sorted epoch:
 
-  * :func:`congestion_scan` — one switch's queue over a pre-sorted epoch
-    (the original single-stage kernel; kept for the legacy per-stage path).
-  * :func:`congestion_cascade` / :func:`congestion_cascade_hosts` — the
-    fused S-stage cascade: one kernel launch walks every switch stage
-    (deepest first) over the same epoch.  Both wrap the one shared body
-    (:func:`_cascade_body`); the hosts variant statically adds a host-id
-    row (permuted alongside through every merge) and per-host delay slots
-    in the SMEM stage carries — the shared-fabric decomposition — while
-    the single-host variant emits exactly the original kernel.
-    Grid is ``(S, N/B)``; the per-switch carries (running cummax ``f``,
-    masked-event rank, and the stage's delay sum) live in SMEM and are reset
-    at the first block of each stage, extending the single-switch scan's
-    carry scheme.  The full epoch's current times / route bits / slot
-    indices persist in VMEM scratch across sequential grid steps; after each
-    stage the last block restores the sorted-by-current-time invariant by
-    merging the two sorted runs (queued vs untouched events) with rank
-    arithmetic — no re-sort, so the whole cascade needs exactly one host
-    sort.  This matches ``analyze_ref``'s per-stage re-sort semantics.
+  * ``C`` masked scans per block, one per QoS read class — class ``c``'s
+    subsequence is ``lo_c <= q <= c`` with service time ``stt_c`` (strict
+    priority: ``lo_c = 0``; WFQ and FIFO: ``lo_c = c``, WFQ inflating
+    ``stt_c``); the plain FIFO queue is the ``C = 1`` instance and reads no
+    class row at all;
+  * per-segment delay sums (host, or host × class) accumulated in a
+    whole-array SMEM output, so delay attribution never leaves the kernel.
+
+Wrappers:
+
+  * :func:`congestion_scan` — one switch's FIFO queue over a pre-sorted epoch.
+  * :func:`congestion_cascade` — the S-stage cascade (optionally
+    host-segmented) and :func:`qos_congestion_cascade` — the QoS-arbitrated
+    cascade.  Each launches the stage kernel once per stage, and restores the
+    sorted-by-current-time invariant between launches in XLA, inside the
+    same jit, with exactly the merges of the inline path
+    (:func:`repro.kernels.ref.serial_queue_cascade` /
+    :func:`repro.kernels.ref.qos_cascade_dyn` drive the stage loop; only the
+    scan is swapped).  The merges are 1-D gathers and scatters, which Mosaic
+    does not lower, so they stay outside the kernel.
 
 TPU adaptation notes (vs the paper's sequential C++ loop):
-  * events live in HBM as (1, N) f32 rows; each grid step pulls a (1, B)
-    tile into VMEM (BlockSpec below), B = 2048 lanes;
-  * prefix scans are done with jnp.cumsum / lax.cummax inside the block —
-    XLA lowers them to log-depth vector ops on the 8×128 VPU;
-  * the inter-block carry is kept in an SMEM scratch, exploiting the fact
-    that the TPU grid is executed sequentially — this is the idiomatic TPU
-    replacement for the GPU-style decoupled-lookback scan;
-  * the cascade's inter-stage merge uses dynamic gather/scatter on the VMEM
-    scratch; it is validated in interpret mode (the CPU test/bench path).
-    On hosts without a TPU the production analyzer path is the fused
-    ``inline`` XLA variant (:func:`repro.kernels.ref.serial_queue_cascade`),
-    which is semantically identical;
-  * the cascade is **latency-agnostic**: it queues arrival times only.
+  * events live in HBM as (1, N) rows; each grid step pulls a (1, B) tile
+    into VMEM, B = 2048 lanes; scalars (service times, class bounds) live in
+    SMEM;
+  * Mosaic implements neither ``cumsum`` nor ``cummax``, so both prefix
+    scans are Hillis–Steele ladders of ``pltpu.roll`` lane rotations under
+    an iota mask (log₂ B steps; the rank sum is over 0/1 floats and stays
+    exact), and each block's carry comes from a reduction (``max(g)`` is
+    ``cummax(g)[-1]``) — a ``[-1]`` element read lowers to an unsupported
+    dynamic slice;
+  * the inter-block carry is kept in SMEM, exploiting the sequential TPU
+    grid — the idiomatic replacement for a GPU decoupled-lookback scan;
+  * compiled for a described v5e in ``tests/test_tpu_compile.py`` and run
+    against ``impl='inline'`` on a v5e chip by ``chip_smoke.py``;
+  * the scan is **latency-agnostic**: it queues arrival times only.
     Device-cache mode (:mod:`repro.core.cache`) reshapes the per-event
     *latency* through a per-(host, pool) scale vector applied outside the
-    kernel, in :func:`repro.core.analyzer._analyze_jax` — so this one
-    kernel body serves cache-enabled and cache-free analyses alike, and
-    hits still contend at every switch (the cache sits on the expander,
-    behind the fabric).
+    kernel, in :func:`repro.core.analyzer._analyze_jax` — so this one kernel
+    body serves cache-enabled and cache-free analyses alike.
 """
 
 from __future__ import annotations
@@ -67,9 +69,9 @@ from . import ref as _ref
 
 __all__ = [
     "congestion_cascade",
-    "congestion_cascade_hosts",
     "congestion_scan",
     "qos_congestion_cascade",
+    "stage_scan",
     "DEFAULT_BLOCK",
 ]
 
@@ -77,36 +79,126 @@ DEFAULT_BLOCK = 2048
 _NEG = -1e30  # sentinel "minus infinity" safely inside f32
 
 
-def _kernel(t_ref, m_ref, stt_ref, out_ref, delay_ref, carry_ref):
-    """One (1, B) block of the masked serial-queue scan.
+def _prefix(x, op, identity):
+    """Inclusive prefix scan of ``op`` along the lanes of a (1, B) tile."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    k = 1
+    while k < x.shape[1]:
+        x = op(x, jnp.where(lane >= k, pltpu.roll(x, k, 1), identity))
+        k *= 2
+    return x
 
-    carry_ref (SMEM, f32[2]): [0] = running max of g over prior blocks,
-                              [1] = number of masked events in prior blocks.
+
+def _stage_kernel(n_classes, n_seg, *refs):
+    """One (1, B) block of one switch stage.
+
+    Ref layout (inputs, outputs, scratch):
+      t_ref     (1, B) time-sorted arrival tile
+      m_ref     (1, B) i32 1 where the event traverses this stage
+      q_ref     (1, B) i32 read class                    [n_classes > 1 only]
+      seg_ref   (1, B) i32 delay-attribution segment     [n_seg > 1 only]
+      stt_ref   SMEM f32[C] per-class service time
+      lo_ref    SMEM i32[C] lowest class in class c's scan  [n_classes > 1 only]
+      out_ref   (1, B) start times (arrival where unmasked)
+      dsum_ref  SMEM f32[n_seg] per-segment delay sums (whole array)
+      carry_ref SMEM f32[2C]: [c] = class c's running max of g,
+                [C + c] = class c's masked events in prior blocks
     """
-    i = pl.program_id(0)
+    it = iter(refs)
+    t_ref, m_ref = next(it), next(it)
+    q_ref = next(it) if n_classes > 1 else None
+    seg_ref = next(it) if n_seg > 1 else None
+    stt_ref = next(it)
+    lo_ref = next(it) if n_classes > 1 else None
+    out_ref, dsum_ref, carry_ref = next(it), next(it), next(it)
 
-    @pl.when(i == 0)
+    @pl.when(pl.program_id(0) == 0)
     def _init():
-        carry_ref[0] = _NEG
-        carry_ref[1] = 0.0
+        for c in range(n_classes):
+            carry_ref[c] = _NEG
+            carry_ref[n_classes + c] = 0.0
+        for k in range(n_seg):
+            dsum_ref[k] = 0.0
 
-    t = t_ref[0, :]
-    m = m_ref[0, :]
-    stt = stt_ref[0]
-    mf = m.astype(t.dtype)
+    t = t_ref[...]
+    m = m_ref[...] != 0
+    start = t
+    for c in range(n_classes):
+        if q_ref is None:
+            sel = own = m
+        else:
+            q = q_ref[...]
+            sel = m & (q <= c) & (q >= lo_ref[c])
+            own = m & (q == c)
+        stt = stt_ref[c]
+        mf = sel.astype(t.dtype)
+        rank = (_prefix(mf, jnp.add, 0.0) - 1.0) + carry_ref[n_classes + c]
+        g = jnp.where(sel, t - stt * rank, _NEG)
+        f = jnp.maximum(_prefix(g, jnp.maximum, _NEG), carry_ref[c])
+        start = jnp.where(own, f + stt * rank, start)
+        carry_ref[c] = jnp.maximum(carry_ref[c], jnp.max(g))
+        carry_ref[n_classes + c] = carry_ref[n_classes + c] + jnp.sum(mf)
 
-    rank_local = jnp.cumsum(mf) - 1.0  # inclusive cumsum − 1
-    rank = rank_local + carry_ref[1]
-    g = jnp.where(m, t - stt * rank, _NEG)
-    f_local = jax.lax.cummax(g)
-    f = jnp.maximum(f_local, carry_ref[0])
-    start = jnp.where(m, f + stt * rank, t)
+    out_ref[...] = start
+    d = jnp.where(m, start - t, 0.0)
+    if seg_ref is None:
+        dsum_ref[0] = dsum_ref[0] + jnp.sum(d)
+    else:
+        seg = seg_ref[...]
+        for k in range(n_seg):
+            dsum_ref[k] = dsum_ref[k] + jnp.sum(jnp.where(seg == k, d, 0.0))
 
-    out_ref[0, :] = start
-    delay_ref[0, :] = jnp.where(m, start - t, 0.0)
 
-    carry_ref[0] = jnp.maximum(carry_ref[0], f_local[-1])
-    carry_ref[1] = carry_ref[1] + jnp.sum(mf)
+@functools.partial(jax.jit, static_argnames=("n_seg", "block", "interpret"))
+def stage_scan(
+    t: jnp.ndarray,  # [N] f32, sorted along each scanned subsequence
+    mask: jnp.ndarray,  # [N] bool, events traversing this stage
+    stt: jnp.ndarray,  # [C] f32 per-class service time
+    q: jnp.ndarray = None,  # [N] i32 read class (required when C > 1)
+    lo: jnp.ndarray = None,  # [C] i32 lowest class of each class's scan
+    seg: jnp.ndarray = None,  # [N] i32 attribution segment (n_seg > 1)
+    n_seg: int = 1,
+    block: int = DEFAULT_BLOCK,
+    interpret: bool = False,
+):
+    """One switch stage: returns ``(start[N], seg_delay[n_seg])``.
+
+    ``start`` is the queue's start time for masked events and the arrival
+    for the rest; ``seg_delay[k]`` sums the masked events' waiting over
+    segment ``k`` of ``seg`` (the whole stage when ``n_seg == 1``).
+    """
+    n = t.shape[0]
+    n_classes = int(stt.shape[0])
+    pad = -n % block
+    rows = [
+        jnp.pad(t, (0, pad), constant_values=jnp.finfo(t.dtype).max / 8),
+        jnp.pad(mask.astype(jnp.int32), (0, pad)),
+    ]
+    if n_classes > 1:
+        rows.append(jnp.pad(q.astype(jnp.int32), (0, pad)))
+    if n_seg > 1:
+        rows.append(jnp.pad(seg.astype(jnp.int32), (0, pad)))
+    npad = n + pad
+    rows = [r.reshape(1, npad) for r in rows]
+    scalars = [jnp.asarray(stt, t.dtype)]
+    if n_classes > 1:
+        scalars.append(jnp.asarray(lo, jnp.int32))
+    tile = pl.BlockSpec((1, block), lambda i: (0, i))
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    start, dsum = pl.pallas_call(
+        functools.partial(_stage_kernel, n_classes, n_seg),
+        grid=(npad // block,),
+        in_specs=[tile] * len(rows) + [smem] * len(scalars),
+        out_specs=[tile, smem],
+        out_shape=[
+            jax.ShapeDtypeStruct((1, npad), t.dtype),
+            jax.ShapeDtypeStruct((n_seg,), t.dtype),
+        ],
+        scratch_shapes=[pltpu.SMEM((2 * n_classes,), t.dtype)],
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )(*rows, *scalars)
+    return start[0, :n], dsum
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
@@ -118,427 +210,57 @@ def congestion_scan(
     interpret: bool = False,
 ):
     """Returns ``(start_times[N], delays[N])`` for one switch's queue."""
-    n = t_sorted.shape[0]
-    if n % block != 0:
-        pad = block - n % block
-        t_sorted = jnp.pad(t_sorted, (0, pad), constant_values=jnp.finfo(t_sorted.dtype).max / 8)
-        mask = jnp.pad(mask, (0, pad))
-    npad = t_sorted.shape[0]
-    grid = npad // block
-
-    t2 = t_sorted.reshape(1, npad)
-    m2 = mask.reshape(1, npad)
-    stt_arr = jnp.asarray([stt], t_sorted.dtype)
-
-    out, delay = pl.pallas_call(
-        _kernel,
-        grid=(grid,),
-        in_specs=[
-            pl.BlockSpec((1, block), lambda i: (0, i)),  # t tile in VMEM
-            pl.BlockSpec((1, block), lambda i: (0, i)),  # mask tile
-            pl.BlockSpec(memory_space=pl.ANY),  # stt scalar
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block), lambda i: (0, i)),
-            pl.BlockSpec((1, block), lambda i: (0, i)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((1, npad), t_sorted.dtype),
-            jax.ShapeDtypeStruct((1, npad), t_sorted.dtype),
-        ],
-        scratch_shapes=[pltpu.SMEM((2,), t_sorted.dtype)],
-        interpret=interpret,
-    )(t2, m2, stt_arr)
-    return out[0, :n], delay[0, :n]
+    start, _ = stage_scan(
+        t_sorted, mask, jnp.reshape(jnp.asarray(stt, t_sorted.dtype), (1,)),
+        block=block, interpret=interpret,
+    )
+    return start, jnp.where(mask, start - t_sorted, 0.0)
 
 
 # --------------------------------------------------------------------------- #
-# Fused multi-stage cascade
+# Cascades: one stage launch per switch, merges in XLA between launches
 # --------------------------------------------------------------------------- #
 
 
-def _cascade_body(n_hosts, has_hosts, *refs):
-    """One (stage, block) step of the fused cascade — shared kernel body.
-
-    ``has_hosts`` (static) selects the host-segmented variant: the refs
-    gain a host-id input tile and a host VMEM row (permuted alongside the
-    times through every merge), the SMEM stage carries gain ``n_hosts``
-    per-host delay slots, and the per-stage delay output row widens from
-    one scalar to ``[n_hosts]``.  With ``has_hosts=False`` the emitted code
-    is exactly the single-host cascade — no host tile, no extra scratch,
-    no second delay reduction.
-
-    Ref layout (inputs, outputs, scratch):
-      t_ref     (1, B) time-sorted arrival tile (read at stage 0 only)
-      bits_ref  (1, B) per-event route bits (stage s <-> bit s)
-      host_ref  (1, B) per-event host ids                  [has_hosts only]
-      stt_ref   (S,)   service times in stage order
-      tout_ref  (1, N) final post-congestion times (sorted slot order)
-      idx_ref   (1, N) slot -> original sorted position
-      delay_ref (1, H or 1) per-stage delay row, block s of the output
-      t_buf     VMEM (1, N) current times, kept sorted across stages
-      bits_buf  VMEM (1, N) route bits, permuted alongside t_buf
-      idx_buf   VMEM (1, N) original sorted position, permuted alongside
-      host_buf  VMEM (1, N) host ids, permuted alongside  [has_hosts only]
-      carry_ref SMEM f32[3 (+ H)]: [0]=cummax, [1]=rank, [2]=stage delay,
-                [3 + h]=host h's delay sum                [has_hosts only]
-    """
-    if has_hosts:
-        (t_ref, bits_ref, host_ref, stt_ref, tout_ref, idx_ref, delay_ref,
-         t_buf, bits_buf, idx_buf, host_buf, carry_ref) = refs
-    else:
-        (t_ref, bits_ref, stt_ref, tout_ref, idx_ref, delay_ref,
-         t_buf, bits_buf, idx_buf, carry_ref) = refs
-    s = pl.program_id(0)
-    b = pl.program_id(1)
-    nb = pl.num_programs(1)
-    n_stages = pl.num_programs(0)
-    block = t_ref.shape[1]
-    off = b * block
-
-    @pl.when(s == 0)
-    def _load():
-        t_buf[0, pl.ds(off, block)] = t_ref[0, :]
-        bits_buf[0, pl.ds(off, block)] = bits_ref[0, :]
-        if has_hosts:
-            host_buf[0, pl.ds(off, block)] = host_ref[0, :]
-        iota = jax.lax.broadcasted_iota(jnp.int32, (1, block), 1)
-        idx_buf[0, pl.ds(off, block)] = iota[0, :] + off
-
-    @pl.when(b == 0)
-    def _reset_stage_carries():
-        carry_ref[0] = _NEG
-        carry_ref[1] = 0.0
-        carry_ref[2] = 0.0
-        if has_hosts:
-            for h in range(n_hosts):
-                carry_ref[3 + h] = 0.0
-
-    t = t_buf[0, pl.ds(off, block)]
-    bits = bits_buf[0, pl.ds(off, block)]
-    m = (jnp.right_shift(bits, s) & 1) == 1
-    stt = stt_ref[s]
-    mf = m.astype(t.dtype)
-
-    rank = (jnp.cumsum(mf) - 1.0) + carry_ref[1]
-    g = jnp.where(m, t - stt * rank, _NEG)
-    f_local = jax.lax.cummax(g)
-    f = jnp.maximum(f_local, carry_ref[0])
-    start = jnp.where(m, f + stt * rank, t)
-    d = jnp.where(m, start - t, 0.0)
-
-    t_buf[0, pl.ds(off, block)] = start
-    carry_ref[0] = jnp.maximum(carry_ref[0], f_local[-1])
-    carry_ref[1] = carry_ref[1] + jnp.sum(mf)
-    carry_ref[2] = carry_ref[2] + jnp.sum(d)
-    if has_hosts:
-        hv = host_buf[0, pl.ds(off, block)]
-        for h in range(n_hosts):
-            carry_ref[3 + h] = carry_ref[3 + h] + jnp.sum(
-                jnp.where(hv == h, d, 0.0)
-            )
-
-    @pl.when(b == nb - 1)
-    def _finish_stage():
-        if has_hosts:
-            for h in range(n_hosts):
-                delay_ref[0, h] = carry_ref[3 + h]
-        else:
-            delay_ref[0, 0] = carry_ref[2]
-
-        @pl.when((s < n_stages - 1) & (carry_ref[2] > 0))
-        def _merge():
-            # The stage rewrote its masked events: the full row is now two
-            # interleaved sorted runs.  Restore the sorted invariant so the
-            # next stage's scan sees true arrival order (zero delay => times
-            # unchanged => already sorted => skipped).
-            x = t_buf[0, :]
-            bt = bits_buf[0, :]
-            ix = idx_buf[0, :]
-            changed = (jnp.right_shift(bt, s) & 1) == 1
-            if has_hosts:
-                hrow = host_buf[0, :]
-                x, bt, ix, hrow = _ref.merge_sorted_runs(x, changed, bt, ix, hrow)
-                host_buf[0, :] = hrow
-            else:
-                x, bt, ix = _ref.merge_sorted_runs(x, changed, bt, ix)
-            t_buf[0, :] = x
-            bits_buf[0, :] = bt
-            idx_buf[0, :] = ix
-
-        @pl.when(s == n_stages - 1)
-        def _write_out():
-            tout_ref[0, :] = t_buf[0, :]
-            idx_ref[0, :] = idx_buf[0, :]
-
-
-def _pad_to_block(block, t_sorted, route_bits, hosts=None):
-    n = t_sorted.shape[0]
-    if n % block != 0:
-        pad = block - n % block
-        t_sorted = jnp.pad(
-            t_sorted, (0, pad), constant_values=jnp.finfo(t_sorted.dtype).max / 4
-        )
-        route_bits = jnp.pad(route_bits, (0, pad))
-        if hosts is not None:
-            hosts = jnp.pad(hosts, (0, pad))
-    return t_sorted, route_bits, hosts
-
-
-@functools.partial(jax.jit, static_argnames=("block", "interpret"))
-@axes("N", route_bits="N", stts="S")
+@functools.partial(
+    jax.jit, static_argnames=("merge_plan", "n_hosts", "block", "interpret")
+)
+@axes("N", route_bits="N", stts="S", hosts="N")
 def congestion_cascade(
     t_sorted: jnp.ndarray,  # [N] f32, globally time-sorted arrivals
     route_bits: jnp.ndarray,  # [N] i32, bit s set iff event traverses stage s
     stts: jnp.ndarray,  # [S] f32, service times in stage order
-    block: int = DEFAULT_BLOCK,
-    interpret: bool = False,
-):
-    """Fused S-stage congestion cascade in a single kernel launch.
-
-    Returns ``(t_final[N], slot_idx[N], per_stage_delay[S])`` with the same
-    semantics as :func:`repro.kernels.ref.serial_queue_cascade`: ``t_final``
-    is in final sorted-slot order and ``slot_idx`` maps each slot back to its
-    position in the input ``t_sorted``.
-    """
-    n = t_sorted.shape[0]
-    n_stages = int(stts.shape[0])
-    t_sorted, route_bits, _ = _pad_to_block(block, t_sorted, route_bits)
-    npad = t_sorted.shape[0]
-    nb = npad // block
-
-    t2 = t_sorted.reshape(1, npad)
-    bits2 = route_bits.astype(jnp.int32).reshape(1, npad)
-    stt_arr = jnp.asarray(stts, t_sorted.dtype)
-
-    t_fin, idx, delay = pl.pallas_call(
-        functools.partial(_cascade_body, 1, False),
-        grid=(n_stages, nb),
-        in_specs=[
-            pl.BlockSpec((1, block), lambda s, b: (0, b)),  # arrival tile
-            pl.BlockSpec((1, block), lambda s, b: (0, b)),  # route-bit tile
-            pl.BlockSpec(memory_space=pl.ANY),  # stts vector
-        ],
-        out_specs=[
-            pl.BlockSpec((1, npad), lambda s, b: (0, 0)),  # t_final row
-            pl.BlockSpec((1, npad), lambda s, b: (0, 0)),  # slot idx row
-            pl.BlockSpec((1, 1), lambda s, b: (0, s)),  # stage delay cell
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((1, npad), t_sorted.dtype),
-            jax.ShapeDtypeStruct((1, npad), jnp.int32),
-            jax.ShapeDtypeStruct((1, n_stages), t_sorted.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((1, npad), t_sorted.dtype),
-            pltpu.VMEM((1, npad), jnp.int32),
-            pltpu.VMEM((1, npad), jnp.int32),
-            pltpu.SMEM((3,), t_sorted.dtype),
-        ],
-        interpret=interpret,
-    )(t2, bits2, stt_arr)
-    return t_fin[0, :n], idx[0, :n], delay[0, :]
-
-
-# --------------------------------------------------------------------------- #
-# Host-segmented cascade (shared-fabric multi-host analysis)
-# --------------------------------------------------------------------------- #
-
-
-@functools.partial(jax.jit, static_argnames=("n_hosts", "block", "interpret"))
-@axes("N", route_bits="N", hosts="N", stts="S")
-def congestion_cascade_hosts(
-    t_sorted: jnp.ndarray,  # [N] f32, globally time-sorted arrivals
-    route_bits: jnp.ndarray,  # [N] i32, bit s set iff event traverses stage s
-    hosts: jnp.ndarray,  # [N] i32 host ids, same sorted order
-    stts: jnp.ndarray,  # [S] f32, service times in stage order
+    merge_plan=None,  # static merge schedule (None: conservative)
+    hosts: jnp.ndarray = None,  # [N] i32 host ids, same sorted order
     n_hosts: int = 1,
     block: int = DEFAULT_BLOCK,
     interpret: bool = False,
 ):
-    """Fused cascade with per-host delay segmentation in one kernel launch.
+    """S-stage congestion cascade with the stage scan as a Pallas kernel.
 
-    Returns ``(t_final[N], slot_idx[N], per_stage_delay[S, n_hosts])`` —
-    the host axis decomposes each stage's queueing delay by the host whose
-    event waited, matching
-    :func:`repro.kernels.ref.serial_queue_cascade` with ``hosts`` given.
-    Shares its kernel body (:func:`_cascade_body`) with the single-host
-    :func:`congestion_cascade`, which pays none of the host-axis cost.
+    Returns ``(t_final[N], slot_idx[N], per_stage_delay)`` with exactly the
+    semantics of :func:`repro.kernels.ref.serial_queue_cascade` (same merge
+    schedule): ``per_stage_delay`` is ``[S]``, or ``[S, n_hosts]`` when
+    ``hosts`` is given.
     """
-    n = t_sorted.shape[0]
-    n_stages = int(stts.shape[0])
-    t_sorted, route_bits, hosts = _pad_to_block(block, t_sorted, route_bits, hosts)
-    npad = t_sorted.shape[0]
-    nb = npad // block
 
-    t2 = t_sorted.reshape(1, npad)
-    bits2 = route_bits.astype(jnp.int32).reshape(1, npad)
-    host2 = hosts.astype(jnp.int32).reshape(1, npad)
-    stt_arr = jnp.asarray(stts, t_sorted.dtype)
-
-    t_fin, idx, delay = pl.pallas_call(
-        functools.partial(_cascade_body, n_hosts, True),
-        grid=(n_stages, nb),
-        in_specs=[
-            pl.BlockSpec((1, block), lambda s, b: (0, b)),  # arrival tile
-            pl.BlockSpec((1, block), lambda s, b: (0, b)),  # route-bit tile
-            pl.BlockSpec((1, block), lambda s, b: (0, b)),  # host-id tile
-            pl.BlockSpec(memory_space=pl.ANY),  # stts vector
-        ],
-        out_specs=[
-            pl.BlockSpec((1, npad), lambda s, b: (0, 0)),  # t_final row
-            pl.BlockSpec((1, npad), lambda s, b: (0, 0)),  # slot idx row
-            pl.BlockSpec((1, n_hosts), lambda s, b: (0, s)),  # stage delay row
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((1, npad), t_sorted.dtype),
-            jax.ShapeDtypeStruct((1, npad), jnp.int32),
-            jax.ShapeDtypeStruct((1, n_stages * n_hosts), t_sorted.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((1, npad), t_sorted.dtype),
-            pltpu.VMEM((1, npad), jnp.int32),
-            pltpu.VMEM((1, npad), jnp.int32),
-            pltpu.VMEM((1, npad), jnp.int32),
-            pltpu.SMEM((3 + n_hosts,), t_sorted.dtype),
-        ],
-        interpret=interpret,
-    )(t2, bits2, host2, stt_arr)
-    return t_fin[0, :n], idx[0, :n], delay[0, :].reshape(n_stages, n_hosts)
-
-
-# --------------------------------------------------------------------------- #
-# QoS-arbitrated cascade (per-class SMEM carries)
-# --------------------------------------------------------------------------- #
-
-
-def _qos_cascade_body(n_classes, *refs):
-    """One (stage, block) step of the QoS-arbitrated cascade.
-
-    Extends :func:`_cascade_body` with per-QoS-class state, in the
-    data-driven formulation of :func:`repro.kernels.ref.qos_cascade_dyn`:
-    disciplines and class weights are runtime scalars read per stage, so one
-    lowering serves every discipline/weight mix.  Each stage runs ``C``
-    masked scans over the block — class ``c``'s selector is ``q_eff <= c``
-    under strict priority and ``q_eff == c`` otherwise, with WFQ inflating
-    the service time to ``stt·W/w_c`` — and each scan owns a (cummax, rank)
-    carry pair so the inter-block chaining of the FIFO kernel carries over
-    per class unchanged.
-
-    Ref layout (inputs, outputs, scratch):
-      t_ref     (1, B) time-sorted arrival tile (read at stage 0 only)
-      bits_ref  (1, B) per-event route bits (stage s <-> bit s)
-      qos_ref   (1, B) per-event QoS class ids (read at stage 0 only)
-      stt_ref   (S,)   service times in stage order
-      disc_ref  (S,)   i32 discipline codes (ref.DISC_*)
-      w_ref     (S, C) f32 per-stage class weights
-      tout_ref  (1, N) final post-congestion times (sorted slot order)
-      idx_ref   (1, N) slot -> original sorted position
-      delay_ref (1, C) per-stage per-class delay row, block s of the output
-      t_buf     VMEM (1, N) current times, kept sorted across stages
-      bits_buf  VMEM (1, N) route bits, permuted alongside t_buf
-      idx_buf   VMEM (1, N) original sorted position, permuted alongside
-      qos_buf   VMEM (1, N) QoS classes, permuted alongside
-      carry_ref SMEM f32[3C + 1]: [c]=class cummax, [C + c]=class rank,
-                [2C + c]=class delay sum, [3C]=stage delay (merge guard)
-    """
-    (t_ref, bits_ref, qos_ref, stt_ref, disc_ref, w_ref, tout_ref, idx_ref,
-     delay_ref, t_buf, bits_buf, idx_buf, qos_buf, carry_ref) = refs
-    s = pl.program_id(0)
-    b = pl.program_id(1)
-    nb = pl.num_programs(1)
-    n_stages = pl.num_programs(0)
-    block = t_ref.shape[1]
-    off = b * block
-
-    @pl.when(s == 0)
-    def _load():
-        t_buf[0, pl.ds(off, block)] = t_ref[0, :]
-        bits_buf[0, pl.ds(off, block)] = bits_ref[0, :]
-        qos_buf[0, pl.ds(off, block)] = qos_ref[0, :]
-        iota = jax.lax.broadcasted_iota(jnp.int32, (1, block), 1)
-        idx_buf[0, pl.ds(off, block)] = iota[0, :] + off
-
-    @pl.when(b == 0)
-    def _reset_stage_carries():
-        for c in range(n_classes):
-            carry_ref[c] = _NEG
-            carry_ref[n_classes + c] = 0.0
-            carry_ref[2 * n_classes + c] = 0.0
-        carry_ref[3 * n_classes] = 0.0
-
-    t = t_buf[0, pl.ds(off, block)]
-    bits = bits_buf[0, pl.ds(off, block)]
-    qv = qos_buf[0, pl.ds(off, block)]
-    m = (jnp.right_shift(bits, s) & 1) == 1
-    stt = stt_ref[s]
-    disc = disc_ref[s]
-    w_total = jnp.zeros((), t.dtype)
-    for c in range(n_classes):
-        w_total = w_total + w_ref[s, c]
-    q_eff = jnp.where(disc == _ref.DISC_FIFO, 0, qv)
-
-    start = t
-    for c in range(n_classes):
-        sel = jnp.where(disc == _ref.DISC_PRIORITY, q_eff <= c, q_eff == c)
-        stt_c = jnp.where(
-            disc == _ref.DISC_WFQ, stt * w_total / w_ref[s, c], stt
+    def scan(ts, m, stt, seg, n_seg):
+        return stage_scan(
+            ts, m, stt[None], seg=seg, n_seg=n_seg, block=block,
+            interpret=interpret,
         )
-        M = m & sel
-        mf = M.astype(t.dtype)
-        rank = (jnp.cumsum(mf) - 1.0) + carry_ref[n_classes + c]
-        g = jnp.where(M, t - stt_c * rank, _NEG)
-        f_local = jax.lax.cummax(g)
-        f = jnp.maximum(f_local, carry_ref[c])
-        start = jnp.where(m & (q_eff == c), f + stt_c * rank, start)
-        carry_ref[c] = jnp.maximum(carry_ref[c], f_local[-1])
-        carry_ref[n_classes + c] = carry_ref[n_classes + c] + jnp.sum(mf)
 
-    d = jnp.where(m, start - t, 0.0)
-    t_buf[0, pl.ds(off, block)] = start
-    for c in range(n_classes):
-        # attribution uses the event's *actual* class, even under FIFO
-        carry_ref[2 * n_classes + c] = carry_ref[2 * n_classes + c] + jnp.sum(
-            jnp.where(qv == c, d, 0.0)
-        )
-    carry_ref[3 * n_classes] = carry_ref[3 * n_classes] + jnp.sum(d)
-
-    @pl.when(b == nb - 1)
-    def _finish_stage():
-        for c in range(n_classes):
-            delay_ref[0, c] = carry_ref[2 * n_classes + c]
-
-        @pl.when((s < n_stages - 1) & (carry_ref[3 * n_classes] > 0))
-        def _merge():
-            # Up to C + 1 interleaved sorted runs after the per-class scans;
-            # fold class by class (ref._qos_fold's schedule) — under FIFO
-            # q_eff = 0 makes step 0 the full two-run merge and the rest
-            # identity permutations.
-            x = t_buf[0, :]
-            bt = bits_buf[0, :]
-            ix = idx_buf[0, :]
-            qr = qos_buf[0, :]
-            for c in range(n_classes):
-                m_cur = (jnp.right_shift(bt, s) & 1) == 1
-                q_f = jnp.where(disc == _ref.DISC_FIFO, 0, qr)
-                changed = m_cur & (q_f == c)
-                within = ~(m_cur & (q_f > c))
-                x, bt, ix, qr = _ref.merge_sorted_runs(
-                    x, changed, bt, ix, qr, within=within
-                )
-            t_buf[0, :] = x
-            bits_buf[0, :] = bt
-            idx_buf[0, :] = ix
-            qos_buf[0, :] = qr
-
-        @pl.when(s == n_stages - 1)
-        def _write_out():
-            tout_ref[0, :] = t_buf[0, :]
-            idx_ref[0, :] = idx_buf[0, :]
+    return _ref.serial_queue_cascade(
+        t_sorted, route_bits, stts, merge_plan, hosts=hosts, n_hosts=n_hosts,
+        scan=scan,
+    )
 
 
-@functools.partial(jax.jit, static_argnames=("block", "interpret"))
-@axes("N", route_bits="N", qos="N", stts="S", disc_code="S", class_weights="S,C")
+@functools.partial(jax.jit, static_argnames=("n_hosts", "block", "interpret"))
+@axes(
+    "N", route_bits="N", qos="N", stts="S", disc_code="S",
+    class_weights="S,C", hosts="N",
+)
 def qos_congestion_cascade(
     t_sorted: jnp.ndarray,  # [N] f32, globally time-sorted arrivals
     route_bits: jnp.ndarray,  # [N] i32, bit s set iff event traverses stage s
@@ -546,58 +268,32 @@ def qos_congestion_cascade(
     stts: jnp.ndarray,  # [S] f32, service times in stage order
     disc_code: jnp.ndarray,  # [S] i32 discipline codes (ref.DISC_*)
     class_weights: jnp.ndarray,  # [S, C] f32 per-stage class weights
+    hosts: jnp.ndarray = None,  # [N] i32 host ids, same sorted order
+    n_hosts: int = 1,
     block: int = DEFAULT_BLOCK,
     interpret: bool = False,
 ):
-    """Fused QoS-arbitrated cascade in a single kernel launch.
+    """QoS-arbitrated cascade with the stage scans as a Pallas kernel.
 
-    Returns ``(t_final[N], slot_idx[N], per_stage_delay[S, C])`` matching
-    :func:`repro.kernels.ref.qos_cascade_dyn` (single-host form): per-stage
-    queueing delay decomposed by the QoS class whose event waited, under
-    runtime per-switch disciplines and class weights.
+    Returns ``(t_final[N], slot_idx[N], per_stage_delay[S, H, C])`` with the
+    semantics of :func:`repro.kernels.ref.qos_cascade_dyn` (``H`` is
+    ``n_hosts``, 1 without ``hosts``): per stage, ``C`` per-class FIFO
+    scans replace the inline path's one max-plus scan — the same DES
+    horizons — and the stable multi-run fold between stages is shared.
     """
-    n = t_sorted.shape[0]
-    n_stages = int(stts.shape[0])
     n_classes = int(class_weights.shape[1])
-    t_sorted, route_bits, qos = _pad_to_block(block, t_sorted, route_bits, qos)
-    npad = t_sorted.shape[0]
-    nb = npad // block
+    lv = jnp.arange(n_classes, dtype=jnp.int32)
 
-    t2 = t_sorted.reshape(1, npad)
-    bits2 = route_bits.astype(jnp.int32).reshape(1, npad)
-    qos2 = jnp.clip(qos.astype(jnp.int32), 0, n_classes - 1).reshape(1, npad)
-    stt_arr = jnp.asarray(stts, t_sorted.dtype)
-    disc_arr = jnp.asarray(disc_code, jnp.int32)
-    w_arr = jnp.asarray(class_weights, t_sorted.dtype)
+    def stage(ts, m, q_cur, disc, stt, w_row, seg, n_seg):
+        q_eff = jnp.where(disc == _ref.DISC_FIFO, 0, q_cur)
+        stt_c = jnp.where(disc == _ref.DISC_WFQ, stt * w_row.sum() / w_row, stt)
+        lo = jnp.where(disc == _ref.DISC_PRIORITY, 0, lv)
+        return stage_scan(
+            ts, m, stt_c, q=q_eff, lo=lo, seg=seg, n_seg=n_seg, block=block,
+            interpret=interpret,
+        )
 
-    t_fin, idx, delay = pl.pallas_call(
-        functools.partial(_qos_cascade_body, n_classes),
-        grid=(n_stages, nb),
-        in_specs=[
-            pl.BlockSpec((1, block), lambda s, b: (0, b)),  # arrival tile
-            pl.BlockSpec((1, block), lambda s, b: (0, b)),  # route-bit tile
-            pl.BlockSpec((1, block), lambda s, b: (0, b)),  # qos tile
-            pl.BlockSpec(memory_space=pl.ANY),  # stts vector
-            pl.BlockSpec(memory_space=pl.ANY),  # discipline codes
-            pl.BlockSpec(memory_space=pl.ANY),  # class-weight table
-        ],
-        out_specs=[
-            pl.BlockSpec((1, npad), lambda s, b: (0, 0)),  # t_final row
-            pl.BlockSpec((1, npad), lambda s, b: (0, 0)),  # slot idx row
-            pl.BlockSpec((1, n_classes), lambda s, b: (0, s)),  # stage row
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((1, npad), t_sorted.dtype),
-            jax.ShapeDtypeStruct((1, npad), jnp.int32),
-            jax.ShapeDtypeStruct((1, n_stages * n_classes), t_sorted.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((1, npad), t_sorted.dtype),
-            pltpu.VMEM((1, npad), jnp.int32),
-            pltpu.VMEM((1, npad), jnp.int32),
-            pltpu.VMEM((1, npad), jnp.int32),
-            pltpu.SMEM((3 * n_classes + 1,), t_sorted.dtype),
-        ],
-        interpret=interpret,
-    )(t2, bits2, qos2, stt_arr, disc_arr, w_arr)
-    return t_fin[0, :n], idx[0, :n], delay[0, :].reshape(n_stages, n_classes)
+    return _ref.qos_cascade_dyn(
+        t_sorted, route_bits, stts, qos, disc_code, class_weights,
+        hosts=hosts, n_hosts=n_hosts, stage=stage,
+    )

@@ -125,7 +125,7 @@ def flash_attention(
             pl.BlockSpec((1, 1, block_q, D), lambda b, h, qi, ki: (b, h, qi, 0)),
             pl.BlockSpec((1, 1, block_k, D), lambda b, h, qi, ki, g=group: (b, h // g, ki, 0)),
             pl.BlockSpec((1, 1, block_k, D), lambda b, h, qi, ki, g=group: (b, h // g, ki, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pltpu.SMEM),  # q_offset scalar
         ],
         out_specs=pl.BlockSpec((1, 1, block_q, D), lambda b, h, qi, ki: (b, h, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, Sq, D), q.dtype),

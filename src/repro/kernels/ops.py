@@ -2,28 +2,27 @@
 
 Three implementations per op:
 
-  * ``'pallas'``           — compiled TPU kernel (the production path),
+  * ``'pallas'``           — compiled TPU kernel,
   * ``'pallas_interpret'`` — same kernel body executed by the Pallas
                              interpreter (CPU-correctness path; used by tests),
-  * ``'ref'``              — pure-jnp oracle (GSPMD-partitionable; used by the
-                             multi-pod dry-run, since Pallas TPU kernels do
-                             not lower on the CPU host platform).
+  * ``'ref'``              — pure-jnp oracle (GSPMD-partitionable, and what
+                             the analyzer's ``impl='inline'`` runs).
 
-Default: ``'pallas'`` when a TPU is present, else ``'ref'``.  Override
-globally with :func:`set_implementation` or per-call with ``impl=``.
+Default: ``'ref'`` on every platform — a kernel runs only where a caller
+asks for it, per call with ``impl=`` or globally with
+:func:`set_implementation`.  A kernel that does not compile for the TPU
+(:func:`ssd`) raises when ``'pallas'`` selects it; nothing falls back.
 """
 
 from __future__ import annotations
 
 from typing import Optional
 
-import jax
 import jax.numpy as jnp
 
 from ..analysis.annotations import axes
 from . import ref
 from .congestion import congestion_cascade as _cascade_pallas
-from .congestion import congestion_cascade_hosts as _cascade_hosts_pallas
 from .congestion import congestion_scan as _congestion_pallas
 from .congestion import qos_congestion_cascade as _qos_cascade_pallas
 from .flash_attention import flash_attention as _flash_pallas
@@ -42,22 +41,11 @@ __all__ = [
     "two_run_merge",
 ]
 
-_IMPL: Optional[str] = None
 _VALID = ("pallas", "pallas_interpret", "ref")
-
-
-def _default_impl() -> str:
-    try:
-        platform = jax.devices()[0].platform
-    except RuntimeError:
-        platform = "cpu"
-    return "pallas" if platform == "tpu" else "ref"
+_IMPL = "ref"
 
 
 def get_implementation() -> str:
-    global _IMPL
-    if _IMPL is None:
-        _IMPL = _default_impl()
     return _IMPL
 
 
@@ -117,7 +105,13 @@ def ssd(
     i = _resolve(impl)
     if i == "ref":
         return ref.ssd_chunked(x, dt, A, Bm, Cm, chunk=min(chunk, x.shape[1]))
-    return _ssd_pallas(x, dt, A, Bm, Cm, chunk=chunk, interpret=(i == "pallas_interpret"))
+    if i == "pallas":
+        raise NotImplementedError(
+            "ssd: the Pallas SSD kernel does not compile for TPU (its "
+            "(1, chunk, 1, P) blocks break Mosaic's tiling, and Mosaic has no "
+            "in-kernel cumsum); use impl='ref'"
+        )
+    return _ssd_pallas(x, dt, A, Bm, Cm, chunk=chunk, interpret=True)
 
 
 @axes("N", mask="N")
@@ -154,26 +148,18 @@ def congestion_cascade(
     Returns ``(t_final, slot_idx, per_stage_delay)``; see
     :func:`repro.kernels.ref.serial_queue_cascade` for the semantics.
     ``merge_plan`` (static, from :func:`repro.core.analyzer.plan_cascade`)
-    prunes inter-stage merges on the ``'ref'`` path; the Pallas kernel
-    always runs the conservative (always-valid) schedule.
-
-    With ``hosts`` (per-event host ids, same sorted order as ``t_sorted``),
-    ``per_stage_delay`` becomes host-segmented ``[S, n_hosts]`` — the Pallas
-    path accumulates the per-host sums in its SMEM stage carries.
+    prunes inter-stage merges on every path.  With ``hosts`` (per-event host
+    ids, same sorted order as ``t_sorted``), ``per_stage_delay`` becomes
+    host-segmented ``[S, n_hosts]``.
     """
     i = _resolve(impl)
     if i == "ref":
         return ref.serial_queue_cascade(
             t_sorted, route_bits, stts, merge_plan, hosts=hosts, n_hosts=n_hosts
         )
-    if hosts is None:
-        return _cascade_pallas(
-            t_sorted, route_bits, stts, block=block,
-            interpret=(i == "pallas_interpret"),
-        )
-    return _cascade_hosts_pallas(
-        t_sorted, route_bits, hosts, stts, n_hosts=n_hosts, block=block,
-        interpret=(i == "pallas_interpret"),
+    return _cascade_pallas(
+        t_sorted, route_bits, stts, merge_plan=merge_plan, hosts=hosts,
+        n_hosts=n_hosts, block=block, interpret=(i == "pallas_interpret"),
     )
 
 
@@ -200,49 +186,42 @@ def qos_congestion_cascade(
     lowering serves every discipline/weight mix.  Returns ``(t_final,
     slot_idx, per_stage_delay[S, n_hosts, C])``; see
     :func:`repro.kernels.ref.qos_cascade_dyn` for the semantics.
-
-    The Pallas kernel is single-host (its SMEM carries are per class); the
-    host-segmented decomposition routes to the ref, which the shared-fabric
-    analyzer uses anyway (``impl='inline'``).
     """
     i = _resolve(impl)
-    if i == "ref" or hosts is not None:
+    if i == "ref":
         return ref.qos_cascade_dyn(
             t_sorted, route_bits, stts, qos, disc_code, class_weights,
             hosts=hosts, n_hosts=n_hosts,
         )
-    t_fin, idx, delay = _qos_cascade_pallas(
+    return _qos_cascade_pallas(
         t_sorted, route_bits, qos, stts, disc_code, class_weights,
-        block=block, interpret=(i == "pallas_interpret"),
+        hosts=hosts, n_hosts=n_hosts, block=block,
+        interpret=(i == "pallas_interpret"),
     )
-    return t_fin, idx, delay[:, None, :]
 
 
 @axes("N", lead="N")
-def two_run_merge(x, lead, *payloads, impl: Optional[str] = None):
+def two_run_merge(x, lead, *payloads):
     """Stable merge of two interleaved sorted runs (envelope formulation).
 
-    All implementations route to the XLA ref: the cummax/searchsorted/
-    scatter formulation is already a handful of fused elementwise passes, so
-    a hand-written Pallas body has nothing left to win on current backends.
+    XLA only: the cummax/searchsorted/scatter formulation is already a
+    handful of fused elementwise passes, and Mosaic lowers neither 1-D
+    gathers nor scatters.
     """
-    _resolve(impl)
     return ref.two_run_merge(x, lead, *payloads)
 
 
 @axes("N")
-def staging_sort(x, run_caps, *payloads, impl: Optional[str] = None):
+def staging_sort(x, run_caps, *payloads):
     """On-device stable sort of concatenated sorted runs (merge tree of
     :func:`two_run_merge` rounds); bitwise-equal to a host stable argsort of
-    the run-major concatenation.  Ref-only, as for :func:`two_run_merge`."""
-    _resolve(impl)
+    the run-major concatenation.  XLA only, as for :func:`two_run_merge`."""
     return ref.staging_sort(x, run_caps, *payloads)
 
 
 @axes("W", idx_pack="W", stts="D")
-def chain_cascade(t_pack, idx_pack, stts, seg_caps, impl: Optional[str] = None):
+def chain_cascade(t_pack, idx_pack, stts, seg_caps):
     """Compact suffix cascade over per-stage packed sorted runs — the
-    device-resident pipeline's fused merge+scan.  Ref-only, as for
+    device-resident pipeline's fused merge+scan.  XLA only, as for
     :func:`two_run_merge`."""
-    _resolve(impl)
     return ref.chain_cascade(t_pack, idx_pack, stts, seg_caps)

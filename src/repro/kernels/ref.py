@@ -291,6 +291,7 @@ def serial_queue_cascade(
     merge_plan=None,  # static: per-stage tuple of (changed_bit, within_bit|None)
     hosts: jnp.ndarray = None,  # [N] i32 host ids in sorted order (optional)
     n_hosts: int = 1,  # static; only used when hosts is given
+    scan=None,  # stage scan (ts, m, stt, seg, n_seg) -> (start, seg_delay)
 ):
     """Fused S-stage congestion cascade over one time-sorted epoch.
 
@@ -324,6 +325,10 @@ def serial_queue_cascade(
     the cascade's live permutation (``hosts[idx]``), so merges need no extra
     payload.
 
+    ``scan`` swaps the per-stage queue (default :func:`_fifo_stage`, pure
+    jnp) — the Pallas cascade passes its compiled stage kernel, so both
+    paths share this loop and its merges.
+
     The cascade never sees latencies: device-cache latency scaling
     (:mod:`repro.core.cache`) happens on the caller's side, which is what
     keeps this oracle — and the Pallas kernel it specifies — identical
@@ -334,7 +339,8 @@ def serial_queue_cascade(
     s_stages = stts.shape[0]
     if merge_plan is None:
         merge_plan = tuple(((s - 1, None),) if s else () for s in range(s_stages))
-    big = jnp.asarray(jnp.finfo(f32).max / 4, f32)
+    if scan is None:
+        scan = _fifo_stage
     ts = t_sorted
     bits = route_bits.astype(jnp.int32)
     idx = jnp.arange(n, dtype=jnp.int32)
@@ -356,22 +362,25 @@ def serial_queue_cascade(
                 dirty > 0, merge, lambda a: (a[0], a[1], a[2]), args
             )
         m = (jnp.right_shift(bits, s) & 1) == 1
-        stt = stts[s]
-        rankf = (jnp.cumsum(m.astype(jnp.int32)) - 1).astype(f32)
-        g = jnp.where(m, ts - stt * rankf, -big)
-        f = jax.lax.cummax(g)
-        start = jnp.where(m, f + stt * rankf, ts)
-        d = jnp.where(m, start - ts, 0.0)
-        dsum = d.sum()
         if hosts is None:
-            per_stage.append(dsum)
+            ts, seg_d = scan(ts, m, stts[s], None, 1)
+            per_stage.append(seg_d[0])
         else:
-            per_stage.append(
-                jax.ops.segment_sum(d, hosts[idx], num_segments=n_hosts)
-            )
-        dirty = dirty + dsum
-        ts = jnp.where(m, start, ts)
+            ts, seg_d = scan(ts, m, stts[s], hosts[idx], n_hosts)
+            per_stage.append(seg_d)
+        dirty = dirty + seg_d.sum()
     return ts, idx, jnp.stack(per_stage)
+
+
+def _fifo_stage(ts, m, stt, seg, n_seg):
+    """One FIFO stage of :func:`serial_queue_cascade`: ``(start, delay
+    summed per segment of seg)`` — the whole stage when ``seg`` is None."""
+    big = jnp.asarray(jnp.finfo(ts.dtype).max / 4, ts.dtype)
+    start = jnp.where(m, _class_scan(ts, m, stt, big), ts)
+    d = jnp.where(m, start - ts, 0.0)
+    if seg is None:
+        return start, d.sum()[None]
+    return start, jax.ops.segment_sum(d, seg, num_segments=n_seg)
 
 
 # --------------------------------------------------------------------------- #
@@ -654,6 +663,21 @@ def _tropical_stage(ts, m, q_cur, disc, stt, w_row):
     return jnp.maximum(ts, fin_c)
 
 
+def _tropical_dyn_stage(ts, m, q_cur, disc, stt, w_row, seg, n_seg):
+    """One stage of :func:`qos_cascade_dyn`: the max-plus scan, then the
+    stage's waiting summed per (host, class) segment."""
+    start = _tropical_stage(ts, m, q_cur, disc, stt, w_row)
+    d = jnp.where(m, start - ts, 0.0)
+    if n_seg <= 32:
+        # one-hot matmul: far cheaper than a scatter-based segment_sum at
+        # small segment counts (a single fused reduction per column)
+        oh = seg[:, None] == jnp.arange(n_seg, dtype=jnp.int32)[None, :]
+        return start, jnp.dot(
+            d, oh.astype(ts.dtype), precision=jax.lax.Precision.HIGHEST
+        )
+    return start, jax.ops.segment_sum(d, seg, num_segments=n_seg)
+
+
 @axes(
     "N", route_bits="N", stts="S", qos="N", disc_code="S",
     class_weights="S,C", hosts="N",
@@ -667,6 +691,7 @@ def qos_cascade_dyn(
     class_weights: jnp.ndarray,  # [S, C] f32 per-stage class weights (traced)
     hosts: jnp.ndarray = None,  # [N] i32 host ids in sorted order (optional)
     n_hosts: int = 1,  # static; attribution rows (1 when hosts is None)
+    stage=None,  # (ts, m, q, disc, stt, w_row, seg, n_seg) -> (start, seg_delay)
 ):
     """Data-driven QoS cascade: disciplines and weights are *runtime* arrays.
 
@@ -692,6 +717,10 @@ def qos_cascade_dyn(
       skipped states keep runs = {mask∩class} ∪ {untouched}, exactly what
       the eventual fold's ``run_id`` labels.
 
+    ``stage`` swaps one stage's arbitration and its delay attribution
+    (default :func:`_tropical_dyn_stage`); the Pallas cascade passes its
+    compiled per-class scans and shares the folds.
+
     Returns ``(t_final, slot_idx, per_stage_delay[S, H, C])`` where ``H`` is
     ``n_hosts`` (1 when ``hosts`` is None).
     """
@@ -707,37 +736,29 @@ def qos_cascade_dyn(
         hosts = jnp.zeros((n,), jnp.int32)
         n_hosts = 1
     disc_code = disc_code.astype(jnp.int32)
+    if stage is None:
+        stage = _tropical_dyn_stage
+    n_seg = n_hosts * n_classes
     dirty = jnp.zeros((), f32)
     per_stage = []
     for s in range(s_stages):
         m = (jnp.right_shift(bits, s) & 1) == 1
         q_cur = jnp.take(qos, idx)
+        seg = jnp.take(hosts, idx) * n_classes + q_cur
         # a zero-service stage is a DES identity (processed in time order,
         # the horizon never exceeds the current arrival, so start == t and
         # delay == 0 for every discipline) — skip its scan entirely
-        start = jax.lax.cond(
+        start, seg_d = jax.lax.cond(
             stts[s] > 0,
-            lambda a: _tropical_stage(
-                a[0], a[1], a[2], disc_code[s], stts[s], class_weights[s]
+            lambda a: stage(
+                a[0], a[1], a[2], disc_code[s], stts[s], class_weights[s],
+                a[3], n_seg,
             ),
-            lambda a: a[0],
-            (ts, m, q_cur),
+            lambda a: (a[0], jnp.zeros((n_seg,), f32)),
+            (ts, m, q_cur, seg),
         )
-        d = jnp.where(m, start - ts, 0.0)
-        dsum = d.sum()
-        seg = jnp.take(hosts, idx) * n_classes + q_cur
-        n_seg = n_hosts * n_classes
-        if n_seg <= 32:
-            # one-hot matmul: far cheaper than a scatter-based segment_sum
-            # at small segment counts (a single fused reduction per column)
-            oh = (seg[:, None] == jnp.arange(n_seg, dtype=jnp.int32)[None, :])
-            per_stage.append((d @ oh.astype(f32)).reshape(n_hosts, n_classes))
-        else:
-            per_stage.append(
-                jax.ops.segment_sum(d, seg, num_segments=n_seg)
-                .reshape(n_hosts, n_classes)
-            )
-        dirty = dirty + dsum
+        per_stage.append(seg_d.reshape(n_hosts, n_classes))
+        dirty = dirty + seg_d.sum()
         ts = jnp.where(m, start, ts)
         if s < s_stages - 1:
             # Elide the fold when the NEXT stage is WFQ over the SAME event

@@ -2,35 +2,13 @@
 
 from __future__ import annotations
 
-from typing import Sequence, Tuple
-
 import jax
 
 __all__ = [
-    "make_abstract_mesh",
     "make_production_mesh",
     "make_local_mesh",
     "make_data_mesh",
 ]
-
-
-def make_abstract_mesh(shape: Sequence[int], axes: Sequence[str]):
-    """Version-compatible ``AbstractMesh`` constructor.
-
-    Newer JAX takes ``AbstractMesh(shape, axis_names)``; JAX <= 0.4.x takes
-    a single tuple of ``(name, size)`` pairs.  Try the modern signature
-    first and fall back on the TypeError the legacy one raises for it.
-    """
-    from jax.sharding import AbstractMesh
-
-    shape_t: Tuple[int, ...] = tuple(int(s) for s in shape)
-    axes_t: Tuple[str, ...] = tuple(axes)
-    if len(shape_t) != len(axes_t):
-        raise ValueError(f"shape {shape_t} / axes {axes_t} length mismatch")
-    try:
-        return AbstractMesh(shape_t, axes_t)
-    except TypeError:
-        return AbstractMesh(tuple(zip(axes_t, shape_t)))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -58,8 +36,7 @@ def make_data_mesh(n: int | None = None):
     The mesh shape the analyzer's sharded dispatch expects: stacked
     ``[K, B, N]`` dispatches shard their leading scenario/session/rack axis
     over 'data' (see ``repro.distributed.sharding.resolve_data_mesh``).
-    Built with ``jax.sharding.Mesh`` directly so a subset of devices works
-    on every supported JAX version.
+    Built with ``jax.sharding.Mesh`` directly so a subset of devices works.
     """
     import numpy as np
     from jax.sharding import Mesh

@@ -582,3 +582,55 @@ def test_attach_async_still_matches_sync():
     assert b.latency_s == pytest.approx(a.latency_s, rel=1e-6)
     assert b.congestion_s == pytest.approx(a.congestion_s, rel=1e-6, abs=1e-12)
     assert b.analyzer_s > 0
+
+
+# --------------------------------------------------------------------------- #
+# persistent compilation cache placement
+# --------------------------------------------------------------------------- #
+
+_CACHE_PROBE = """
+import jax, jax.numpy as jnp
+from repro.core.aot import install_persistent_cache
+hits = []
+jax.monitoring.register_event_listener(
+    lambda e, **kw: hits.append(e) if e.endswith("compilation_cache/cache_hits") else None
+)
+print(install_persistent_cache())
+if COMPILE:
+    jax.jit(lambda x: x * 3.0 + 1.0)(jnp.arange(8.0)).block_until_ready()
+print(len(hits))
+"""
+
+
+def _cache_probe(compile_, cache_dir=None):
+    import os
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    repo = Path(__file__).resolve().parents[1]
+    env = {k: v for k, v in os.environ.items() if k != "JAX_COMPILATION_CACHE_DIR"}
+    env.update(JAX_PLATFORMS="cpu", PYTHONPATH=str(repo / "src"))
+    if cache_dir is not None:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(cache_dir)
+    out = subprocess.run(
+        [sys.executable, "-c", f"COMPILE = {compile_!r}\n" + _CACHE_PROBE],
+        env=env, cwd=repo, capture_output=True, text=True, timeout=120, check=True,
+    ).stdout.split()
+    return out[-2], int(out[-1])
+
+
+def test_persistent_cache_lives_where_the_environment_says(tmp_path):
+    """JAX_COMPILATION_CACHE_DIR places the cache, code sets no other
+    directory, and a second identical process hits what the first wrote."""
+    from pathlib import Path
+
+    cache = tmp_path / "jax_cache"
+    where, hits = _cache_probe(True, cache)
+    assert where == str(cache) and hits == 0
+    assert any(cache.iterdir())
+    where, hits = _cache_probe(True, cache)
+    assert where == str(cache) and hits >= 1
+    # without the variable: the fixed, gitignored <repo>/.jax_cache
+    where, _ = _cache_probe(False)
+    assert where == str(Path(__file__).resolve().parents[1] / ".jax_cache")

@@ -149,3 +149,22 @@ def test_ops_dispatch_modes_agree():
     assert ops.get_implementation() in ("ref", "pallas", "pallas_interpret")
     with pytest.raises(ValueError):
         ops.set_implementation("nope")
+
+
+def test_ops_default_is_ref_and_ssd_pallas_raises():
+    """No platform probe picks a kernel: the default is the jnp path, and
+    the SSD kernel — which Mosaic refuses — raises instead of falling back."""
+    assert ops._IMPL == "ref"
+    ks = jax.random.split(jax.random.PRNGKey(3), 5)
+    x = jax.random.normal(ks[0], (1, 64, 2, 8))
+    dt = jax.nn.softplus(jax.random.normal(ks[1], (1, 64, 2))) * 0.1
+    A = -jnp.exp(jax.random.normal(ks[2], (2,)) * 0.3)
+    Bm = jax.random.normal(ks[3], (1, 64, 8)) * 0.5
+    Cm = jax.random.normal(ks[4], (1, 64, 8)) * 0.5
+    with pytest.raises(NotImplementedError, match="impl='ref'"):
+        ops.ssd(x, dt, A, Bm, Cm, chunk=32, impl="pallas")
+    np.testing.assert_allclose(
+        np.asarray(ops.ssd(x, dt, A, Bm, Cm, chunk=32, impl="pallas_interpret")),
+        np.asarray(ops.ssd(x, dt, A, Bm, Cm, chunk=32)),
+        rtol=2e-4, atol=2e-4,
+    )

@@ -146,7 +146,7 @@ def test_pallas_interpret_matches_ref():
     np.testing.assert_allclose(np.asarray(tf_k), np.asarray(tf_r), rtol=1e-6)
     np.testing.assert_array_equal(np.asarray(idx_k), np.asarray(idx_r))
     np.testing.assert_allclose(
-        np.asarray(psd_k), np.asarray(psd_r)[:, 0, :], rtol=1e-5, atol=1e-3
+        np.asarray(psd_k), np.asarray(psd_r), rtol=1e-5, atol=1e-3
     )
 
 

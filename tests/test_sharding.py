@@ -2,18 +2,18 @@
 
 import jax
 import pytest
+from jax.sharding import AbstractMesh
 from jax.sharding import PartitionSpec as P
 
 import repro.configs as cfgs
 from repro.distributed import sharding as shr
-from repro.launch.mesh import make_abstract_mesh
 from repro.models import Model
 
 
 def _mesh(multi=False):
     if multi:
-        return make_abstract_mesh((2, 16, 16), ("pod", "data", "model"))
-    return make_abstract_mesh((16, 16), ("data", "model"))
+        return AbstractMesh((2, 16, 16), ("pod", "data", "model"))
+    return AbstractMesh((16, 16), ("data", "model"))
 
 
 def _pshapes(arch):
