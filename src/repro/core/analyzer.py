@@ -69,14 +69,14 @@ from __future__ import annotations
 
 import dataclasses
 import heapq
-import time
-from typing import List, Optional, Sequence, Tuple
+from typing import Any, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from ..analysis.annotations import axes
+from . import spans
 from .aot import AotDispatchCache
 from .events import EventStager, MemEvents
 from .topology import FlatTopology
@@ -95,6 +95,9 @@ __all__ = [
     "analyze_any",
     "analyze_ref",
     "bucket_pow2",
+    "collect_dispatch",
+    "count_dispatch",
+    "enqueue_dispatch",
     "plan_cascade",
     "plan_chain",
     "serial_queue_ref",
@@ -110,16 +113,20 @@ class DispatchStats:
     ``padded_fraction`` is the fraction of leading-axis rows that were
     bucket/alignment padding — wasted compute the caller can act on.
 
-    The pipeline breakdown splits the dispatch wall clock: ``stage_s``
-    host staging (pack/fill, zero argsort on the pipeline path),
-    ``transfer_s`` H2D placement, ``compile_s`` AOT lowering (nonzero only
-    on a cache miss — steady state is 0), ``compute_s`` time spent blocked
-    on device execution (under the engine's overlapped dispatcher this is
-    only the *exposed* compute, the part H2D/staging of the next batch
-    could not hide).  ``donated`` records whether the dispatch reused the
-    staged device buffers in place; ``aot_cache_hit`` whether it ran a
-    pre-compiled executable.  Non-pipeline dispatches leave all six at
-    their defaults.
+    The timing split of the dispatch's wall clock, each part measured by
+    the :mod:`~repro.core.spans` span of the same name: ``stage_s`` host
+    staging (pack/fill plus the scale and window buffers), ``transfer_s``
+    H2D placement, ``compile_s`` compiles (an AOT miss, or backend compile
+    seconds seen inside the call — steady state is 0), ``enqueue_s`` the
+    call of the executable, ``wait_s`` the block on its outputs and
+    ``d2h_s`` their copy to the host.  ``compute_s`` is ``enqueue_s +
+    wait_s + d2h_s``: under the engine's overlapped dispatcher only the
+    *exposed* compute, the part staging and H2D of the next batch could
+    not hide.  ``slots`` counts the dispatched plane's slots (B × N, × K
+    where stacked) and ``events`` the real events among them.
+    ``donated`` records whether the dispatch reused the staged device
+    buffers in place; ``aot_cache_hit`` whether it ran a pre-compiled
+    executable.
 
     ``qos_classes`` is the number of QoS classes the dispatched graph
     decomposed congestion over (1 = the plain FIFO fabric).
@@ -132,10 +139,46 @@ class DispatchStats:
     stage_s: float = 0.0
     transfer_s: float = 0.0
     compile_s: float = 0.0
-    compute_s: float = 0.0
+    enqueue_s: float = 0.0
+    wait_s: float = 0.0
+    d2h_s: float = 0.0
+    slots: int = 0
+    events: int = 0
     donated: bool = False
     aot_cache_hit: bool = False
     qos_classes: int = 1
+
+    @property
+    def compute_s(self) -> float:
+        return self.enqueue_s + self.wait_s + self.d2h_s
+
+
+def enqueue_dispatch(fn, *args, **kwargs) -> Tuple[Any, float, float]:
+    """Call an executable or jitted function under the ``cxlsim.enqueue``
+    span; returns ``(outputs, enqueue_s, compile_s)``.  Backend compile
+    seconds seen on this thread during the call (a cold jit) are moved
+    out of ``enqueue_s`` into ``compile_s``."""
+    c0 = spans.compile_seconds()
+    with spans.span("cxlsim.enqueue") as sp:
+        out = fn(*args, **kwargs)
+    compile_s = spans.compile_seconds() - c0
+    return out, sp.seconds - compile_s, compile_s
+
+
+def collect_dispatch(out) -> Tuple[Any, float, float]:
+    """Block on a dispatch's outputs (``cxlsim.wait``), then copy them to
+    the host (``cxlsim.d2h``); returns ``(host_outputs, wait_s, d2h_s)``."""
+    with spans.span("cxlsim.wait") as wait:
+        jax.block_until_ready(out)
+    with spans.span("cxlsim.d2h") as d2h:
+        host = jax.device_get(out)
+    return host, wait.seconds, d2h.seconds
+
+
+def count_dispatch(slots: int, events: int) -> None:
+    """The per-dispatch slot and event counters of :mod:`spans`."""
+    spans.count("cxlsim.slots", slots)
+    spans.count("cxlsim.events", events)
 
 
 def _opt_add(a: Optional[np.ndarray], b: Optional[np.ndarray]) -> Optional[np.ndarray]:
@@ -1306,8 +1349,8 @@ class PendingBatch:
     returns the :class:`DelayBreakdown`; until then the caller is free to
     stage and launch the *next* batch — the engine's overlapped dispatcher
     does exactly that, so batch k+1's staging and H2D run while batch k
-    computes.  ``stats.compute_s`` is finalized at finish time with the
-    exposed device wait."""
+    computes.  ``stats.wait_s`` and ``stats.d2h_s`` are filled at finish
+    time with the exposed device wait and the copy back."""
 
     analyzer: "EpochAnalyzer"
     out: Optional[tuple]
@@ -1319,17 +1362,12 @@ class PendingBatch:
         if self.out is None:
             a.last_dispatch = self.stats
             return DelayBreakdown.zero(P, S, H)
-        t0 = time.perf_counter()
         # the single host-boundary crossing for the whole batch; the
         # pipeline dispatch's trailing (t_fin, idx_pack) leaves stay on
         # device and are simply dropped
-        lat, cong, bw, ppl, psc, psb, phl, phc, phb, pcc = jax.device_get(
-            self.out[:10]
-        )
-        stats = dataclasses.replace(
-            self.stats,
-            compute_s=self.stats.compute_s + (time.perf_counter() - t0),
-        )
+        host, wait_s, d2h_s = collect_dispatch(self.out[:10])
+        lat, cong, bw, ppl, psc, psb, phl, phc, phb, pcc = host
+        stats = dataclasses.replace(self.stats, wait_s=wait_s, d2h_s=d2h_s)
         a.last_dispatch = stats
         self.stats = stats
         self.out = None
@@ -1544,28 +1582,27 @@ class EpochAnalyzer:
         if not pairs:
             return PendingBatch(self, None, DispatchStats(rows=0))
         traces = [tr for tr, _ in pairs]
-        t0 = time.perf_counter()
-        n_bucket = self._bucket(max(tr.n for tr in traces))
-        b_bucket = self._bucket(len(traces), floor=1)
-        st = stager if stager is not None else self._stager
-        chain = self._chain_plan
-        caps = None
-        if chain is not None:
-            buf, pack, caps = st.stage_packed(
-                traces, b_bucket, n_bucket, chain.enter_stage,
-                len(chain.stage_order),
-            )
-        else:
-            buf = st.stage(traces, b_bucket, n_bucket)
-            pack = None
-        np_dtype = np.dtype(jnp.dtype(self.dtype).name)
-        scale_buf = np.ones((b_bucket, H * P), np_dtype)
-        for row, (_, sc) in enumerate(pairs):
-            if sc is not None:
-                scale_buf[row] = sc
-        span = np.maximum(buf["span"], self.bw_window_ns)
-        bw_window = np.maximum(span / self.n_windows, 1.0).astype(np_dtype)
-        t1 = time.perf_counter()
+        with spans.span("cxlsim.stage") as stage:
+            n_bucket = self._bucket(max(tr.n for tr in traces))
+            b_bucket = self._bucket(len(traces), floor=1)
+            st = stager if stager is not None else self._stager
+            chain = self._chain_plan
+            caps = None
+            if chain is not None:
+                buf, pack, caps = st.stage_packed(
+                    traces, b_bucket, n_bucket, chain.enter_stage,
+                    len(chain.stage_order),
+                )
+            else:
+                buf = st.stage(traces, b_bucket, n_bucket)
+                pack = None
+            np_dtype = np.dtype(jnp.dtype(self.dtype).name)
+            scale_buf = np.ones((b_bucket, H * P), np_dtype)
+            for row, (_, sc) in enumerate(pairs):
+                if sc is not None:
+                    scale_buf[row] = sc
+            span = np.maximum(buf["span"], self.bw_window_ns)
+            bw_window = np.maximum(span / self.n_windows, 1.0).astype(np_dtype)
 
         from repro.distributed.sharding import timed_device_put
 
@@ -1579,33 +1616,39 @@ class EpochAnalyzer:
                 buf["t"], buf["pool"], buf["bytes"], buf["weight"],
                 buf["host"], buf["qos"], buf["valid"], bw_window, scale_buf,
             )
-        dev_args, transfer_s = timed_device_put(list(host_args))
+        with spans.span("cxlsim.h2d") as h2d:
+            dev_args = timed_device_put(list(host_args))
 
         compile_s = 0.0
         aot_hit = False
         donated = False
         if self.pipeline:
             key, build = self._aot_build(chain, caps, b_bucket, n_bucket, dev_args)
-            c0 = time.perf_counter()
-            exe, aot_hit = self._aot.get(key, build)
-            if not aot_hit:
-                compile_s = time.perf_counter() - c0
-            t2 = time.perf_counter()
+            built: List[float] = []
+
+            def build_in_span():
+                with spans.span("cxlsim.compile") as c:
+                    exe = build()
+                built.append(c.seconds)
+                return exe
+
+            exe, aot_hit = self._aot.get(key, build_in_span)
+            compile_s = sum(built)
             if chain is not None:
-                out = exe(
-                    *dev_args, self._pool_lat, self._local_lat, self._route,
+                out, enqueue_s, jit_compile_s = enqueue_dispatch(
+                    exe, *dev_args, self._pool_lat, self._local_lat, self._route,
                     self._stt, self._bw,
                 )
                 donated = bool(dev_args[0].is_deleted())
             else:
-                out = exe(
-                    *dev_args, self._bits_table, self._pool_lat,
+                out, enqueue_s, jit_compile_s = enqueue_dispatch(
+                    exe, *dev_args, self._bits_table, self._pool_lat,
                     self._local_lat, self._route, self._stt, self._bw,
                     self._disc, self._weights,
                 )
         else:
-            t2 = time.perf_counter()
-            out = self._batch_fn(
+            out, enqueue_s, jit_compile_s = enqueue_dispatch(
+                self._batch_fn,
                 *dev_args, self._bits_table, self._pool_lat, self._local_lat,
                 self._route, self._stt, self._bw, self._disc, self._weights,
                 stage_order=self._stage_order,
@@ -1616,16 +1659,20 @@ class EpochAnalyzer:
                 merge_plan=self._merge_plan,
                 qos_on=self.qos_on,
             )
-        dispatch_s = time.perf_counter() - t2
+        slots = b_bucket * n_bucket
+        events = sum(tr.n for tr in traces)
+        count_dispatch(slots, events)
         stats = DispatchStats(
             devices_used=1,
             shard_rows=0,
             rows=len(traces),
             padded_fraction=float(b_bucket - len(traces)) / b_bucket,
-            stage_s=t1 - t0,
-            transfer_s=transfer_s,
-            compile_s=compile_s,
-            compute_s=dispatch_s,
+            stage_s=stage.seconds,
+            transfer_s=h2d.seconds,
+            compile_s=compile_s + jit_compile_s,
+            enqueue_s=enqueue_s,
+            slots=slots,
+            events=events,
             donated=donated,
             aot_cache_hit=aot_hit,
             qos_classes=self.flat.n_qos_classes,
@@ -1667,74 +1714,9 @@ class EpochAnalyzer:
         so its dispatcher thread never shares mutable buffers with callers
         analyzing synchronously on this analyzer.
         """
-        if self.pipeline:
-            # the synchronous special case of the overlapped pipeline:
-            # launch, then immediately block
-            return self.launch_batch(traces, lat_scales, stager=stager).finish()
-        P, S = self.flat.n_pools, self.flat.n_switches
-        H = self.flat.n_hosts
-        pairs = self._clean_pairs(traces, lat_scales)
-        if not pairs:
-            return DelayBreakdown.zero(P, S, H)
-        traces = [tr for tr, _ in pairs]
-        n_bucket = self._bucket(max(tr.n for tr in traces))
-        b_bucket = self._bucket(len(traces), floor=1)
-        st = stager if stager is not None else self._stager
-        buf = st.stage(traces, b_bucket, n_bucket)
-        scale_buf = np.ones((b_bucket, H * P), np.dtype(jnp.dtype(self.dtype).name))
-        for row, (_, sc) in enumerate(pairs):
-            if sc is not None:
-                scale_buf[row] = sc
-        # per-epoch window length: n_windows static windows tile each span
-        span = np.maximum(buf["span"], self.bw_window_ns)
-        bw_window = np.maximum(span / self.n_windows, 1.0)
-        self.last_dispatch = DispatchStats(
-            devices_used=1,
-            shard_rows=0,
-            rows=len(traces),
-            padded_fraction=float(b_bucket - len(traces)) / b_bucket,
-            qos_classes=self.flat.n_qos_classes,
-        )
-        out = self._batch_fn(
-            jnp.asarray(buf["t"]),
-            jnp.asarray(buf["pool"]),
-            jnp.asarray(buf["bytes"]),
-            jnp.asarray(buf["weight"]),
-            jnp.asarray(buf["host"]),
-            jnp.asarray(buf["qos"]),
-            jnp.asarray(buf["valid"]),
-            jnp.asarray(bw_window, self.dtype),
-            jnp.asarray(scale_buf),
-            self._bits_table,
-            self._pool_lat,
-            self._local_lat,
-            self._route,
-            self._stt,
-            self._bw,
-            self._disc,
-            self._weights,
-            stage_order=self._stage_order,
-            n_windows=self.n_windows,
-            n_hosts=H,
-            impl=self.impl,
-            fused=self.fused,
-            merge_plan=self._merge_plan,
-            qos_on=self.qos_on,
-        )
-        # the single host-boundary crossing for the whole batch
-        lat, cong, bw, ppl, psc, psb, phl, phc, phb, pcc = jax.device_get(out)
-        return DelayBreakdown(
-            float(lat),
-            float(cong),
-            float(bw),
-            ppl.astype(np.float64),
-            psc.astype(np.float64),
-            psb.astype(np.float64),
-            phl.astype(np.float64),
-            phc.astype(np.float64),
-            phb.astype(np.float64),
-            pcc.astype(np.float64),
-        )
+        # the synchronous special case of the overlapped dispatch: launch,
+        # then immediately block
+        return self.launch_batch(traces, lat_scales, stager=stager).finish()
 
     def analyze_batch_multi(
         self,
@@ -1806,54 +1788,45 @@ class EpochAnalyzer:
             len(rows),
             what="coalesced session dispatch",
         )
-        n_bucket = self._bucket(
-            max(tr.n for i in rows for tr, _ in cleaned[i])
-        )
-        b_bucket = self._bucket(max(len(cleaned[i]) for i in rows), floor=1)
-        k_bucket = pad_to_multiple(self._bucket(len(rows), floor=1), n_shards)
-        st = stager if stager is not None else self._stager
-        buf = st.stage_stack(
-            [[tr for tr, _ in cleaned[i]] for i in rows],
-            k_bucket, b_bucket, n_bucket,
-        )
-        scale_buf = np.ones(
-            (k_bucket, b_bucket, H * P), np.dtype(jnp.dtype(self.dtype).name)
-        )
-        for k, i in enumerate(rows):
-            for row, (_, sc) in enumerate(cleaned[i]):
-                if sc is not None:
-                    scale_buf[k, row] = sc
-        span = np.maximum(buf["span"], self.bw_window_ns)
-        bw_window = np.maximum(span / self.n_windows, 1.0)
-        self.last_dispatch = DispatchStats(
-            devices_used=n_shards,
-            shard_rows=k_bucket // n_shards if mesh is not None else 0,
-            rows=len(rows),
-            padded_fraction=float(k_bucket - len(rows)) / k_bucket,
-            qos_classes=self.flat.n_qos_classes,
-        )
+        with spans.span("cxlsim.stage") as stage:
+            n_bucket = self._bucket(
+                max(tr.n for i in rows for tr, _ in cleaned[i])
+            )
+            b_bucket = self._bucket(max(len(cleaned[i]) for i in rows), floor=1)
+            k_bucket = pad_to_multiple(self._bucket(len(rows), floor=1), n_shards)
+            st = stager if stager is not None else self._stager
+            buf = st.stage_stack(
+                [[tr for tr, _ in cleaned[i]] for i in rows],
+                k_bucket, b_bucket, n_bucket,
+            )
+            np_dtype = np.dtype(jnp.dtype(self.dtype).name)
+            scale_buf = np.ones((k_bucket, b_bucket, H * P), np_dtype)
+            for k, i in enumerate(rows):
+                for row, (_, sc) in enumerate(cleaned[i]):
+                    if sc is not None:
+                        scale_buf[k, row] = sc
+            span = np.maximum(buf["span"], self.bw_window_ns)
+            bw_window = np.maximum(span / self.n_windows, 1.0).astype(np_dtype)
         if mesh is not None:
             self.sharded_dispatches += 1
         put_k = lambda a: shard_rows(mesh, jnp.asarray(a))
         put_r = lambda a: replicated(mesh, a)
-        res = self._multi_fn(
-            put_k(buf["t"]),
-            put_k(buf["pool"]),
-            put_k(buf["bytes"]),
-            put_k(buf["weight"]),
-            put_k(buf["host"]),
-            put_k(buf["qos"]),
-            put_k(buf["valid"]),
-            put_k(jnp.asarray(bw_window, self.dtype)),
-            put_k(scale_buf),
-            put_r(self._bits_table),
-            put_r(self._pool_lat),
-            put_r(self._local_lat),
-            put_r(self._route),
-            put_r(self._stt),
-            put_r(self._bw),
-            put_r(self._disc),
-            put_r(self._weights),
+        with spans.span("cxlsim.h2d") as h2d:
+            dev_k = [
+                put_k(buf[f])
+                for f in ("t", "pool", "bytes", "weight", "host", "qos", "valid")
+            ] + [put_k(bw_window), put_k(scale_buf)]
+            dev_r = [
+                put_r(a)
+                for a in (
+                    self._bits_table, self._pool_lat, self._local_lat,
+                    self._route, self._stt, self._bw, self._disc, self._weights,
+                )
+            ]
+        res, enqueue_s, compile_s = enqueue_dispatch(
+            self._multi_fn,
+            *dev_k,
+            *dev_r,
             stage_order=self._stage_order,
             n_windows=self.n_windows,
             n_hosts=H,
@@ -1863,7 +1836,26 @@ class EpochAnalyzer:
             qos_on=self.qos_on,
         )
         # one [K, ...] transfer for every coalesced session
-        lat, cong, bw, ppl, psc, psb, phl, phc, phb, pcc = jax.device_get(res)
+        host, wait_s, d2h_s = collect_dispatch(res)
+        lat, cong, bw, ppl, psc, psb, phl, phc, phb, pcc = host
+        slots = k_bucket * b_bucket * n_bucket
+        events = sum(tr.n for i in rows for tr, _ in cleaned[i])
+        count_dispatch(slots, events)
+        self.last_dispatch = DispatchStats(
+            devices_used=n_shards,
+            shard_rows=k_bucket // n_shards if mesh is not None else 0,
+            rows=len(rows),
+            padded_fraction=float(k_bucket - len(rows)) / k_bucket,
+            stage_s=stage.seconds,
+            transfer_s=h2d.seconds,
+            compile_s=compile_s,
+            enqueue_s=enqueue_s,
+            wait_s=wait_s,
+            d2h_s=d2h_s,
+            slots=slots,
+            events=events,
+            qos_classes=self.flat.n_qos_classes,
+        )
         for k, i in enumerate(rows):
             out[i] = DelayBreakdown(
                 float(lat[k]),

@@ -68,6 +68,7 @@ from .engine import AnalysisEngine, EngineClient, EngineHandle, fold_dispatch_st
 from .events import MemEvents, RegionMap
 from .migration import MigrationSimulator
 from .policy import PlacementPolicy, capacity_check
+from .spans import span
 from .timer import EpochSchedule
 from .topology import Topology
 from .tracer import HardwareModel, Phase, TPU_V5E, synthesize_step_trace
@@ -107,6 +108,11 @@ class SimReport:
     transfer_s: float = 0.0  # explicit H2D device_put time
     compile_s: float = 0.0  # AOT lowering time (first dispatch per shape only)
     compute_s: float = 0.0  # exposed device compute (post-overlap)
+    enqueue_s: float = 0.0  # the executable calls (part of compute_s)
+    wait_s: float = 0.0  # blocked on device outputs (part of compute_s)
+    d2h_s: float = 0.0  # outputs copied to the host (part of compute_s)
+    slots: int = 0  # dispatched plane slots (B x N per dispatch)
+    events: int = 0  # real events among them
     donated_dispatches: int = 0  # dispatches whose input planes were donated
     aot_cache_hits: int = 0  # dispatches served from the AOT executable cache
 
@@ -160,6 +166,11 @@ class SimReport:
             "transfer_s": self.transfer_s,
             "compile_s": self.compile_s,
             "compute_s": self.compute_s,
+            "enqueue_s": self.enqueue_s,
+            "wait_s": self.wait_s,
+            "d2h_s": self.d2h_s,
+            "slots": self.slots,
+            "events": self.events,
             "donated_dispatches": self.donated_dispatches,
             "aot_cache_hits": self.aot_cache_hits,
             "qos_classes": self.qos_classes,
@@ -413,7 +424,8 @@ class AttachedProgram(EngineClient):
         In async mode the step's epoch batch is submitted *before* the
         native dispatch, so the analyzer works while the step executes;
         totals become visible via :attr:`report` (which flushes)."""
-        batch, coh_ns, scales = self._epoch_batch()
+        with span("cxlsim.epoch_batch"):
+            batch, coh_ns, scales = self._epoch_batch()
         if self._handle is not None:
             n_epochs = len(batch)
             self._handle.submit(
@@ -422,13 +434,12 @@ class AttachedProgram(EngineClient):
                 fold=lambda bd, elapsed: self._fold(bd, coh_ns, elapsed, n_epochs),
             )
 
-        t0 = time.perf_counter()
-        out = self.step_fn(*args, **kwargs)
-        jax.block_until_ready(out)
-        native = time.perf_counter() - t0
+        with span("cxlsim.native") as native:
+            out = self.step_fn(*args, **kwargs)
+            jax.block_until_ready(out)
         with self._report_lock:
-            self._report.native_s += native
-            self._report.simulated_s += native
+            self._report.native_s += native.seconds
+            self._report.simulated_s += native.seconds
             self._report.steps += 1
 
         if self._handle is None:

@@ -48,7 +48,6 @@ from __future__ import annotations
 
 import dataclasses
 import threading
-import time
 from collections import deque
 from concurrent.futures import Future, InvalidStateError
 from typing import Callable, Deque, Dict, List, Optional, Sequence, Tuple
@@ -57,8 +56,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..analysis.annotations import guarded_by, single_threaded
-from .analyzer import DelayBreakdown, EpochAnalyzer, PendingBatch, analyze_any
+from .analyzer import (
+    DelayBreakdown, DispatchStats, EpochAnalyzer, PendingBatch, analyze_any,
+)
 from .events import EventStager, MemEvents
+from .spans import span
 
 __all__ = [
     "AnalysisEngine",
@@ -104,12 +106,15 @@ def fold_dispatch_stats(report, stats, group_size: int) -> None:
     ``padded_waste`` / ``coalesced_group_size`` fields (SimReport,
     FabricReport).  Device counts, shard widths and group sizes keep their
     maxima (did sharding/coalescing ever engage, and how wide); padded
-    waste keeps the worst fraction seen.  The pipeline timing split
-    (``stage_s``/``transfer_s``/``compile_s``/``compute_s``) accumulates
-    across dispatches, and ``donated_dispatches``/``aot_cache_hits`` count
-    how often donation and the AOT cache engaged — coalesced dispatches
-    report zero timing on every member handle, so cross-session sharing
-    never double-counts.  Callers hold their report lock.
+    waste keeps the worst fraction seen.  The timing split
+    (``stage_s``/``transfer_s``/``compile_s``/``enqueue_s``/``wait_s``/
+    ``d2h_s``, and ``compute_s``, their last three summed) and the
+    ``slots``/``events`` counts accumulate across dispatches, and
+    ``donated_dispatches``/``aot_cache_hits`` count how often donation and
+    the AOT cache engaged.  A coalesced dispatch's timing and counts are
+    carried by its first session only (the others see them zeroed), so
+    cross-session sharing never double-counts.  Callers hold their report
+    lock.
     """
     if stats is not None:
         report.devices_used = max(report.devices_used, stats.devices_used)
@@ -119,6 +124,11 @@ def fold_dispatch_stats(report, stats, group_size: int) -> None:
         report.transfer_s += stats.transfer_s
         report.compile_s += stats.compile_s
         report.compute_s += stats.compute_s
+        report.enqueue_s += stats.enqueue_s
+        report.wait_s += stats.wait_s
+        report.d2h_s += stats.d2h_s
+        report.slots += stats.slots
+        report.events += stats.events
         if stats.donated:
             report.donated_dispatches += 1
         if stats.aot_cache_hit:
@@ -127,6 +137,15 @@ def fold_dispatch_stats(report, stats, group_size: int) -> None:
         report.coalesced_group_size = max(
             report.coalesced_group_size, int(group_size)
         )
+
+
+def _peer_view(stats: DispatchStats) -> DispatchStats:
+    """A coalesced peer's copy of a dispatch's stats: the sharding facts,
+    without the timing and counts the group's first session carries."""
+    return dataclasses.replace(
+        stats, stage_s=0.0, transfer_s=0.0, compile_s=0.0, enqueue_s=0.0,
+        wait_s=0.0, d2h_s=0.0, slots=0, events=0,
+    )
 
 
 @dataclasses.dataclass
@@ -215,9 +234,11 @@ class EngineHandle:
         with eng._cv:
             self._check_open_locked()
             eng._ensure_thread_locked()
-            while self._inflight >= self.max_inflight:
-                self._check_open_locked()
-                eng._cv.wait(1.0)
+            if self._inflight >= self.max_inflight:
+                with span("cxlsim.submit_wait"):
+                    while self._inflight >= self.max_inflight:
+                        self._check_open_locked()
+                        eng._cv.wait(1.0)
             self._check_open_locked()
             self._inflight += 1
             fut: Future = Future()
@@ -531,121 +552,124 @@ class AnalysisEngine:
         and surfaced by :meth:`_finish`."""
         stager = self._stager_for(group[0].handle.analyzer)
         live = group
-        t0 = time.perf_counter()
-        try:
-            if len(group) > 1:
-                # per-session validation BEFORE stacking: one session's bad
-                # trace (unreachable route, scales mismatch) must drop only
-                # that session's batch, never its coalesced peers'
-                live = []
-                for sub in group:
-                    try:
-                        sub.handle.analyzer._clean_pairs(sub.traces, sub.scales)
-                    except BaseException as e:
-                        with self._cv:
-                            sub.handle._record_error_locked(e, len(sub.traces))
-                        self._resolve(sub.future, error=e)
-                    else:
-                        live.append(sub)
-            pending: Optional[PendingBatch] = None
-            bds: Optional[List[DelayBreakdown]] = None
-            if not live:
-                bds = []
-            elif (
-                len(live) == 1
-                and isinstance(live[0].handle.analyzer, EpochAnalyzer)
-                and type(live[0].handle.analyzer).analyze_batch
-                is EpochAnalyzer.analyze_batch
-            ):
-                # the overlapped fast path talks to launch_batch directly;
-                # subclasses that override analyze_batch (tests inject
-                # failures there) keep the classic synchronous route
-                sub = live[0]
-                pending = sub.handle.analyzer.launch_batch(
-                    sub.traces, sub.scales, stager=stager
-                )
-            elif len(live) == 1:
-                sub = live[0]
-                bds = [sub.handle._analyze(sub.traces, sub.scales, stager)]
-            else:
-                bds = live[0].handle.analyzer.analyze_batch_multi(
-                    [s.traces for s in live],
-                    [s.scales for s in live],
-                    stager=stager,
-                    mesh=self.mesh,
-                )
-            return _Launched(
-                group, live, pending, bds, time.perf_counter() - t0, None
-            )
-        except BaseException as e:
-            return _Launched(group, live, None, None, time.perf_counter() - t0, e)
+        pending: Optional[PendingBatch] = None
+        bds: Optional[List[DelayBreakdown]] = None
+        error: Optional[BaseException] = None
+        with span("cxlsim.launch") as launch:
+            try:
+                if len(group) > 1:
+                    # per-session validation BEFORE stacking: one session's
+                    # bad trace (unreachable route, scales mismatch) must
+                    # drop only that session's batch, never its peers'
+                    live = []
+                    for sub in group:
+                        try:
+                            sub.handle.analyzer._clean_pairs(sub.traces, sub.scales)
+                        except BaseException as e:
+                            with self._cv:
+                                sub.handle._record_error_locked(e, len(sub.traces))
+                            self._resolve(sub.future, error=e)
+                        else:
+                            live.append(sub)
+                if not live:
+                    bds = []
+                elif (
+                    len(live) == 1
+                    and isinstance(live[0].handle.analyzer, EpochAnalyzer)
+                    and type(live[0].handle.analyzer).analyze_batch
+                    is EpochAnalyzer.analyze_batch
+                ):
+                    # the overlapped fast path talks to launch_batch
+                    # directly; subclasses that override analyze_batch
+                    # (tests inject failures there) keep the classic
+                    # synchronous route
+                    sub = live[0]
+                    pending = sub.handle.analyzer.launch_batch(
+                        sub.traces, sub.scales, stager=stager
+                    )
+                elif len(live) == 1:
+                    sub = live[0]
+                    bds = [sub.handle._analyze(sub.traces, sub.scales, stager)]
+                else:
+                    bds = live[0].handle.analyzer.analyze_batch_multi(
+                        [s.traces for s in live],
+                        [s.scales for s in live],
+                        stager=stager,
+                        mesh=self.mesh,
+                    )
+            except BaseException as e:
+                pending, bds, error = None, None, e
+        return _Launched(group, live, pending, bds, launch.seconds, error)
 
     def _finish(self, launched: "_Launched") -> None:
         """Resolve one launched group: block on the device result if it was
         an overlapped launch, run folds, resolve futures, release inflight
         slots."""
         group, live = launched.group, launched.live
-        try:
-            if launched.error is not None:
-                raise launched.error
-            t0 = time.perf_counter()
-            if launched.pending is not None:
-                bds: List[DelayBreakdown] = [launched.pending.finish()]
-            else:
-                bds = launched.bds
-            # launch work + exposed finish wait; the overlap gap (spent
-            # launching the NEXT group) is deliberately excluded
-            elapsed = launched.launch_s + (time.perf_counter() - t0)
-            if live:
-                # written before the fold loop so fold callbacks (and any
-                # reader after the future resolves) see this dispatch's
-                # sharding stats on their own handle, even when a peer's
-                # analyzer ran the stacked dispatch
-                stats = getattr(
-                    live[0].handle.analyzer, "last_dispatch", None
-                )
-                for sub in live:
-                    sub.handle.last_dispatch = stats
-                    sub.handle.last_group_size = len(live)
-            total_epochs = sum(len(s.traces) for s in live)
-            with self._cv:
-                if live:
-                    self.dispatches += 1
-                if len(live) > 1:
-                    self.coalesced_dispatches += 1
-                    self.max_coalesced_sessions = max(
-                        self.max_coalesced_sessions, len(live)
-                    )
-            for sub, bd in zip(live, bds):
-                # the dispatch's compute seconds are attributed across the
-                # coalesced group by epoch share (evenly when all batches
-                # are empty) so summed analyzer_s never exceeds real cost
-                if len(live) == 1:
-                    share = elapsed
-                elif total_epochs:
-                    share = elapsed * len(sub.traces) / total_epochs
+        with span("cxlsim.finish"):
+            try:
+                if launched.error is not None:
+                    raise launched.error
+                # launch work + exposed finish wait; the overlap gap (spent
+                # launching the NEXT group) is deliberately excluded
+                elapsed = launched.launch_s
+                if launched.pending is not None:
+                    bds: List[DelayBreakdown] = [launched.pending.finish()]
+                    elapsed += launched.pending.stats.wait_s + launched.pending.stats.d2h_s
                 else:
-                    share = elapsed / len(live)
-                try:
-                    if sub.fold is not None:
-                        sub.fold(bd, share)
-                    self._resolve(sub.future, result=bd)
-                except BaseException as e:  # analyzed but not folded: dropped
-                    with self._cv:
+                    bds = launched.bds
+                if live:
+                    # written before the fold loop so fold callbacks (and any
+                    # reader after the future resolves) see this dispatch's
+                    # sharding stats on their own handle, even when a peer's
+                    # analyzer ran the stacked dispatch
+                    stats = getattr(live[0].handle.analyzer, "last_dispatch", None)
+                    for j, sub in enumerate(live):
+                        sub.handle.last_dispatch = (
+                            stats if j == 0 or stats is None else _peer_view(stats)
+                        )
+                        sub.handle.last_group_size = len(live)
+                total_epochs = sum(len(s.traces) for s in live)
+                with self._cv:
+                    if live:
+                        self.dispatches += 1
+                    if len(live) > 1:
+                        self.coalesced_dispatches += 1
+                        self.max_coalesced_sessions = max(
+                            self.max_coalesced_sessions, len(live)
+                        )
+                with span("cxlsim.fold"):
+                    for sub, bd in zip(live, bds):
+                        # the dispatch's compute seconds are attributed across
+                        # the coalesced group by epoch share (evenly when all
+                        # batches are empty) so summed analyzer_s never
+                        # exceeds real cost
+                        if len(live) == 1:
+                            share = elapsed
+                        elif total_epochs:
+                            share = elapsed * len(sub.traces) / total_epochs
+                        else:
+                            share = elapsed / len(live)
+                        try:
+                            if sub.fold is not None:
+                                sub.fold(bd, share)
+                            self._resolve(sub.future, result=bd)
+                        except BaseException as e:  # analyzed, not folded: dropped
+                            with self._cv:
+                                sub.handle._record_error_locked(e, len(sub.traces))
+                            self._resolve(sub.future, error=e)
+            except BaseException as e:  # whole dispatch failed: every live
+                with self._cv:  # batch dropped (validation failures already recorded)
+                    for sub in live:
                         sub.handle._record_error_locked(e, len(sub.traces))
-                    self._resolve(sub.future, error=e)
-        except BaseException as e:  # whole dispatch failed: every live batch
-            with self._cv:  # dropped (validation failures already recorded)
                 for sub in live:
-                    sub.handle._record_error_locked(e, len(sub.traces))
-            for sub in live:
-                self._resolve(sub.future, error=e)
-        finally:
-            with self._cv:
-                self._active -= 1
-                for sub in group:
-                    sub.handle._inflight -= 1
-                self._cv.notify_all()
+                    self._resolve(sub.future, error=e)
+            finally:
+                with self._cv:
+                    self._active -= 1
+                    for sub in group:
+                        sub.handle._inflight -= 1
+                    self._cv.notify_all()
 
     @staticmethod
     def _resolve(fut: Future, result=None, error=None) -> None:

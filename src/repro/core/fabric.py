@@ -73,6 +73,7 @@ from .engine import AnalysisEngine, EngineClient, EngineHandle, fold_dispatch_st
 from .events import MemEvents, RegionMap, concat_events
 from .migration import LocalBudget, MigrationConfig, MigrationSimulator
 from .policy import PlacementPolicy
+from .spans import span
 from .timer import EpochSchedule
 from .topology import Topology
 from .tracer import HardwareModel, Phase, TPU_V5E, synthesize_step_trace
@@ -157,6 +158,11 @@ class FabricReport:
     transfer_s: float = 0.0
     compile_s: float = 0.0
     compute_s: float = 0.0
+    enqueue_s: float = 0.0
+    wait_s: float = 0.0
+    d2h_s: float = 0.0
+    slots: int = 0
+    events: int = 0
     donated_dispatches: int = 0
     aot_cache_hits: int = 0
     qos_classes: int = 1
@@ -203,6 +209,11 @@ class FabricReport:
             "transfer_s": self.transfer_s,
             "compile_s": self.compile_s,
             "compute_s": self.compute_s,
+            "enqueue_s": self.enqueue_s,
+            "wait_s": self.wait_s,
+            "d2h_s": self.d2h_s,
+            "slots": self.slots,
+            "events": self.events,
             "donated_dispatches": self.donated_dispatches,
             "aot_cache_hits": self.aot_cache_hits,
             "qos_classes": self.qos_classes,
@@ -584,7 +595,8 @@ class FabricSession(EngineClient):
         merged timelines are cached: per-round analyzer overhead is a
         reported quantity (the paper's accounting), matching how
         ``CXLMemSim.attach`` re-analyzes its cached trace each step."""
-        merged, miss_ns, scales = self._merged_round()
+        with span("cxlsim.merge"):
+            merged, miss_ns, scales = self._merged_round()
         n_epochs = len(merged)
         stats = self._round_stats()
 
@@ -613,10 +625,10 @@ class FabricSession(EngineClient):
         natives: List[float] = []
         for h, tenant in enumerate(self.tenants):
             if tenant.step_fn is not None:
-                t0 = time.perf_counter()
-                out = tenant.step_fn(*tenant.step_args)
-                jax.block_until_ready(out)
-                natives.append(time.perf_counter() - t0)
+                with span("cxlsim.native") as native:
+                    out = tenant.step_fn(*tenant.step_args)
+                    jax.block_until_ready(out)
+                natives.append(native.seconds)
             else:
                 natives.append(self._tenant_epochs(h)[1])
         with self._report_lock:

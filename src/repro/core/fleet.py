@@ -48,18 +48,21 @@ slowdown.
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import spans
 from .analyzer import (
     DelayBreakdown,
     DispatchStats,
     _analyze_fleet_jax,
     bucket_pow2,
+    collect_dispatch,
+    count_dispatch,
+    enqueue_dispatch,
     plan_cascade,
 )
 from .events import EventStager, MemEvents, RegionMap, concat_events
@@ -564,12 +567,11 @@ class FleetSim:
         n_bucket = bucket_pow2(max(n_max, 1))
         b_bucket = bucket_pow2(B, floor=1)
         k_bucket = pad_to_multiple(bucket_pow2(K, floor=1), n_shards)
-        t_stage = time.perf_counter()
-        buf = self._stager.stage_stack(rack_traces, k_bucket, b_bucket, n_bucket)
-        span = np.maximum(buf["span"], self.bw_window_ns)
-        bw_window = np.maximum(span / self.n_windows, 1.0)
-        scale = np.ones((k_bucket, b_bucket, V), self._np_dtype)
-        stage_s = time.perf_counter() - t_stage
+        with spans.span("cxlsim.stage") as stage:
+            buf = self._stager.stage_stack(rack_traces, k_bucket, b_bucket, n_bucket)
+            span = np.maximum(buf["span"], self.bw_window_ns)
+            bw_window = np.maximum(span / self.n_windows, 1.0)
+            scale = np.ones((k_bucket, b_bucket, V), self._np_dtype)
 
         ls = self._leaf_stack
 
@@ -585,38 +587,28 @@ class FleetSim:
         self.dispatch_count += 1
         put_k = lambda a: shard_rows(mesh, jnp.asarray(a))
         put_r = lambda a: replicated(mesh, a)
-        t_put = time.perf_counter()
-        dev_args = (
-            put_k(buf["t"]),
-            put_k(buf["pool"]),
-            put_k(buf["bytes"]),
-            put_k(buf["weight"]),
-            put_k(buf["host"]),
-            put_k(buf["qos"]),
-            put_k(buf["valid"]),
-            put_k(jnp.asarray(bw_window, self.dtype)),
-            put_k(scale),
-            put_r(self._bits_table),
-            put_k(pad_k(np.asarray(ls.pool_latency_ns, self._np_dtype))),
-            put_k(pad_k(np.asarray(ls.local_latency_ns, self._np_dtype))),
-            put_r(self._route),
-            put_k(pad_k(np.asarray(ls.switch_stt_ns, self._np_dtype))),
-            put_k(pad_k(np.asarray(ls.switch_bandwidth_gbps, self._np_dtype))),
-            put_k(pad_k(self._disc_stack)),
-            put_k(pad_k(self._weights_stack)),
-        )
-        transfer_s = time.perf_counter() - t_put
-        self.last_dispatch = DispatchStats(
-            devices_used=n_shards,
-            shard_rows=k_bucket // n_shards if mesh is not None else 0,
-            rows=K,
-            padded_fraction=float(k_bucket - K) / k_bucket,
-            stage_s=stage_s,
-            transfer_s=transfer_s,
-            qos_classes=self.n_qos_classes,
-        )
-        t_run = time.perf_counter()
-        out = self._fleet_jit(
+        with spans.span("cxlsim.h2d") as h2d:
+            dev_args = (
+                put_k(buf["t"]),
+                put_k(buf["pool"]),
+                put_k(buf["bytes"]),
+                put_k(buf["weight"]),
+                put_k(buf["host"]),
+                put_k(buf["qos"]),
+                put_k(buf["valid"]),
+                put_k(jnp.asarray(bw_window, self.dtype)),
+                put_k(scale),
+                put_r(self._bits_table),
+                put_k(pad_k(np.asarray(ls.pool_latency_ns, self._np_dtype))),
+                put_k(pad_k(np.asarray(ls.local_latency_ns, self._np_dtype))),
+                put_r(self._route),
+                put_k(pad_k(np.asarray(ls.switch_stt_ns, self._np_dtype))),
+                put_k(pad_k(np.asarray(ls.switch_bandwidth_gbps, self._np_dtype))),
+                put_k(pad_k(self._disc_stack)),
+                put_k(pad_k(self._weights_stack)),
+            )
+        out, enqueue_s, compile_s = enqueue_dispatch(
+            self._fleet_jit,
             *dev_args,
             stage_order=self._stage_order,
             n_windows=self.n_windows,
@@ -626,9 +618,25 @@ class FleetSim:
             merge_plan=self._merge_plan,
             qos_on=self.qos_on,
         )
-        lat, cong, bw, ppl, psc, psb, phl, phc, phb, pcc = jax.device_get(out)
-        self.last_dispatch = dataclasses.replace(
-            self.last_dispatch, compute_s=time.perf_counter() - t_run
+        host, wait_s, d2h_s = collect_dispatch(out)
+        lat, cong, bw, ppl, psc, psb, phl, phc, phb, pcc = host
+        slots = k_bucket * b_bucket * n_bucket
+        events = sum(tr.n for rows in rack_traces for tr in rows)
+        count_dispatch(slots, events)
+        self.last_dispatch = DispatchStats(
+            devices_used=n_shards,
+            shard_rows=k_bucket // n_shards if mesh is not None else 0,
+            rows=K,
+            padded_fraction=float(k_bucket - K) / k_bucket,
+            stage_s=stage.seconds,
+            transfer_s=h2d.seconds,
+            compile_s=compile_s,
+            enqueue_s=enqueue_s,
+            wait_s=wait_s,
+            d2h_s=d2h_s,
+            slots=slots,
+            events=events,
+            qos_classes=self.n_qos_classes,
         )
         return [
             DelayBreakdown(

@@ -37,18 +37,21 @@ from __future__ import annotations
 
 import dataclasses
 import itertools
-import time
 from typing import Callable, Dict, Iterable, List, Mapping, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import spans
 from .analyzer import (
     DelayBreakdown,
     DispatchStats,
     _analyze_sweep_jax,
     bucket_pow2,
+    collect_dispatch,
+    count_dispatch,
+    enqueue_dispatch,
     plan_cascade,
 )
 from .cache import DeviceCacheConfig, DeviceCacheModel
@@ -120,6 +123,7 @@ class SweepResult:
     # phase timing of this run's dispatch (host pack / H2D / device compute)
     stage_s: float = 0.0
     transfer_s: float = 0.0
+    compile_s: float = 0.0  # compiles inside the dispatch (a cold call)
     compute_s: float = 0.0
     qos_classes: int = 1  # QoS class count of this run's dispatch
 
@@ -592,54 +596,61 @@ class ScenarioSuite:
         fd = self.dtype
         # host staging (pack), H2D transfer, then the dispatch proper — the
         # same phase split DispatchStats reports for the epoch pipeline
-        t0 = time.perf_counter()
-        host_r = [
-            stack_np("t"), stack_np("bytes"), stack_np("weight"),
-            stack_np("host"), stack_np("valid"), stack_np("region"),
-            np.asarray(bw_window, self._np_dtype),
-        ]
-        host_k = [
-            group_of, cascade_of, assign, lat_scale,
-            np.asarray(topo_stack.pool_latency_ns, self._np_dtype),
-            np.asarray(topo_stack.local_latency_ns, self._np_dtype),
-            np.asarray(topo_stack.switch_bandwidth_gbps, self._np_dtype),
-        ]
-        stage_s = time.perf_counter() - t0
-        t0 = time.perf_counter()
-        dev_r = [put_r(jnp.asarray(a, fd) if a.dtype.kind == "f" else jnp.asarray(a)) for a in host_r]
-        dev_cas = [
-            put_r(jnp.asarray(cas_group)), put_r(jnp.asarray(cas_assign)),
-            put_r(jnp.asarray(cas_stt)), put_r(jnp.asarray(cas_disc)),
-            put_r(jnp.asarray(cas_weights)),
-            put_r(jnp.asarray(self._qos_of_region)),
-        ]
-        dev_k = [put_k(a) for a in host_k]
-        transfer_s = time.perf_counter() - t0
-        self.last_dispatch = DispatchStats(
-            devices_used=n_shards,
-            shard_rows=Kp // n_shards if mesh is not None else 0,
-            rows=K,
-            padded_fraction=float(Kp - K) / Kp,
-            stage_s=stage_s,
-            transfer_s=transfer_s,
-            qos_classes=C,
-        )
-        t0 = time.perf_counter()
-        out = self._sweep_fn(
+        with spans.span("cxlsim.stage") as stage:
+            host_r = [
+                stack_np("t"), stack_np("bytes"), stack_np("weight"),
+                stack_np("host"), stack_np("valid"), stack_np("region"),
+                np.asarray(bw_window, self._np_dtype),
+            ]
+            host_k = [
+                group_of, cascade_of, assign, lat_scale,
+                np.asarray(topo_stack.pool_latency_ns, self._np_dtype),
+                np.asarray(topo_stack.local_latency_ns, self._np_dtype),
+                np.asarray(topo_stack.switch_bandwidth_gbps, self._np_dtype),
+            ]
+        with spans.span("cxlsim.h2d") as h2d:
+            dev_r = [put_r(jnp.asarray(a, fd) if a.dtype.kind == "f" else jnp.asarray(a)) for a in host_r]
+            dev_cas = [
+                put_r(jnp.asarray(cas_group)), put_r(jnp.asarray(cas_assign)),
+                put_r(jnp.asarray(cas_stt)), put_r(jnp.asarray(cas_disc)),
+                put_r(jnp.asarray(cas_weights)),
+                put_r(jnp.asarray(self._qos_of_region)),
+            ]
+            dev_k = [put_k(a) for a in host_k]
+            dev_s = [put_r(self._bits_table), put_r(self._route)]
+        out, enqueue_s, compile_s = enqueue_dispatch(
+            self._sweep_fn,
             *dev_r,
             *dev_cas,
             *dev_k,
-            put_r(self._bits_table),
-            put_r(self._route),
+            *dev_s,
             stage_order=self._stage_order,
             n_windows=self.n_windows,
             n_hosts=H,
             merge_plan=self._merge_plan,
             qos_on=qos_on,
         )
-        lat, cong, bw, ppl, psc, psb, phl, phc, phb, pcc = jax.device_get(out)
-        self.last_dispatch = dataclasses.replace(
-            self.last_dispatch, compute_s=time.perf_counter() - t0
+        host, wait_s, d2h_s = collect_dispatch(out)
+        lat, cong, bw, ppl, psc, psb, phl, phc, phb, pcc = host
+        # every scenario row prices its granularity group's [B, N] plane
+        valid = host_r[4]
+        slots = Kp * int(np.prod(valid.shape[1:]))
+        events = int(valid.reshape(len(valid), -1).sum(axis=1)[group_of].sum())
+        count_dispatch(slots, events)
+        self.last_dispatch = DispatchStats(
+            devices_used=n_shards,
+            shard_rows=Kp // n_shards if mesh is not None else 0,
+            rows=K,
+            padded_fraction=float(Kp - K) / Kp,
+            stage_s=stage.seconds,
+            transfer_s=h2d.seconds,
+            compile_s=compile_s,
+            enqueue_s=enqueue_s,
+            wait_s=wait_s,
+            d2h_s=d2h_s,
+            slots=slots,
+            events=events,
+            qos_classes=C,
         )
         breakdowns = [
             DelayBreakdown(
@@ -666,6 +677,7 @@ class ScenarioSuite:
             padded_fraction=self.last_dispatch.padded_fraction,
             stage_s=self.last_dispatch.stage_s,
             transfer_s=self.last_dispatch.transfer_s,
+            compile_s=self.last_dispatch.compile_s,
             compute_s=self.last_dispatch.compute_s,
             qos_classes=C,
         )

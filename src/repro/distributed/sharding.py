@@ -18,7 +18,6 @@ divisible, else the expert hidden dim.
 
 from __future__ import annotations
 
-import time
 import warnings
 from typing import Any, Dict, Optional, Tuple
 
@@ -118,23 +117,20 @@ def replicated(mesh: Optional[Mesh], x):
 
 
 def timed_device_put(tree, mesh: Optional[Mesh] = None, spec: Optional[P] = None):
-    """H2D placement with the transfer wall clock measured at the source.
+    """H2D placement of a dispatch's staged planes; returns the device tree.
 
-    Returns ``(device_tree, seconds)``.  The pipeline dispatcher uses this
-    to report ``transfer_s`` per dispatch and, because the placement is an
-    explicit ``device_put`` (not an implicit transfer inside the jitted
-    call), the resulting device buffers are what ``donate_argnums``
-    consumes — donation engages on the copies, never on the caller's host
-    staging planes.  With ``mesh`` (and optionally ``spec``) the placement
-    is sharded; default is the single default device.
+    The dispatch times this call under the ``cxlsim.h2d`` span
+    (:mod:`repro.core.spans`) and reports it as ``transfer_s``.  Because
+    the placement is an explicit ``device_put`` (not an implicit transfer
+    inside the jitted call), the resulting device buffers are what
+    ``donate_argnums`` consumes — donation engages on the copies, never on
+    the caller's host staging planes.  With ``mesh`` (and optionally
+    ``spec``) the placement is sharded; default is the single default
+    device.
     """
-    t0 = time.perf_counter()
     if mesh is None:
-        out = jax.device_put(tree)
-    else:
-        out = jax.device_put(tree, NamedSharding(mesh, spec if spec is not None else P()))
-    t1 = time.perf_counter()
-    return out, t1 - t0
+        return jax.device_put(tree)
+    return jax.device_put(tree, NamedSharding(mesh, spec if spec is not None else P()))
 
 
 def batch_axes(mesh: Mesh) -> Tuple[str, ...]:

@@ -448,6 +448,7 @@ def test_sim_report_summary_keys_locked():
         "dropped_batches", "dropped_epochs",
         "devices_used", "shard_rows", "padded_waste", "coalesced_group_size",
         "stage_s", "transfer_s", "compile_s", "compute_s",
+        "enqueue_s", "wait_s", "d2h_s", "slots", "events",
         "donated_dispatches", "aot_cache_hits",
         "qos_classes", "qos_delay_shares",
     }
@@ -462,6 +463,7 @@ def test_fabric_report_summary_keys_locked():
         "dropped_batches", "dropped_epochs",
         "devices_used", "shard_rows", "padded_waste", "coalesced_group_size",
         "stage_s", "transfer_s", "compile_s", "compute_s",
+        "enqueue_s", "wait_s", "d2h_s", "slots", "events",
         "donated_dispatches", "aot_cache_hits",
         "qos_classes", "qos_delay_shares",
     }

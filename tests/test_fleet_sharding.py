@@ -96,9 +96,14 @@ def test_analyze_batch_multi_sharded_bitwise_parity(data_mesh):
         assert x.congestion_ns == y.congestion_ns
         assert x.bandwidth_ns == y.bandwidth_ns
         np.testing.assert_array_equal(x.per_host_total_ns, y.per_host_total_ns)
-    assert sharded.last_dispatch == DispatchStats(
-        devices_used=8, shard_rows=2, rows=11, padded_fraction=5 / 16
+    st = sharded.last_dispatch
+    assert isinstance(st, DispatchStats)
+    assert (st.devices_used, st.shard_rows, st.rows, st.padded_fraction) == (
+        8, 2, 11, 5 / 16
     )
+    assert st.slots == 16 * 4 * 512  # [K, B, N] buckets of 11 x 3 x 300
+    assert st.events == sum(tr.n for g in groups for tr in g)
+    assert st.compute_s == st.enqueue_s + st.wait_s + st.d2h_s
     assert plain.last_dispatch.devices_used == 1
     assert sharded.sharded_dispatches == 1
 
