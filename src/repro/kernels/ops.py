@@ -200,15 +200,16 @@ def qos_congestion_cascade(
     )
 
 
-@axes("N", lead="N")
-def two_run_merge(x, lead, *payloads):
-    """Stable merge of two interleaved sorted runs (envelope formulation).
+@axes("N")
+def two_run_merge(x, split, *payloads):
+    """Stable merge of two adjacent sorted runs ``x[:split]`` and
+    ``x[split:]`` (``split`` static) by ranking only the shorter run.
 
-    XLA only: the cummax/searchsorted/scatter formulation is already a
-    handful of fused elementwise passes, and Mosaic lowers neither 1-D
-    gathers nor scatters.
+    XLA only: a short-run count, a scatter as wide as the short run, one
+    ``cumsum`` and a gather per payload; Mosaic lowers neither 1-D gathers
+    nor scatters.
     """
-    return ref.two_run_merge(x, lead, *payloads)
+    return ref.two_run_merge(x, split, *payloads)
 
 
 @axes("N")

@@ -107,48 +107,59 @@ def merge_sorted_runs(
     return tuple(jnp.zeros_like(p).at[pos].set(p) for p in (x,) + payloads)
 
 
-@axes("N", lead="N")
-def two_run_merge(x: jnp.ndarray, lead: jnp.ndarray, *payloads: jnp.ndarray):
-    """Merge two interleaved sorted runs by rank arithmetic (no compaction).
+# two_run_merge counts with one compare of the short run against the whole
+# long run while short x long is at most this many pairs, else with a binary
+# search.  On a TPU v5e the compare was never slower at any shape measured, up
+# to 2**30 pairs (PERF.md §6, PR 14); past that its work grows as the product.
+_COMPARE_ALL_MAX_PAIRS = 1 << 30
 
-    ``x`` holds two individually-sorted runs marked by the boolean ``lead``
-    mask; ties place ``lead`` elements first.  Unlike
-    :func:`merge_sorted_runs` (which physically compacts each run before
-    ``searchsorted``), each run is ranked against the *forward-filled
-    cumulative-max envelope* of the other run in place: for a ``lead``
-    element the merged rank is its own-run rank plus the count of other-run
-    elements strictly below it, read off one ``searchsorted`` against the
-    envelope plus a prefix count.  That replaces two scatter compactions
-    with two ``cummax`` scans — measurably cheaper on XLA CPU — while
-    producing bit-identical merged order.
+
+@axes("N")
+def two_run_merge(x: jnp.ndarray, split, *payloads: jnp.ndarray):
+    """Stable merge of two adjacent sorted runs ``[A | B]`` by ranking the
+    shorter one.
+
+    ``x[:split]`` (``A``) and ``x[split:]`` (``B``) are individually sorted;
+    ``split`` is static.  Ties place ``A`` first, so the result is
+    **bitwise identical** to a host stable argsort of ``x``.  Only the
+    shorter run is ranked against the longer: ``b_j`` lands at
+    ``j + #(A <= b_j)``, ``a_i`` at ``i + #(B < a_i)``.  A scatter as wide
+    as the short run marks those slots and one ``cumsum`` over the
+    unmarked ones places the long run in order.  The count compares the
+    short run against the whole long run at once (no loop) up to
+    ``_COMPARE_ALL_MAX_PAIRS`` pairs, else binary-searches per short-run
+    entry.
 
     Padding contract (the device pipeline's): entries keyed ``+inf`` in
-    either run sort to the tail, ``lead``-run pads before the others, and
-    never perturb the ranks of finite entries.
+    either run sort to the tail, ``A``'s pads before ``B``'s, and never
+    perturb the ranks of finite entries.
 
     Returns ``(x, *payloads)`` permuted into merged order.
     """
     n = x.shape[0]
-    neg = jnp.asarray(-jnp.inf, x.dtype)
-    a = lead
-    b = ~lead
-    ca = jnp.cumsum(a.astype(jnp.int32))
-    cb = jnp.cumsum(b.astype(jnp.int32))
-    m_a = jax.lax.cummax(jnp.where(a, x, neg))
-    m_b = jax.lax.cummax(jnp.where(b, x, neg))
-    # a-queries count b-elements strictly below ('left': a first on ties);
-    # b-queries count a-elements at-or-below ('right')
-    pos_b = jnp.searchsorted(m_b, x, side="left")
-    pos_a = jnp.searchsorted(m_a, x, side="right")
-    cnt_b = jnp.where(pos_b > 0, cb[jnp.maximum(pos_b - 1, 0)], 0)
-    cnt_a = jnp.where(pos_a > 0, ca[jnp.maximum(pos_a - 1, 0)], 0)
-    rank = jnp.where(a, (ca - 1) + cnt_b, (cb - 1) + cnt_a)
-    iota = jnp.arange(n, dtype=jnp.int32)
-    # rank is a permutation of [0, n): invert once, gather every payload
-    src = (
+    w0 = int(split)
+    if not 0 <= w0 <= n:
+        raise ValueError(f"split {w0} outside [0, {n}]")
+    w1 = n - w0
+    a, b = x[:w0], x[w0:]
+    ws = min(w0, w1)
+    method = "compare_all" if w0 * w1 <= _COMPARE_ALL_MAX_PAIRS else "scan"
+    if w1 <= w0:  # B short: A first on ties, so count A at-or-below
+        cnt = jnp.searchsorted(a, b, side="right", method=method)
+        s_off, l_off = w0, 0
+    else:  # A short: count B strictly below
+        cnt = jnp.searchsorted(b, a, side="left", method=method)
+        s_off, l_off = 0, w0
+    j = jnp.arange(ws, dtype=jnp.int32)
+    # slot -> 1 + source index of the short-run entry placed there, else 0
+    mark = (
         jnp.zeros((n,), jnp.int32)
-        .at[rank]
-        .set(iota, unique_indices=True, mode="promise_in_bounds")
+        .at[j + cnt]
+        .set(s_off + 1 + j, unique_indices=True, mode="promise_in_bounds")
+    )
+    short = mark > 0
+    src = jnp.where(
+        short, mark - 1, l_off - 1 + jnp.cumsum((~short).astype(jnp.int32))
     )
     return tuple(jnp.take(p, src) for p in (x,) + payloads)
 
@@ -160,10 +171,11 @@ def staging_sort(x: jnp.ndarray, run_caps, *payloads: jnp.ndarray):
     ``x`` is the concatenation of ``len(run_caps)`` individually-sorted
     runs, run ``r`` occupying the static slice of width ``run_caps[r]``
     (pad entries keyed ``+inf`` at each run's tail).  A ``ceil(log2 R)``
-    round tree of :func:`two_run_merge` calls over adjacent run pairs
-    produces the fully-sorted order; ties keep the lower run first, so the
-    result is **bitwise identical** to a host stable argsort of the
-    run-major concatenation (all pads land at the global tail).
+    round tree of :func:`two_run_merge` calls over adjacent run pairs, each
+    split at the static width of its left run, produces the fully-sorted
+    order; ties keep the lower run first, so the result is **bitwise
+    identical** to a host stable argsort of the run-major concatenation
+    (all pads land at the global tail).
 
     This is the device half of the staging contract: the host packs runs
     (a stable partition, O(copy), zero argsort) and the merge tree replaces
@@ -194,9 +206,8 @@ def staging_sort(x: jnp.ndarray, run_caps, *payloads: jnp.ndarray):
         for i in range(0, len(runs) - 1, 2):
             (s0, w0), (s1, w1) = runs[i], runs[i + 1]
             flush_gap(cursor, s0)
-            lead = jnp.arange(w0 + w1, dtype=jnp.int32) < w0
             merged = two_run_merge(
-                arrs[0][s0 : s1 + w1], lead, *(p[s0 : s1 + w1] for p in arrs[1:])
+                arrs[0][s0 : s1 + w1], w0, *(p[s0 : s1 + w1] for p in arrs[1:])
             )
             for j, m in enumerate(merged):
                 pieces[j].append(m)
@@ -226,11 +237,12 @@ def chain_cascade(
     Under that nesting the cascade never needs full-width merges: the
     working array ``A`` holds exactly the events that traverse the current
     stage, each stage folds in the (time-sorted) segment of events whose
-    *deepest* switch it is with one :func:`two_run_merge`, and the stage
-    scan runs **unmasked** — its output start times are non-decreasing, so
-    ``A`` stays sorted and never splits back into runs.  Total merge work
-    is the sum of the growing compact widths instead of S full-width
-    merge+scan passes, and local-DRAM traffic (no routes) never enters at
+    *deepest* switch it is with one :func:`two_run_merge` split at ``A``'s
+    static width, and the stage scan runs **unmasked** — its output start
+    times are non-decreasing, so ``A`` stays sorted and never splits back
+    into runs.  A merge ranks only the shorter run, so a narrow entry
+    segment is ranked at the cost of its own width (placing ``A`` is one
+    full-width pass), and local-DRAM traffic (no routes) never enters at
     all.
 
     Per-event final times are bitwise identical to
@@ -261,11 +273,9 @@ def chain_cascade(
             if a_t.shape[0] == 0:
                 a_t, a_i = seg_t, seg_i
             else:
-                w0 = a_t.shape[0]
-                lead = jnp.arange(w0 + cap, dtype=jnp.int32) < w0
                 a_t, a_i = two_run_merge(
                     jnp.concatenate([a_t, seg_t]),
-                    lead,
+                    a_t.shape[0],
                     jnp.concatenate([a_i, seg_i]),
                 )
             off += cap

@@ -17,6 +17,8 @@ Covers the PR's contract end to end:
     same numbers as synchronous dispatch.
 """
 
+import re
+
 import numpy as np
 import pytest
 
@@ -27,6 +29,7 @@ from repro.core.analyzer import (
     DispatchStats,
     EpochAnalyzer,
     FineGrainedSimulator,
+    _analyze_pipeline_jax,
     analyze_ref,
     plan_chain,
 )
@@ -51,25 +54,59 @@ def _host_stable(keys, *payloads):
     return (np.asarray(keys)[order],) + tuple(np.asarray(p)[order] for p in payloads)
 
 
-def test_two_run_merge_bitwise_with_ties_and_pads(rng):
-    w0, w1 = 37, 27
-    a = np.sort(rng.integers(0, 20, w0)).astype(np.float32)  # many exact ties
+def _two_runs(rng, w0, w1, pad0, pad1):
+    """Two sorted runs [A | B] with many exact ties and +inf/-1 pad tails."""
+    a = np.sort(rng.integers(0, 20, w0)).astype(np.float32)
     b = np.sort(rng.integers(0, 20, w1)).astype(np.float32)
-    a[-5:] = np.inf  # pad tails
-    b[-3:] = np.inf
     ids = np.arange(w0 + w1, dtype=np.int32)
-    ids[w0 - 5 : w0] = -1
-    ids[-3:] = -1
-    x = np.concatenate([a, b])
-    lead = np.arange(w0 + w1, dtype=np.int32) < w0
-    got_x, got_i = ref.two_run_merge(
-        jnp.asarray(x), jnp.asarray(lead), jnp.asarray(ids)
-    )
+    a[w0 - pad0 :] = np.inf
+    b[w1 - pad1 :] = np.inf
+    ids[w0 - pad0 : w0] = -1
+    ids[w0 + w1 - pad1 :] = -1
+    return np.concatenate([a, b]), ids
+
+
+@pytest.mark.parametrize(
+    "w0, w1, pad0, pad1",
+    [
+        (37, 27, 5, 3),  # interleaved ties, both runs padded
+        (256, 16, 9, 16),  # long A, short all-pad B: the attach cell's merge
+        (16, 256, 2, 40),  # short A
+        (300, 300, 20, 7),  # equal widths
+        (1, 40, 0, 4),  # one-element A
+        (40, 1, 6, 0),  # one-element B
+        (300, 24, 300, 5),  # long run all pads
+    ],
+)
+@pytest.mark.parametrize("search", ["compare_all", "binary"])
+def test_two_run_merge_bitwise_with_ties_and_pads(
+    rng, monkeypatch, search, w0, w1, pad0, pad1
+):
+    if search == "binary":  # the branch wide runs take past the pair cap
+        monkeypatch.setattr(ref, "_COMPARE_ALL_MAX_PAIRS", 0)
+    x, ids = _two_runs(rng, w0, w1, pad0, pad1)
+    got_x, got_i = ref.two_run_merge(jnp.asarray(x), w0, jnp.asarray(ids))
     # host oracle: stable argsort of the run-major concatenation resolves
     # ties lower-run-first — exactly two_run_merge's tie contract
     exp_x, exp_i = _host_stable(x, ids)
     np.testing.assert_array_equal(np.asarray(got_x), exp_x)
     np.testing.assert_array_equal(np.asarray(got_i), exp_i)
+
+
+def test_two_run_merge_vmapped_batch(rng):
+    w0, w1, B = 64, 16, 4
+    rows = [
+        _two_runs(rng, w0, w1, int(rng.integers(0, w0)), int(rng.integers(0, w1 + 1)))
+        for _ in range(B)
+    ]
+    x = np.stack([r[0] for r in rows])
+    ids = np.stack([r[1] for r in rows])
+    f = jax.vmap(lambda xx, ii: ref.two_run_merge(xx, w0, ii))
+    got_x, got_i = f(jnp.asarray(x), jnp.asarray(ids))
+    for b in range(B):
+        exp_x, exp_i = _host_stable(x[b], ids[b])
+        np.testing.assert_array_equal(np.asarray(got_x[b]), exp_x)
+        np.testing.assert_array_equal(np.asarray(got_i[b]), exp_i)
 
 
 @pytest.mark.parametrize("caps", [(16,), (16, 16), (8, 16, 4), (8, 8, 8, 8, 8)])
@@ -118,18 +155,19 @@ def test_staging_sort_vmapped_batch(rng):
         np.testing.assert_array_equal(np.asarray(got_i[b]), exp_i)
 
 
-def test_chain_cascade_matches_serial_cascade(rng):
+def _check_chain_cascade_vs_serial(rng, caps, stts, fills=None):
+    """chain_cascade over packed entry segments (``fills[d]`` real events
+    in segment ``d``, random when None) against the serial cascade."""
     # tie-free times => per-event finals are bitwise identical
-    D = 4  # stages, deepest first; stage d's events traverse stages d..D-1
-    caps = (8, 8, 16, 8)
+    D = len(caps)  # stages, deepest first; stage d's events traverse d..D-1
     W = sum(caps)
-    stts = np.asarray([7.0, 5.0, 3.0, 2.0], np.float32)
+    stts = np.asarray(stts, np.float32)
     t_pack = np.full((W,), np.inf, np.float32)
     idx = np.full((W,), -1, np.int32)
     entry = np.full((W,), -1, np.int32)
     off = 0
     for d, c in enumerate(caps):
-        fill = int(rng.integers(1, c + 1))
+        fill = int(rng.integers(1, c + 1)) if fills is None else fills[d]
         t_pack[off : off + fill] = np.sort(
             rng.uniform(0, 400, fill)
         ).astype(np.float32)
@@ -149,18 +187,64 @@ def test_chain_cascade_matches_serial_cascade(rng):
     route_bits = np.zeros_like(ent_sorted)
     for s in range(D):
         route_bits |= np.where(ent_sorted <= s, 1 << s, 0)
-    tf, _, ds = ref.serial_queue_cascade(
+    tf, slot, ds = ref.serial_queue_cascade(
         jnp.asarray(t_sorted),
         jnp.asarray(route_bits),
         jnp.asarray(stts),
     )
     got = {int(i): float(t) for i, t in zip(np.asarray(i_fin), np.asarray(t_fin)) if i >= 0}
+    # tf[k] is the final time of the event at sorted position slot[k]
     exp = {
         int(i): float(t)
-        for i, t in zip(idx[real][order], np.asarray(tf))
+        for i, t in zip(idx[real][order][np.asarray(slot)], np.asarray(tf))
     }
     assert got == exp
     np.testing.assert_allclose(np.asarray(dsums), np.asarray(ds), rtol=1e-6)
+
+
+def test_chain_cascade_matches_serial_cascade(rng):
+    _check_chain_cascade_vs_serial(rng, (8, 8, 16, 8), [7.0, 5.0, 3.0, 2.0])
+
+
+def test_chain_cascade_pad_only_entry_segments(rng):
+    # the attach cell's packing: every event enters at the deepest stage and
+    # the later entry segments hold only their 16 +inf pads
+    _check_chain_cascade_vs_serial(rng, (64, 16, 16), [4.0, 2.0, 3.0], fills=(57, 0, 0))
+
+
+def test_pipeline_graph_merges_pad_segments_without_a_loop():
+    # the attach cell's chain dispatch, scaled down: a wide first segment and
+    # two 16-wide entry segments.  Ranking only the short run compares it
+    # against the long one directly, so no while loop may run over the
+    # packed width (a binary search over it is a 13-round gather loop).
+    flat = figure1_topology().flatten()
+    plan = plan_chain(flat)
+    B, N, caps = 4, 2048, (1024, 16, 16)
+    W = sum(caps)
+    V, S = np.asarray(flat.route).shape
+    sds = jax.ShapeDtypeStruct
+    f32, i32 = jnp.float32, jnp.int32
+    args = (
+        sds((B, W), f32), sds((B, W), i32), sds((B, N), i32), sds((B, N), f32),
+        sds((B, N), f32), sds((B, N), jnp.bool_), sds((B,), f32), sds((B, V), f32),
+        sds((V,), f32), sds((), f32), sds((V, S), f32), sds((S,), f32), sds((S,), f32),
+    )
+    jitted = jax.jit(
+        _analyze_pipeline_jax,
+        static_argnames=("stage_order", "seg_caps", "n_windows"),
+        donate_argnums=(0, 1),
+    )
+    lowered = jitted.lower(
+        *args, stage_order=plan.stage_order, seg_caps=caps, n_windows=32
+    )
+    hlo = lowered.compile().as_text()
+    loops = [ln for ln in hlo.splitlines() if re.search(r"\bwhile\(", ln)]
+    wide = [
+        ln for ln in loops
+        if any(int(d) >= caps[0] for dims in re.findall(r"\[([\d,]+)\]", ln.split(" while(")[0])
+               for d in dims.split(","))
+    ]
+    assert not wide, wide
 
 
 # --------------------------------------------------------------------------- #
