@@ -1,6 +1,6 @@
 """Architecture registry + assigned input shapes + input_specs.
 
-40 assigned cells = 10 archs × 4 shapes.  ``cells()`` enumerates the
+44 cells = 11 archs × 4 shapes.  ``cells()`` enumerates the
 runnable ones and records every skip with its reason (full-attention archs
 skip long_500k; the encoder-only arch skips decode shapes) — see DESIGN.md
 §Arch-applicability.
@@ -38,6 +38,7 @@ _MODULES = {
     "mamba2-2.7b": "mamba2_2_7b",
     "qwen2-vl-72b": "qwen2_vl_72b",
     "hubert-xlarge": "hubert_xlarge",
+    "granite-4.0-h-small": "granite_4_0_h_small",
 }
 
 ARCH_IDS: Tuple[str, ...] = tuple(_MODULES)
@@ -61,7 +62,7 @@ SHAPES: Dict[str, Shape] = {
 # families whose attention is full/quadratic -> long_500k skipped
 _FULL_ATTENTION = ("dense", "moe", "vlm")
 # sub-quadratic families run long_500k
-_SUBQUADRATIC = ("ssm", "hybrid")
+_SUBQUADRATIC = ("ssm", "hybrid", "granitemoehybrid")
 
 
 def _module(arch: str):
@@ -81,7 +82,7 @@ def get_smoke(arch: str) -> ModelConfig:
 
 
 def cells() -> List[Dict[str, Any]]:
-    """All 40 (arch × shape) cells with runnable flag + skip reason."""
+    """All 44 (arch × shape) cells with runnable flag + skip reason."""
     out = []
     for arch in ARCH_IDS:
         cfg = get_config(arch)

@@ -37,6 +37,16 @@ plus the per-component delay decomposition, per-pool/switch, per-epoch.
 ``analyzer_s`` stays the analyzer's own compute seconds (the paper's
 overhead accounting) whether or not it overlapped native execution.
 
+A memory program may also be a function of the step's outputs (a decode
+step whose routed-expert reads follow that step's own router counts).
+Such a step runs natively first; its program is then built (span
+``cxlsim.program``) and its epoch batch submitted, so the batch is priced
+while the next step runs.  Counters ``cxlsim.experts.held`` /
+``cxlsim.experts.touched`` (expert-class regions, and those the step read),
+``cxlsim.bytes.expert`` and ``cxlsim.bytes.priced`` (the step program's
+expert-class and total bytes) record what each step priced.  A program
+that is the same for every step keeps the order above.
+
 ``AttachedProgram`` is a context manager; ``with sim.attach(...) as prog``
 (or an explicit ``prog.close()``) releases its engine handle.  The shared
 engine keeps exactly one dispatcher thread for the whole process — attach
@@ -55,7 +65,7 @@ from __future__ import annotations
 import dataclasses
 import threading
 import time
-from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple, Union
 
 import jax
 import numpy as np
@@ -68,10 +78,10 @@ from .engine import AnalysisEngine, EngineClient, EngineHandle, fold_dispatch_st
 from .events import MemEvents, RegionMap
 from .migration import MigrationSimulator
 from .policy import PlacementPolicy, capacity_check
-from .spans import span
+from .spans import count, span
 from .timer import EpochSchedule
 from .topology import Topology
-from .tracer import HardwareModel, Phase, TPU_V5E, synthesize_step_trace
+from .tracer import HardwareModel, Phase, TPU_V5E, class_traffic, synthesize_step_trace
 from .units import ns_to_s
 
 __all__ = ["CXLMemSim", "AttachedProgram", "SimReport"]
@@ -228,14 +238,20 @@ class CXLMemSim:
     def attach(
         self,
         step_fn: Callable[..., Any],
-        phases: Sequence[Phase],
+        phases: Union[Sequence[Phase], Callable[[Any], Sequence[Phase]]],
         regions: RegionMap,
         calibration: float = 1.0,
+        warm_programs: Sequence[Sequence[Phase]] = (),
     ) -> "AttachedProgram":
+        """``phases`` is the step's memory program: one phase list for every
+        step, or a function of each step's outputs that returns that step's
+        phases.  ``warm_programs`` are phase lists of such a function whose
+        dispatch shapes ``warmup`` compiles at attach (every plane bucket
+        the steps can reach)."""
         self.policy.place(regions, self.flat)
         if self.check_capacity:
             capacity_check(regions, self.flat)
-        return AttachedProgram(self, step_fn, list(phases), regions, calibration)
+        return AttachedProgram(self, step_fn, phases, regions, calibration, warm_programs)
 
 
 class AttachedProgram(EngineClient):
@@ -247,14 +263,18 @@ class AttachedProgram(EngineClient):
         self,
         sim: CXLMemSim,
         step_fn: Callable[..., Any],
-        phases: List[Phase],
+        phases: Union[Sequence[Phase], Callable[[Any], Sequence[Phase]]],
         regions: RegionMap,
         calibration: float,
+        warm_programs: Sequence[Sequence[Phase]] = (),
     ):
         self.sim = sim
         self.step_fn = step_fn
-        self.phases = phases
+        # a routing-driven program is built from each step's outputs
+        self.program = phases if callable(phases) else None
+        self.phases = None if callable(phases) else list(phases)
         self.regions = regions
+        self._n_experts_held = sum(1 for r in regions if r.tensor_class == "expert")
         self.calibration = calibration
         if sim.analyzer_kind == "epoch":
             self._analyzer = EpochAnalyzer(
@@ -284,8 +304,11 @@ class AttachedProgram(EngineClient):
         if sim.warmup and isinstance(self._analyzer, EpochAnalyzer):
             # pre-compile the pipeline executable on this step's trace shapes
             # so the first real dispatch is a pure AOT-cache hit
-            traces, _, _ = self._traces()
-            self._analyzer.warmup(traces)
+            if self.program is None:
+                traces, _, _ = self._traces()
+                self._analyzer.warmup(traces)
+            for warm in warm_programs:
+                self._analyzer.warmup(self._traces(list(warm))[0])
 
     # ------------------------------------------------------------------ #
 
@@ -299,33 +322,42 @@ class AttachedProgram(EngineClient):
 
     # ------------------------------------------------------------------ #
 
-    def _traces(self):
-        """Structural traces are shape-static per step; cache across steps,
-        but recompute when migration has changed residency."""
+    def _traces(self, phases: Optional[List[Phase]] = None):
+        """Structural traces of a static program are shape-static per step:
+        cached across steps, but recomputed when migration has changed
+        residency.  ``phases`` (one step of a routing-driven program) are
+        synthesized afresh."""
+        if phases is not None:
+            return self._synthesize(phases)
         if self._trace_cache is None or self.sim.migration is not None:
-            mode = "layer" if self.sim.epoch.mode == "layer" else "step"
-            traces, native_ns, names = synthesize_step_trace(
-                self.phases,
-                self.regions,
-                hw=self.sim.hw,
-                granularity_bytes=self.sim.policy.granularity_bytes,
-                max_events_per_access=self.sim.max_events_per_access,
-                calibration=self.calibration,
-                epoch_mode=mode,
-            )
-            if self.sim.epoch.mode == "quantum":
-                cut: List[MemEvents] = []
-                for tr in traces:
-                    cut.extend(self.sim.epoch.slices(tr))
-                traces = cut
-                native_ns = [self.sim.epoch.quantum_ns] * len(traces)
-                names = [f"q{i}" for i in range(len(traces))]
-            if self.sim.sample_rate < 1.0:
-                traces = [t.sample(self.sim.sample_rate, seed=i) for i, t in enumerate(traces)]
-            self._trace_cache = (traces, native_ns, names)
+            self._trace_cache = self._synthesize(self.phases)
         return self._trace_cache
 
-    def _epoch_batch(self) -> Tuple[List[MemEvents], float, Optional[List]]:
+    def _synthesize(self, phases: List[Phase]):
+        mode = "layer" if self.sim.epoch.mode == "layer" else "step"
+        traces, native_ns, names = synthesize_step_trace(
+            phases,
+            self.regions,
+            hw=self.sim.hw,
+            granularity_bytes=self.sim.policy.granularity_bytes,
+            max_events_per_access=self.sim.max_events_per_access,
+            calibration=self.calibration,
+            epoch_mode=mode,
+        )
+        if self.sim.epoch.mode == "quantum":
+            cut: List[MemEvents] = []
+            for tr in traces:
+                cut.extend(self.sim.epoch.slices(tr))
+            traces = cut
+            native_ns = [self.sim.epoch.quantum_ns] * len(traces)
+            names = [f"q{i}" for i in range(len(traces))]
+        if self.sim.sample_rate < 1.0:
+            traces = [t.sample(self.sim.sample_rate, seed=i) for i, t in enumerate(traces)]
+        return traces, native_ns, names
+
+    def _epoch_batch(
+        self, phases: Optional[List[Phase]] = None
+    ) -> Tuple[List[MemEvents], float, Optional[List]]:
         """One step's epoch traces with migration/coherency/cache applied.
 
         Stateful transforms run on the submitting thread so their epoch
@@ -333,7 +365,7 @@ class AttachedProgram(EngineClient):
         The device cache observes the *final* per-epoch stream (including
         injected migration and BI traffic, which warms and pollutes it like
         any other access) and returns per-epoch latency-scale vectors."""
-        traces, _, _ = self._traces()
+        traces, _, _ = self._traces(phases)
         from .events import concat_events  # local import to avoid cycle
 
         batch: List[MemEvents] = []
@@ -423,16 +455,13 @@ class AttachedProgram(EngineClient):
 
         In async mode the step's epoch batch is submitted *before* the
         native dispatch, so the analyzer works while the step executes;
-        totals become visible via :attr:`report` (which flushes)."""
-        with span("cxlsim.epoch_batch"):
-            batch, coh_ns, scales = self._epoch_batch()
-        if self._handle is not None:
-            n_epochs = len(batch)
-            self._handle.submit(
-                batch,
-                scales,
-                fold=lambda bd, elapsed: self._fold(bd, coh_ns, elapsed, n_epochs),
-            )
+        totals become visible via :attr:`report` (which flushes).  A
+        routing-driven program's batch is submitted *after* its step, and
+        is priced while the next step runs."""
+        if self.program is None:
+            with span("cxlsim.epoch_batch"):
+                batch = self._epoch_batch()
+            self._submit(batch)
 
         with span("cxlsim.native") as native:
             out = self.step_fn(*args, **kwargs)
@@ -442,8 +471,20 @@ class AttachedProgram(EngineClient):
             self._report.simulated_s += native.seconds
             self._report.steps += 1
 
+        if self.program is not None:
+            with span("cxlsim.program"):
+                phases = list(self.program(out))
+                total, expert, touched = class_traffic(phases, self.regions, "expert")
+            count("cxlsim.experts.held", self._n_experts_held)
+            count("cxlsim.experts.touched", touched)
+            count("cxlsim.bytes.expert", int(expert))
+            count("cxlsim.bytes.priced", int(total))
+            with span("cxlsim.epoch_batch"):
+                batch = self._epoch_batch(phases)
+            self._submit(batch)
+
         if self._handle is None:
-            delay_ns = self._analyze_and_accumulate(batch, coh_ns, scales)
+            delay_ns = self._analyze_and_accumulate(*batch)
             if self.sim.inject_delays and delay_ns > 0:
                 # the paper's delay injection: the host program observes the
                 # simulated-topology execution speed
@@ -451,6 +492,18 @@ class AttachedProgram(EngineClient):
                 with self._report_lock:
                     self._report.injected_sleep_s += ns_to_s(delay_ns)
         return out
+
+    def _submit(self, batch) -> None:
+        """Hand a step's ``(epochs, coh_ns, scales)`` to the async engine."""
+        if self._handle is None:
+            return
+        traces, coh_ns, scales = batch
+        n_epochs = len(traces)
+        self._handle.submit(
+            traces,
+            scales,
+            fold=lambda bd, elapsed: self._fold(bd, coh_ns, elapsed, n_epochs),
+        )
 
     def run(self, n_steps: int, *args, **kwargs) -> SimReport:
         for _ in range(n_steps):

@@ -422,8 +422,10 @@ class EventStager:
         natural = caps
         prev = self._cap_hwm.get(cap_key)
         if prev is not None:
+            # a stage held at the floor cannot shrink: it counts as idle
+            # while its demand still fits, never once the demand outgrows it
             idle = all(
-                n <= p // 2 or p <= cap_floor
+                n <= p // 2 or (p <= cap_floor and n <= p)
                 for n, p in zip(natural, prev)
             )
             if idle:
@@ -553,7 +555,7 @@ class Region:
     rid: int
     name: str
     nbytes: int
-    tensor_class: str  # 'param' | 'grad' | 'opt_state' | 'activation' | 'kvcache' | 'expert' | 'input' | 'other'
+    tensor_class: str  # 'param' | 'grad' | 'opt_state' | 'activation' | 'kvcache' | 'ssm_state' | 'expert' | 'input' | 'other'
     pool: int = 0  # pool index; set by a placement policy
     access_count: float = 0.0  # running hotness statistic (per epoch window)
 

@@ -58,6 +58,7 @@ __all__ = [
     "synthesize_skeleton",
     "synthesize_step_trace",
     "phase_duration_ns",
+    "class_traffic",
     "hlo_cost_summary",
 ]
 
@@ -106,6 +107,22 @@ TPU_V5E = HardwareModel(
 
 def phase_duration_ns(phase: Phase, hw: HardwareModel) -> float:
     return hw.phase_ns(phase.flops, phase.total_bytes())
+
+
+def class_traffic(
+    phases: Sequence[Phase], regions: RegionMap, tensor_class: str
+) -> Tuple[float, float, int]:
+    """``(all bytes, bytes of tensor_class, regions of that class touched)``
+    of one step's program."""
+    total = cls_bytes = 0.0
+    touched = set()
+    for ph in phases:
+        for a in ph.accesses:
+            total += a.bytes_
+            if regions[a.region].tensor_class == tensor_class:
+                cls_bytes += a.bytes_
+                touched.add(a.region)
+    return total, cls_bytes, len(touched)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -228,7 +228,7 @@ def param_pspecs(param_shapes, cfg, mesh: Mesh, strategy: str = "dp_tp"):
                 return spec(model, fsdp)
             return spec()  # q_norm / k_norm
         if "moe" in names:
-            E = cfg.n_experts
+            E = cfg.n_held_experts
             ep_ok = E % _axis_size(mesh, model) == 0 and not moe_dp
             if last == "router":
                 return spec(fsdp, None)
@@ -262,6 +262,8 @@ def param_pspecs(param_shapes, cfg, mesh: Mesh, strategy: str = "dp_tp"):
                 return spec(model, fsdp)
             if last == "conv_w":
                 return spec(None, model)
+            if last == "conv_b":
+                return spec(model)  # conv-width bias, sharded with conv_w
             if last == "norm":
                 return spec(model)  # inner-width gain, sharded with di
             return spec()  # A_log, dt_bias, D

@@ -231,12 +231,13 @@ def attention_block(
     window: Optional[int] = None,
     block_q: int = 1024,
     block_k: int = 1024,
+    scale: Optional[float] = None,  # None => d_head ** -0.5
 ) -> jnp.ndarray:
     """Full-sequence attention (train / prefill)."""
     B, S, _ = x.shape
     q, k, v = _project_qkv(p, x, n_heads, n_kv_heads, d_head, positions, rope_variant, qk_norm, theta)
     o = chunked_attention(
-        q, k, v, causal=causal, block_q=block_q, block_k=block_k, window=window
+        q, k, v, causal=causal, scale=scale, block_q=block_q, block_k=block_k, window=window
     )
     o = o.transpose(0, 2, 1, 3).reshape(B, S, n_heads * d_head)
     return o @ p["wo"].astype(x.dtype)
@@ -255,6 +256,7 @@ def decode_attention_block(
     qk_norm: bool = False,
     theta: float = 10_000.0,
     window: Optional[int] = None,
+    scale: Optional[float] = None,  # None => d_head ** -0.5
 ) -> Tuple[jnp.ndarray, Tuple[jnp.ndarray, jnp.ndarray]]:
     """Single-token decode with KV-cache update; returns (out, new_cache)."""
     B = x.shape[0]
@@ -265,7 +267,9 @@ def decode_attention_block(
     cv = jax.lax.dynamic_update_slice(cv, v.astype(cv.dtype), (0, 0, cache_len, 0))
     g = n_heads // n_kv_heads
     qg = q.reshape(B, n_kv_heads, g, 1, d_head).astype(jnp.float32)
-    s = jnp.einsum("bhgqd,bhkd->bhgqk", qg, ck.astype(jnp.float32)) * (d_head ** -0.5)
+    if scale is None:
+        scale = d_head ** -0.5
+    s = jnp.einsum("bhgqd,bhkd->bhgqk", qg, ck.astype(jnp.float32)) * scale
     kpos = jnp.arange(Smax)
     mask = kpos[None, :] <= cache_len
     if window is not None:

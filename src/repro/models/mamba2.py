@@ -1,15 +1,17 @@
 """Mamba2 block (SSD mixer) — attention-free sequence mixing.
 
-Structure (Dao & Gu 2024, simplified to 1 B/C group):
+Structure (Dao & Gu 2024, one B/C group):
 
-  in_proj -> [x (H·P), z (H·P), B (N), C (N), dt (H)]
-  depthwise causal conv1d (kernel 4) on x
-  SSD scan (kernels/ssd_scan.py, or the chunked jnp ref under GSPMD)
+  in_proj -> [z (H·P), x (H·P), B (N), C (N), dt (H)]
+  depthwise causal conv1d (kernel 4, with bias) over x‖B‖C, then SiLU
+  dt = softplus(dt + dt_bias);  SSD scan (kernels/ssd_scan.py, or the
+  chunked jnp ref under GSPMD);  y + D·x
   gate: y ⊙ silu(z); RMSNorm; out_proj
 
-Decode keeps two caches per layer: the conv tail [B, K-1, H·P] and the SSM
-state [B, H, N, P]; a decode step is O(1) in sequence length, which is why
-the ``long_500k`` shape runs on this family.
+Decode keeps two caches per layer: the conv tail [B, K-1, H·P + 2N] (the
+pre-conv x‖B‖C stream) and the SSM state [B, H, N, P]; a decode step is
+O(1) in sequence length, which is why the ``long_500k`` shape runs on this
+family.
 """
 
 from __future__ import annotations
@@ -38,10 +40,12 @@ CONV_K = 4
 
 def init_mamba2(key, d_model: int, n_heads: int, d_head: int, d_state: int) -> Params:
     di = n_heads * d_head  # inner width
+    conv_dim = di + 2 * d_state  # x‖B‖C
     ks = jax.random.split(key, 6)
     return {
         "in_proj": init_linear(ks[0], d_model, 2 * di + 2 * d_state + n_heads),
-        "conv_w": jax.random.truncated_normal(ks[1], -3, 3, (CONV_K, di), jnp.float32) * 0.3,
+        "conv_w": jax.random.truncated_normal(ks[1], -3, 3, (CONV_K, conv_dim), jnp.float32) * 0.3,
+        "conv_b": jax.random.truncated_normal(ks[3], -3, 3, (conv_dim,), jnp.float32) * 0.1,
         "A_log": jnp.log(jnp.linspace(1.0, 8.0, n_heads).astype(jnp.float32)),
         "dt_bias": jnp.zeros((n_heads,), jnp.float32),
         "D": jnp.ones((n_heads,), jnp.float32),  # skip connection
@@ -65,15 +69,29 @@ def _pad_seq(chunk: int, *arrays):
 
 
 def _split_proj(p, u, n_heads, d_head, d_state):
+    """in_proj output -> (z, x‖B‖C before the conv, dt)."""
     di = n_heads * d_head
     z = u[..., :di]
-    x = u[..., di : 2 * di]
-    Bm = u[..., 2 * di : 2 * di + d_state]
-    Cm = u[..., 2 * di + d_state : 2 * di + 2 * d_state]
+    xbc = u[..., di : 2 * di + 2 * d_state]
     dt = jax.nn.softplus(
         u[..., 2 * di + 2 * d_state :].astype(jnp.float32) + p["dt_bias"]
     )
-    return z, x, Bm, Cm, dt
+    return z, xbc, dt
+
+
+def _split_xbc(xbc, di: int, d_state: int):
+    return xbc[..., :di], xbc[..., di : di + d_state], xbc[..., di + d_state :]
+
+
+def _causal_conv(p, xbc):
+    """Depthwise causal conv (kernel CONV_K, with bias) over the sequence
+    axis of [B, S, conv_dim], then SiLU."""
+    S = xbc.shape[1]
+    xp = jnp.pad(xbc, ((0, 0), (CONV_K - 1, 0), (0, 0)))
+    conv = sum(
+        xp[:, i : i + S, :] * p["conv_w"][i].astype(xbc.dtype) for i in range(CONV_K)
+    )
+    return jax.nn.silu(conv + p["conv_b"].astype(xbc.dtype))
 
 
 def mamba2_block(
@@ -83,18 +101,13 @@ def mamba2_block(
     d_head: int,
     d_state: int,
     chunk: int = 128,
+    eps: float = 1e-6,
 ) -> jnp.ndarray:
     B, S, _ = h.shape
     di = n_heads * d_head
     u = h @ p["in_proj"].astype(h.dtype)
-    z, x, Bm, Cm, dt = _split_proj(p, u, n_heads, d_head, d_state)
-
-    # depthwise causal conv (kernel CONV_K) over sequence
-    xp = jnp.pad(x, ((0, 0), (CONV_K - 1, 0), (0, 0)))
-    conv = sum(
-        xp[:, i : i + S, :] * p["conv_w"][i].astype(h.dtype) for i in range(CONV_K)
-    )
-    x = jax.nn.silu(conv)
+    z, xbc, dt = _split_proj(p, u, n_heads, d_head, d_state)
+    x, Bm, Cm = _split_xbc(_causal_conv(p, xbc), di, d_state)
 
     A = -jnp.exp(p["A_log"])  # [H] negative decay rates
     xh = x.reshape(B, S, n_heads, d_head)
@@ -106,7 +119,7 @@ def mamba2_block(
     y = y.reshape(B, S, di)
 
     y = y * jax.nn.silu(z)
-    y = rms_norm(y, p["norm"])
+    y = rms_norm(y, p["norm"], eps)
     return y @ p["out_proj"].astype(h.dtype)
 
 
@@ -150,19 +163,16 @@ def mamba2_prefill(
     d_head: int,
     d_state: int,
     chunk: int = 128,
+    eps: float = 1e-6,
 ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
     """Full-sequence forward that also returns the decode cache."""
     B, S, _ = h.shape
     di = n_heads * d_head
     u = h @ p["in_proj"].astype(h.dtype)
-    z, x, Bm, Cm, dt = _split_proj(p, u, n_heads, d_head, d_state)
-
-    conv_tail = x[:, S - (CONV_K - 1) :, :]  # pre-conv stream tail
-    xp = jnp.pad(x, ((0, 0), (CONV_K - 1, 0), (0, 0)))
-    conv = sum(
-        xp[:, i : i + S, :] * p["conv_w"][i].astype(h.dtype) for i in range(CONV_K)
-    )
-    x = jax.nn.silu(conv)
+    z, xbc, dt = _split_proj(p, u, n_heads, d_head, d_state)
+    # pre-conv stream tail, zero-filled on the left for S < CONV_K - 1
+    conv_tail = jnp.pad(xbc, ((0, 0), (CONV_K - 1, 0), (0, 0)))[:, S:, :]
+    x, Bm, Cm = _split_xbc(_causal_conv(p, xbc), di, d_state)
 
     A = -jnp.exp(p["A_log"])
     xh = x.reshape(B, S, n_heads, d_head)
@@ -173,7 +183,7 @@ def mamba2_prefill(
     y = y + xh * p["D"][None, None, :, None].astype(y.dtype)
     y = y.reshape(B, S, di)
     y = y * jax.nn.silu(z)
-    y = rms_norm(y, p["norm"])
+    y = rms_norm(y, p["norm"], eps)
     out = y @ p["out_proj"].astype(h.dtype)
     cache = {
         "conv": conv_tail.astype(jnp.float32),
@@ -183,9 +193,9 @@ def mamba2_prefill(
 
 
 def init_mamba2_cache(batch: int, n_heads: int, d_head: int, d_state: int, dtype=jnp.float32):
-    di = n_heads * d_head
+    conv_dim = n_heads * d_head + 2 * d_state
     return {
-        "conv": jnp.zeros((batch, CONV_K - 1, di), dtype),
+        "conv": jnp.zeros((batch, CONV_K - 1, conv_dim), dtype),
         "ssm": jnp.zeros((batch, n_heads, d_state, d_head), jnp.float32),
     }
 
@@ -197,22 +207,23 @@ def mamba2_decode(
     n_heads: int,
     d_head: int,
     d_state: int,
+    eps: float = 1e-6,
 ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
     B = h.shape[0]
     di = n_heads * d_head
     u = h @ p["in_proj"].astype(h.dtype)
-    z, x, Bm, Cm, dt = _split_proj(p, u, n_heads, d_head, d_state)
-    x = x[:, 0]  # [B, di]
+    z, xbc, dt = _split_proj(p, u, n_heads, d_head, d_state)
     z = z[:, 0]
-    Bm = Bm[:, 0].astype(jnp.float32)  # [B, N]
-    Cm = Cm[:, 0].astype(jnp.float32)
     dt = dt[:, 0]  # [B, H]
 
-    # conv cache: window = [tail, x]
-    win = jnp.concatenate([cache["conv"], x[:, None, :].astype(cache["conv"].dtype)], axis=1)
-    conv = sum(win[:, i, :] * p["conv_w"][i].astype(h.dtype) for i in range(CONV_K))
-    xc = jax.nn.silu(conv)  # [B, di]
+    # conv cache: window = [tail, x‖B‖C]
+    win = jnp.concatenate([cache["conv"], xbc[:, :1, :].astype(cache["conv"].dtype)], axis=1)
+    conv = sum(win[:, i, :] * p["conv_w"][i].astype(win.dtype) for i in range(CONV_K))
+    conv = jax.nn.silu(conv + p["conv_b"].astype(win.dtype))  # [B, conv_dim]
     new_conv = win[:, 1:, :]
+    xc, Bm, Cm = _split_xbc(conv, di, d_state)
+    Bm = Bm.astype(jnp.float32)  # [B, N]
+    Cm = Cm.astype(jnp.float32)
 
     A = -jnp.exp(p["A_log"])  # [H]
     xh = xc.reshape(B, n_heads, d_head).astype(jnp.float32)
@@ -221,11 +232,16 @@ def mamba2_decode(
     s = dec[..., None, None] * s + dt[..., None, None] * (
         Bm[:, None, :, None] * xh[:, :, None, :]
     )
-    y = jnp.einsum("bn,bhnp->bhp", Cm, s)  # [B, H, P]
+    # the state is kept, and read back, in the cache's dtype; the read is a
+    # full-precision contraction (a TPU f32 dot would round it to bf16)
+    s = s.astype(cache["ssm"].dtype)
+    y = jnp.einsum(
+        "bn,bhnp->bhp", Cm, s.astype(jnp.float32), precision=jax.lax.Precision.HIGHEST
+    )  # [B, H, P]
     y = y + xh * p["D"][None, :, None]
     y = y.reshape(B, di).astype(h.dtype)
 
     y = y * jax.nn.silu(z)
-    y = rms_norm(y, p["norm"])
+    y = rms_norm(y, p["norm"], eps)
     out = (y @ p["out_proj"].astype(h.dtype)).reshape(B, 1, -1)
     return out, {"conv": new_conv, "ssm": s}

@@ -23,7 +23,7 @@ __all__ = ["ModelConfig", "Model"]
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
     name: str
-    family: str  # 'dense' | 'moe' | 'hybrid' | 'ssm' | 'vlm' | 'audio'
+    family: str  # 'dense' | 'moe' | 'hybrid' | 'granitemoehybrid' | 'ssm' | 'vlm' | 'audio'
     n_layers: int
     d_model: int
     n_heads: int
@@ -41,6 +41,11 @@ class ModelConfig:
     decode_capacity_factor: float = 2.0
     moe_dispatch: str = "einsum"  # 'einsum' | 'dense'
     moe_group_tokens: int = 4096  # GShard dispatch group size
+    # expert parallelism's share: this layer holds experts_held of the
+    # n_experts the router scores, from expert_offset (0 => all of them)
+    experts_held: int = 0
+    expert_offset: int = 0
+    shared_d_ff: int = 0  # shared-expert hidden; 0 => moe_d_ff
     # --- attention ---
     rope_variant: str = "rope"  # 'rope' | 'rope2d' | 'mrope' | 'none'
     rope_theta: float = 10_000.0
@@ -49,6 +54,7 @@ class ModelConfig:
     window: Optional[int] = None  # sliding-window span (attn layers)
     attn_block_q: int = 1024
     attn_block_k: int = 1024
+    attention_multiplier: float = 0.0  # score scale; 0 => d_head ** -0.5
     # --- SSM / hybrid ---
     ssm_state: int = 0
     ssm_heads: int = 0
@@ -59,6 +65,12 @@ class ModelConfig:
     tie_embeddings: bool = True
     embed_inputs: bool = True  # False: step takes precomputed embeddings
     norm: str = "rms"  # 'rms' | 'ln'
+    norm_eps: float = 1e-6  # RMSNorm epsilon
+    # Granite's scalar multipliers: embeddings × embedding_multiplier, each
+    # residual branch × residual_multiplier, logits / logits_scaling
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
     mlp_gated: bool = True  # False: plain 2-matrix GELU MLP (StarCoder2, encoders)
     # Cast every weight matrix to cfg.dtype ONCE at step entry (instead of at
     # each use).  Under FSDP this moves the cast BEFORE the parameter
@@ -90,6 +102,10 @@ class ModelConfig:
             object.__setattr__(self, "moe_d_ff", self.d_ff)
 
     @property
+    def n_held_experts(self) -> int:
+        return self.experts_held or self.n_experts
+
+    @property
     def padded_vocab(self) -> int:
         m = self.pad_vocab_to_multiple
         if m and self.vocab_size % m:
@@ -114,13 +130,18 @@ class ModelConfig:
             )
         if fam == "ssm":
             return (("mamba", None if self.d_ff == 0 else "mlp"),)
-        if fam == "hybrid":
+        if fam in ("hybrid", "granitemoehybrid"):
             k = self.attn_every
-            attn_pos = k // 2  # attention mid-group (Jamba places it interior)
+            # attention mid-group (Jamba places it interior; Granite-4.0-H
+            # at offset 5 of each period of 10)
+            attn_pos = k // 2
             spec = []
             for i in range(k):
                 mixer = "attn" if i == attn_pos else "mamba"
-                ffn = "moe" if (self.n_experts and i % 2 == 1) else "mlp"
+                if fam == "granitemoehybrid":  # MoE (+ shared expert) everywhere
+                    ffn = "moe"
+                else:
+                    ffn = "moe" if (self.n_experts and i % 2 == 1) else "mlp"
                 spec.append((mixer, ffn))
             return tuple(spec)
         raise ValueError(f"unknown family {fam}")
@@ -240,21 +261,27 @@ class Model:
     def _embed(self, params, tokens_or_embeds):
         cfg = self.cfg
         if cfg.embed_inputs:
-            return params["embed"].astype(cfg.dtype)[tokens_or_embeds]
-        return tokens_or_embeds.astype(cfg.dtype)
+            x = params["embed"].astype(cfg.dtype)[tokens_or_embeds]
+        else:
+            x = tokens_or_embeds.astype(cfg.dtype)
+        if cfg.embedding_multiplier != 1.0:
+            x = x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
+        return x
 
     def _head(self, params, x):
         cfg = self.cfg
         xn = (
             layer_norm(x, params["final_norm"]["g"], params["final_norm"]["b"])
             if cfg.norm == "ln"
-            else rms_norm(x, params["final_norm"])
+            else rms_norm(x, params["final_norm"], cfg.norm_eps)
         )
         if "lm_head" in params:
             w = params["lm_head"].astype(cfg.dtype)
         else:
             w = params["embed"].T.astype(cfg.dtype)
         logits = xn @ w  # [B, S, V_padded]
+        if cfg.logits_scaling != 1.0:
+            logits = logits / jnp.asarray(cfg.logits_scaling, logits.dtype)
         if cfg.padded_vocab != cfg.vocab_size:
             # mask pad columns to -inf: loss/argmax identical to unpadded
             col = jnp.arange(cfg.padded_vocab)
@@ -318,22 +345,29 @@ class Model:
                 "v": jnp.zeros(shape, cfg.cache_dtype),
             }
         if nm:
-            di = cfg.ssm_heads * cfg.ssm_d_head
-            cache["ssm_conv"] = jnp.zeros((G, nm, batch, 3, di), jnp.float32)
+            conv_dim = cfg.ssm_heads * cfg.ssm_d_head + 2 * cfg.ssm_state  # x‖B‖C
+            cache["ssm_conv"] = jnp.zeros((G, nm, batch, 3, conv_dim), jnp.float32)
             cache["ssm_state"] = jnp.zeros(
                 (G, nm, batch, cfg.ssm_heads, cfg.ssm_state, cfg.ssm_d_head),
                 jnp.float32,
             )
         return cache
 
-    def decode_step(self, params, caches, token_or_embed, cache_len):
-        """One token for every sequence; returns (logits [B,V], new_caches)."""
+    def decode_step(self, params, caches, token_or_embed, cache_len, expert_counts=False):
+        """One token for every sequence; returns (logits [B,V], new_caches),
+        and with ``expert_counts`` (held-expert families) also the int32
+        ``[n_layers, n_held_experts]`` count of tokens routed to each held
+        expert of each layer."""
         cfg = self.cfg
         x = self._embed(params, token_or_embed)  # [B, 1, D]
         B = x.shape[0]
         positions = self._positions(B, 1, offset=cache_len)
-        x, new_caches = tf.decode_stack(
+        x, new_caches, counts = tf.decode_stack(
             params["blocks"], x, positions, caches, cache_len, cfg
         )
         logits = self._head(params, x)[:, 0]
+        if expert_counts:
+            if counts is None:
+                raise ValueError(f"{cfg.name}: no held-expert layers to count")
+            return logits, new_caches, counts.reshape(cfg.n_layers, cfg.n_held_experts)
         return logits, new_caches

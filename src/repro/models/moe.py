@@ -19,7 +19,7 @@ import jax.numpy as jnp
 
 from .layers import init_linear
 
-__all__ = ["init_moe", "moe_block"]
+__all__ = ["held_moe_block", "init_moe", "moe_block"]
 
 Params = Dict[str, jnp.ndarray]
 
@@ -30,10 +30,15 @@ def init_moe(
     d_ff: int,
     n_experts: int,
     shared_expert: bool = False,
+    n_router: int = 0,
+    shared_d_ff: int = 0,
 ) -> Params:
+    """``n_experts`` expert weight sets; the router scores ``n_router``
+    experts (default all of them: a layer that holds only its share of a
+    layer's experts still routes over every expert)."""
     ks = jax.random.split(key, 5)
     p = {
-        "router": init_linear(ks[0], d_model, n_experts),
+        "router": init_linear(ks[0], d_model, n_router or n_experts),
         # stacked expert weights: [E, d_model, d_ff] / [E, d_ff, d_model]
         "wi": jax.random.truncated_normal(ks[1], -3, 3, (n_experts, d_model, d_ff), jnp.float32) * d_model ** -0.5,
         "wu": jax.random.truncated_normal(ks[2], -3, 3, (n_experts, d_model, d_ff), jnp.float32) * d_model ** -0.5,
@@ -41,10 +46,69 @@ def init_moe(
     }
     if shared_expert:
         kk = jax.random.split(ks[4], 3)
-        p["shared_wi"] = init_linear(kk[0], d_model, d_ff)
-        p["shared_wu"] = init_linear(kk[1], d_model, d_ff)
-        p["shared_wo"] = init_linear(kk[2], d_ff, d_model, scale=d_ff ** -0.5)
+        sf = shared_d_ff or d_ff
+        p["shared_wi"] = init_linear(kk[0], d_model, sf)
+        p["shared_wu"] = init_linear(kk[1], d_model, sf)
+        p["shared_wo"] = init_linear(kk[2], sf, d_model, scale=sf ** -0.5)
     return p
+
+
+def _shared_expert(p: Params, x: jnp.ndarray) -> jnp.ndarray:
+    h = jax.nn.silu(x @ p["shared_wi"].astype(x.dtype)) * (x @ p["shared_wu"].astype(x.dtype))
+    return h @ p["shared_wo"].astype(x.dtype)
+
+
+def held_moe_block(
+    p: Params,
+    x: jnp.ndarray,  # [B, S, d_model]
+    top_k: int,
+    expert_offset: int = 0,
+) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """GraniteMoE routing over a held share of the experts.
+
+    The router scores all ``E`` experts; each token takes the top-``k``
+    logits and a softmax over those ``k`` (GraniteMoE's order: top-k, then
+    softmax).  This layer holds experts ``expert_offset ..
+    expert_offset + E_held - 1`` (``p['wi']`` has ``E_held`` rows) and adds
+    only their part of the result, for every token routed to them: no
+    capacity, so no token is dropped.  The held experts are computed densely
+    (each one for every token, weighted by its gate, 0 where not routed).
+    The shared expert, when present, is added in full.
+
+    The router and its softmax run in float32, the router at full matmul
+    precision (a TPU float32 dot otherwise rounds its inputs to bfloat16).
+
+    Returns (output [B,S,D], aux_loss, counts [E_held] int32: tokens
+    routed to each held expert).
+    """
+    B, S, D = x.shape
+    E = p["router"].shape[1]
+    n_held = p["wi"].shape[0]
+    T = B * S
+    xt = x.reshape(T, D)
+    logits = jnp.dot(
+        xt.astype(jnp.float32), p["router"].astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
+    )  # [T, E]
+    top, idx = jax.lax.top_k(logits, top_k)  # [T, k]
+    gates = jax.nn.softmax(top, axis=-1)
+    local = idx - expert_offset
+    onehot = jax.nn.one_hot(local, n_held, dtype=jnp.int32)  # [T, k, E_held]; 0 off-share
+    counts = onehot.sum(axis=(0, 1))
+    comb = (onehot.astype(gates.dtype) * gates[..., None]).sum(axis=1)  # [T, E_held]
+
+    h = jnp.einsum("td,edf->tef", xt, p["wi"].astype(x.dtype))
+    u = jnp.einsum("td,edf->tef", xt, p["wu"].astype(x.dtype))
+    eo = jnp.einsum("tef,efd->ted", jax.nn.silu(h) * u, p["wo"].astype(x.dtype))
+    out = jnp.einsum("ted,te->td", eo, comb.astype(x.dtype))
+    if "shared_wi" in p:
+        out = out + _shared_expert(p, xt)
+
+    # load-balance aux loss over the whole router: E · Σ_e f_e · P_e
+    probs = jax.nn.softmax(logits, axis=-1)
+    ce = jnp.zeros((E,), jnp.float32).at[idx.reshape(-1)].add(1.0) / (T * top_k)
+    aux = E * jnp.sum(probs.mean(axis=0) * ce)
+    return out.reshape(B, S, D), aux, counts.astype(jnp.int32)
 
 
 def moe_block(
@@ -144,10 +208,7 @@ def moe_block(
         out = jnp.einsum("gsec,gecd->gsd", cw, eo)
 
     if "shared_wi" in p:
-        h = jax.nn.silu(xg @ p["shared_wi"].astype(x.dtype)) * (
-            xg @ p["shared_wu"].astype(x.dtype)
-        )
-        out = out + h @ p["shared_wo"].astype(x.dtype)
+        out = out + _shared_expert(p, xg)
 
     out = out.reshape(Gm * gs, D)[:T]
     return out.reshape(B, S, D), aux

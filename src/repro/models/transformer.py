@@ -11,6 +11,9 @@ Group composition per family (cfg.group_spec()):
   moe     1 group  = [attn+mlp] × (interleave−1) + [attn+moe]
   hybrid  1 group  = attn_every sublayers, one of them attention, the rest
           Mamba2; FFNs alternate dense/MoE (Jamba's 1:7 + MoE-every-2)
+  granitemoehybrid = hybrid's period (attention mid-group), MoE with
+          a shared expert in every sublayer, over a held share of the
+          experts (moe.held_moe_block); Granite's residual multiplier
   ssm     1 group  = [mamba2]                             × n_layers
   vlm     = dense (M-RoPE positions)
   audio   = dense non-causal encoder (LN + GELU MLP)
@@ -77,8 +80,9 @@ def init_group(key, cfg) -> Params:
                     sub["mlp"] = init_gated_mlp(sk[1], cfg.d_model, cfg.d_ff)
             elif ffn == "moe":
                 sub["moe"] = moe_mod.init_moe(
-                    sk[1], cfg.d_model, cfg.moe_d_ff or cfg.d_ff, cfg.n_experts,
-                    shared_expert=cfg.shared_expert,
+                    sk[1], cfg.d_model, cfg.moe_d_ff or cfg.d_ff, cfg.n_held_experts,
+                    shared_expert=cfg.shared_expert, n_router=cfg.n_experts,
+                    shared_d_ff=cfg.shared_d_ff,
                 )
             else:
                 raise ValueError(ffn)
@@ -89,7 +93,34 @@ def init_group(key, cfg) -> Params:
 def _norm(cfg, x, np_):
     if cfg.norm == "ln":
         return layer_norm(x, np_["g"], np_["b"])
-    return rms_norm(x, np_)
+    return rms_norm(x, np_, cfg.norm_eps)
+
+
+def _residual(cfg, x, branch):
+    if cfg.residual_multiplier != 1.0:
+        branch = branch * jnp.asarray(cfg.residual_multiplier, branch.dtype)
+    return x + branch
+
+
+def _attn_scale(cfg):
+    return cfg.attention_multiplier or None  # None => d_head ** -0.5
+
+
+def _ffn(cfg, sub, ffn, h, decode: bool):
+    """One FFN sublayer: (out, aux, held-expert counts or None)."""
+    if ffn == "mlp":
+        out = (
+            dense_mlp(sub["mlp"], h) if (cfg.norm == "ln" or not cfg.mlp_gated) else gated_mlp(sub["mlp"], h)
+        )
+        return out, jnp.zeros((), jnp.float32), None
+    if cfg.family == "granitemoehybrid":
+        return moe_mod.held_moe_block(sub["moe"], h, cfg.top_k, expert_offset=cfg.expert_offset)
+    out, a = moe_mod.moe_block(
+        sub["moe"], h, cfg.top_k,
+        capacity_factor=cfg.decode_capacity_factor if decode else cfg.capacity_factor,
+        dispatch=cfg.moe_dispatch, group_tokens=cfg.moe_group_tokens,
+    )
+    return out, a, None
 
 
 # --------------------------------------------------------------------------- #
@@ -129,7 +160,7 @@ def apply_group(
                     positions, cfg.rope_variant, cfg.qk_norm, cfg.rope_theta,
                 )
                 o = attn_mod.chunked_attention(
-                    q, k, v, causal=cfg.causal,
+                    q, k, v, causal=cfg.causal, scale=_attn_scale(cfg),
                     block_q=cfg.attn_block_q, block_k=cfg.attn_block_k,
                     window=cfg.window,
                 )
@@ -148,35 +179,28 @@ def apply_group(
                     causal=cfg.causal, rope_variant=cfg.rope_variant,
                     qk_norm=cfg.qk_norm, theta=cfg.rope_theta, window=cfg.window,
                     block_q=cfg.attn_block_q, block_k=cfg.attn_block_k,
+                    scale=_attn_scale(cfg),
                 )
         else:  # mamba
             if collect_cache:
                 mix, mcache = m2.mamba2_prefill(
                     sub["mamba"], h, cfg.ssm_heads, cfg.ssm_d_head, cfg.ssm_state,
-                    chunk=cfg.ssm_chunk,
+                    chunk=cfg.ssm_chunk, eps=cfg.norm_eps,
                 )
                 ssm_conv.append(mcache["conv"])
                 ssm_state.append(mcache["ssm"])
             else:
                 mix = m2.mamba2_block(
                     sub["mamba"], h, cfg.ssm_heads, cfg.ssm_d_head, cfg.ssm_state,
-                    chunk=cfg.ssm_chunk,
+                    chunk=cfg.ssm_chunk, eps=cfg.norm_eps,
                 )
-        x = x + mix
+        x = _residual(cfg, x, mix)
         if ffn is not None:
             h = _norm(cfg, x, sub["norm2"])
-            if ffn == "mlp":
-                out = (
-                    dense_mlp(sub["mlp"], h) if (cfg.norm == "ln" or not cfg.mlp_gated) else gated_mlp(sub["mlp"], h)
-                )
-            else:
-                out, a = moe_mod.moe_block(
-                    sub["moe"], h, cfg.top_k,
-                    capacity_factor=cfg.capacity_factor, dispatch=cfg.moe_dispatch,
-                    group_tokens=cfg.moe_group_tokens,
-                )
+            out, a, _ = _ffn(cfg, sub, ffn, h, decode=False)
+            if ffn == "moe":
                 aux = aux + a
-            x = x + out
+            x = _residual(cfg, x, out)
     cache = None
     if collect_cache:
         cache = {}
@@ -200,8 +224,12 @@ def decode_group(
     cache: Dict[str, Any],  # this group's cache slice
     cache_len,
     cfg,
-) -> Tuple[jnp.ndarray, Dict[str, Any]]:
+) -> Tuple[jnp.ndarray, Dict[str, Any], Optional[jnp.ndarray]]:
+    """Returns (x, new_cache, counts): ``counts`` is the int32
+    [group_size, n_held_experts] held-expert routing count of a
+    granitemoehybrid group, else None."""
     new_cache: Dict[str, Any] = {}
+    counts: List = []
     ai = 0
     mi = 0
     for i, (mixer, ffn) in enumerate(cfg.group_spec()):
@@ -213,7 +241,7 @@ def decode_group(
                 sub["attn"], h, positions, kv, cache_len,
                 cfg.n_heads, cfg.n_kv_heads, cfg.d_head,
                 rope_variant=cfg.rope_variant, qk_norm=cfg.qk_norm,
-                theta=cfg.rope_theta, window=cfg.window,
+                theta=cfg.rope_theta, window=cfg.window, scale=_attn_scale(cfg),
             )
             new_cache.setdefault("kv", {"k": [], "v": []})
             new_cache["kv"]["k"].append(kv_new[0])
@@ -222,25 +250,19 @@ def decode_group(
         else:
             mc = {"conv": cache["ssm_conv"][mi], "ssm": cache["ssm_state"][mi]}
             mix, mc_new = m2.mamba2_decode(
-                sub["mamba"], h, mc, cfg.ssm_heads, cfg.ssm_d_head, cfg.ssm_state
+                sub["mamba"], h, mc, cfg.ssm_heads, cfg.ssm_d_head, cfg.ssm_state,
+                eps=cfg.norm_eps,
             )
             new_cache.setdefault("ssm_conv", []).append(mc_new["conv"])
             new_cache.setdefault("ssm_state", []).append(mc_new["ssm"])
             mi += 1
-        x = x + mix
+        x = _residual(cfg, x, mix)
         if ffn is not None:
             h = _norm(cfg, x, sub["norm2"])
-            if ffn == "mlp":
-                out = (
-                    dense_mlp(sub["mlp"], h) if (cfg.norm == "ln" or not cfg.mlp_gated) else gated_mlp(sub["mlp"], h)
-                )
-            else:
-                out, _ = moe_mod.moe_block(
-                    sub["moe"], h, cfg.top_k,
-                    capacity_factor=cfg.decode_capacity_factor, dispatch=cfg.moe_dispatch,
-                    group_tokens=cfg.moe_group_tokens,
-                )
-            x = x + out
+            out, _, c = _ffn(cfg, sub, ffn, h, decode=True)
+            if c is not None:
+                counts.append(c)
+            x = _residual(cfg, x, out)
     # restack lists into arrays
     if "kv" in new_cache:
         new_cache["kv"] = {
@@ -250,7 +272,7 @@ def decode_group(
     if "ssm_conv" in new_cache:
         new_cache["ssm_conv"] = jnp.stack(new_cache["ssm_conv"])
         new_cache["ssm_state"] = jnp.stack(new_cache["ssm_state"])
-    return x, new_cache
+    return x, new_cache, (jnp.stack(counts) if counts else None)
 
 
 # --------------------------------------------------------------------------- #
@@ -325,20 +347,22 @@ def apply_stack(
 
 
 def decode_stack(stack: Params, x, positions, caches, cache_len, cfg):
-    """Scan decode over groups with per-group cache slices."""
+    """Scan decode over groups with per-group cache slices.  Returns (x,
+    new_caches, counts): ``counts`` is [n_groups, group_size,
+    n_held_experts] for held-expert groups, else None."""
 
     def body(h, inp):
         gp, cache = inp
-        h, new_cache = decode_group(gp, h, positions, cache, cache_len, cfg)
-        return h, new_cache
+        h, new_cache, counts = decode_group(gp, h, positions, cache, cache_len, cfg)
+        return h, (new_cache, counts)
 
     if cfg.scan_layers:
-        x, new_caches = jax.lax.scan(body, x, (stack, caches))
+        x, (new_caches, counts) = jax.lax.scan(body, x, (stack, caches))
     else:
         new_list = []
         for i, gp in enumerate(stack):
             c = jax.tree.map(lambda a: a[i], caches)
             x, nc = body(x, (gp, c))
             new_list.append(nc)
-        new_caches = jax.tree.map(lambda *xs: jnp.stack(xs), *new_list)
-    return x, new_caches
+        new_caches, counts = jax.tree.map(lambda *xs: jnp.stack(xs), *new_list)
+    return x, new_caches, counts

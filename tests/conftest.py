@@ -16,6 +16,35 @@ import numpy as np
 import pytest
 
 
+# XLA's CPU backend maps each compiled program's code into the process,
+# some 1,500 mappings for one qos cascade pair of a new shape.  A worker
+# that runs many compiling tests reaches the kernel's vm.max_map_count
+# (65,530 by default), and LLVM then fails to compile with "Cannot allocate
+# memory" in whichever test comes next.  Past this many mappings the jit
+# caches are dropped after a test, which unmaps the programs no one holds.
+_MAP_HIGH = 10_000
+
+
+def _n_maps() -> int:
+    try:
+        with open("/proc/self/maps", "rb") as f:
+            return sum(1 for _ in f)
+    except OSError:
+        return 0
+
+
+@pytest.fixture(autouse=True)
+def _bounded_code_maps():
+    yield
+    if _n_maps() > _MAP_HIGH:
+        import gc
+
+        import jax
+
+        jax.clear_caches()
+        gc.collect()
+
+
 @pytest.fixture(scope="session")
 def rng():
     return np.random.default_rng(0)

@@ -90,9 +90,9 @@ def test_smoke_prefill_decode_roundtrip(arch):
 
 def test_cells_accounting():
     cells = cfgs.cells()
-    assert len(cells) == 40
+    assert len(cells) == 44
     runnable = [c for c in cells if c["runnable"]]
-    assert len(runnable) == 31
+    assert len(runnable) == 35
     for c in cells:
         if not c["runnable"]:
             assert c["skip"]
@@ -120,6 +120,7 @@ def test_param_counts_hit_targets():
         "mamba2-2.7b": (2.7e9, 0.05),
         "qwen2-vl-72b": (72e9, 0.05),
         "hubert-xlarge": (1e9, 0.15),
+        "granite-4.0-h-small": (32.2e9, 0.03),
     }
     for arch, (want, tol) in targets.items():
         got = cfgs.get_config(arch).param_counts()["total"]

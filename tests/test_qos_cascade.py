@@ -599,6 +599,30 @@ def test_stage_packed_caps_decay_after_idle_streak():
     assert stage(3000)[0] >= 4096
 
 
+def test_stage_packed_caps_grow_from_the_floor():
+    """A stage whose held cap sits at the floor grows when its demand
+    outgrows the floor (a routing-driven program can go from no routed
+    events in a step to many in the next)."""
+    stager = EventStager()
+    enter = np.asarray([-1, 0], np.int32)
+
+    def stage(n_local, n_routed):
+        n = n_local + n_routed
+        ev = MemEvents.build(
+            t_ns=np.arange(1, n + 1, dtype=np.float64),
+            pool=np.r_[np.zeros(n_local, np.int64), np.ones(n_routed, np.int64)],
+            bytes_=np.full(n, 64.0),
+        )
+        _, pack, caps = stager.stage_packed([ev], 1, 512, enter, 1)
+        return pack, caps
+
+    _, caps = stage(100, 0)
+    assert caps == (16,)
+    pack, caps = stage(100, 128)
+    assert caps == (128,)
+    assert int((pack["idx"][0] >= 0).sum()) == 128
+
+
 def test_stage_packed_oscillating_workload_never_decays():
     stager = EventStager()
     enter = np.asarray([-1, 0], np.int32)
