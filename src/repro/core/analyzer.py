@@ -69,7 +69,8 @@ from __future__ import annotations
 
 import dataclasses
 import heapq
-from typing import Any, List, Optional, Sequence, Tuple
+import math
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -78,7 +79,7 @@ import numpy as np
 from ..analysis.annotations import axes
 from . import spans
 from .aot import AotDispatchCache
-from .events import EventStager, MemEvents
+from .events import EventStager, MemEvents, PlaneLayout, plane_layout
 from .topology import FlatTopology
 
 # f32 contractions at full precision: a TPU's default f32 dot rounds its
@@ -614,34 +615,50 @@ def plan_chain(flat: FlatTopology) -> Optional[ChainPlan]:
     return ChainPlan(enter_stage=enter, stage_order=stage_order)
 
 
-@axes(
-    "B,W", "B,W", "B,N", "B,N", "B,N", "B,N", "B", "B,V", "V", "",
-    "V,S", "S", "S",
-)
+def unpack_plane(words: jnp.ndarray, layout: PlaneLayout) -> Dict[str, jnp.ndarray]:
+    """The staged columns of one word plane, restored on device: each is
+    a static slice at its :func:`~repro.core.events.plane_layout` offset,
+    float columns bitcast back from their int32 words (a transposed one
+    transposed back) and ``valid`` compared with 0 — the bits the host
+    staged, unchanged."""
+    cols = {}
+    for name, off, shape, kind in layout:
+        w = jax.lax.slice(words, (off,), (off + math.prod(shape),))
+        if kind == "t":
+            w = jax.lax.bitcast_convert_type(w.reshape(shape[::-1]), jnp.float32).T
+        elif kind == "f":
+            w = jax.lax.bitcast_convert_type(w.reshape(shape), jnp.float32)
+        elif kind == "b":
+            w = w.reshape(shape) != 0
+        else:
+            w = w.reshape(shape)
+        cols[name] = w
+    return cols
+
+
+@axes("P", "V", "", "V,S", "S", "S")
 def _analyze_pipeline_jax(
-    t_pack: jnp.ndarray,  # [B, W] f32 per-stage packed sorted runs (+inf pads) — DONATED
-    idx_pack: jnp.ndarray,  # [B, W] i32 positions into the staged row (-1 pads) — DONATED
-    pool: jnp.ndarray,  # [B, N] i32 full plane (staged row order)
-    nbytes: jnp.ndarray,  # [B, N] f32
-    weight: jnp.ndarray,  # [B, N] f32
-    valid: jnp.ndarray,  # [B, N] bool
-    bw_window_ns: jnp.ndarray,  # [B]
-    lat_scale: jnp.ndarray,  # [B, V]
+    words: jnp.ndarray,  # [P] i32 staged word plane (chain layout) — DONATED
     pool_latency_ns: jnp.ndarray,  # [V]
     local_latency_ns: jnp.ndarray,  # []
     route: jnp.ndarray,  # [V, S]
     switch_stt_ns: jnp.ndarray,  # [S]
     switch_bw: jnp.ndarray,  # [S]
+    layout: PlaneLayout,  # static: the plane's columns
     stage_order: Tuple[int, ...],  # static
     seg_caps: Tuple[int, ...],  # static packed segment capacities
     n_windows: int,  # static
 ):
     """Device-resident single-host chain dispatch (the pipeline hot path).
 
-    The merge of per-stage sorted runs into one fabric timeline and every
-    serial-queue scan happen **inside this graph**
-    (:func:`repro.kernels.ref.chain_cascade` over a compact suffix that
-    only ever holds routed events), so staging performed zero host
+    ``words`` carries every staged column in one transfer: the per-stage
+    packed sorted runs ``t_pack`` (+inf pads) and ``idx_pack`` (positions
+    into the staged row, -1 pads), the full-plane ``pool``, ``bytes``,
+    ``weight`` and ``valid``, then ``bw_window`` and ``scale``
+    (:func:`unpack_plane`).  The merge of per-stage sorted runs into one
+    fabric timeline and every serial-queue scan happen **inside this
+    graph** (:func:`repro.kernels.ref.chain_cascade` over a compact suffix
+    that only ever holds routed events), so staging performed zero host
     argsorts.  Bandwidth windows come straight off the compact array:
     local-DRAM route rows are all zero, so unrouted events could only ever
     contribute zero bytes to every switch — skipping them is exact, and
@@ -649,10 +666,13 @@ def _analyze_pipeline_jax(
     than padded ``N``.  Latency stays a full-plane gather (it needs no
     times).  Returns the ten breakdown leaves of :func:`_analyze_jax`
     (this path is FIFO-only, so the per-class leaf is the degenerate
-    ``[congestion]``) plus ``(t_fin, idx_fin)`` — shaped/typed exactly
-    like the two donated inputs, so XLA serves them from the donated
-    buffers and steady-state dispatch allocates nothing on device.
+    ``[congestion]``) plus the plane with the final ``(t, idx)`` runs
+    written over the packed ones — shaped and typed like the donated
+    input, so XLA serves it from the donated buffer and steady-state
+    dispatch allocates nothing on device.
     """
+    c = unpack_plane(words, layout)
+    t_pack, idx_pack = c["t_pack"], c["idx_pack"]
     V = pool_latency_ns.shape[0]
     S = switch_stt_ns.shape[0]
     f32 = t_pack.dtype
@@ -710,10 +730,15 @@ def _analyze_pipeline_jax(
         )
 
     outs = jax.vmap(one)(
-        t_pack, idx_pack, pool, nbytes, weight, valid, bw_window_ns, lat_scale
+        t_pack, idx_pack, c["pool"], c["bytes"], c["weight"], c["valid"],
+        c["bw_window"], c["scale"],
     )
     summed = tuple(x.sum(axis=0) for x in outs[:10])
-    return summed + (outs[10], outs[11])
+    off = {name: o for name, o, _, _ in layout}
+    t_fin = jax.lax.bitcast_convert_type(outs[10], jnp.int32).ravel()
+    words = jax.lax.dynamic_update_slice(words, t_fin, (off["t_pack"],))
+    words = jax.lax.dynamic_update_slice(words, outs[11].ravel(), (off["idx_pack"],))
+    return summed + (words,)
 
 
 @axes(
@@ -1009,6 +1034,40 @@ def _analyze_batch_jax(
     else:
         outs = jax.vmap(one)(*xs)
     return jax.tree.map(lambda x: x.sum(axis=0), outs)
+
+
+@axes("P", "V", "V", "", "V,S", "S", "S", "S", "S,C")
+def _analyze_words_jax(
+    words: jnp.ndarray,  # [P] i32 staged word plane (full-plane layout)
+    bits_table: jnp.ndarray,  # [V]
+    pool_latency_ns: jnp.ndarray,
+    local_latency_ns: jnp.ndarray,
+    route: jnp.ndarray,
+    switch_stt_ns: jnp.ndarray,
+    switch_bw: jnp.ndarray,
+    disc_code: jnp.ndarray,  # [S]
+    class_weights: jnp.ndarray,  # [S, C]
+    layout: PlaneLayout,  # static: the plane's columns
+    stage_order: Tuple[int, ...],
+    n_windows: int,
+    n_hosts: int,
+    impl: str = "inline",
+    fused: bool = True,
+    merge_plan=None,
+    qos_on: bool = False,
+):
+    """:func:`_analyze_batch_jax` on one staged word plane: the dispatch
+    of :meth:`EpochAnalyzer.launch_batch` off the chain path, whose nine
+    columns cross to the device in one transfer (:func:`unpack_plane`)."""
+    c = unpack_plane(words, layout)
+    return _analyze_batch_jax(
+        c["t"], c["pool"], c["bytes"], c["weight"], c["host"], c["qos"],
+        c["valid"], c["bw_window"], c["scale"], bits_table, pool_latency_ns,
+        local_latency_ns, route, switch_stt_ns, switch_bw, disc_code,
+        class_weights, stage_order=stage_order, n_windows=n_windows,
+        n_hosts=n_hosts, impl=impl, fused=fused, merge_plan=merge_plan,
+        qos_on=qos_on,
+    )
 
 
 @axes(
@@ -1363,8 +1422,8 @@ class PendingBatch:
             a.last_dispatch = self.stats
             return DelayBreakdown.zero(P, S, H)
         # the single host-boundary crossing for the whole batch; the
-        # pipeline dispatch's trailing (t_fin, idx_pack) leaves stay on
-        # device and are simply dropped
+        # chain dispatch's trailing plane stays on device and is simply
+        # dropped
         host, wait_s, d2h_s = collect_dispatch(self.out[:10])
         lat, cong, bw, ppl, psc, psb, phl, phc, phb, pcc = host
         stats = dataclasses.replace(self.stats, wait_s=wait_s, d2h_s=d2h_s)
@@ -1457,7 +1516,9 @@ class EpochAnalyzer:
             "stage_order", "n_windows", "n_hosts", "impl", "fused",
             "merge_plan", "qos_on",
         )
-        self._batch_fn = jax.jit(_analyze_batch_jax, static_argnames=_static)
+        self._batch_fn = jax.jit(
+            _analyze_words_jax, static_argnames=_static + ("layout",)
+        )
         self._multi_fn = jax.jit(_analyze_multi_jax, static_argnames=_static)
         self.pipeline = bool(pipeline)
         self._chain_plan: Optional[ChainPlan] = None
@@ -1500,16 +1561,19 @@ class EpochAnalyzer:
             _check_reachable(self.flat, tr)
         return pairs
 
-    def _aot_build(self, chain: Optional[ChainPlan], caps, b_bucket, n_bucket, dev_args):
+    def _aot_build(
+        self, chain: Optional[ChainPlan], caps, b_bucket, n_bucket, layout, words
+    ):
         """(cache key, build thunk) for this dispatch's AOT executable.
 
         The key carries what varies *within* one analyzer: the dispatch
         kind and the bucketed shapes (chain-path segment capacities
-        included — they are static operands of the compact cascade).  The
-        topology fingerprint and mesh are fixed per analyzer and its
-        private cache, so they need no key bits here; the engine's
-        ``dispatch_key`` separates analyzers."""
-        sds = tuple(jax.ShapeDtypeStruct(a.shape, a.dtype) for a in dev_args)
+        included — they are static operands of the compact cascade, and
+        with the shapes they fix the plane's ``layout``).  The topology
+        fingerprint and mesh are fixed per analyzer and its private cache,
+        so they need no key bits here; the engine's ``dispatch_key``
+        separates analyzers."""
+        words_s = jax.ShapeDtypeStruct(words.shape, words.dtype)
         topo = (self._pool_lat, self._local_lat, self._route, self._stt, self._bw)
         topo_s = tuple(jax.ShapeDtypeStruct(a.shape, a.dtype) for a in topo)
         if chain is not None:
@@ -1518,11 +1582,12 @@ class EpochAnalyzer:
             def build():
                 jitted = jax.jit(
                     _analyze_pipeline_jax,
-                    static_argnames=("stage_order", "seg_caps", "n_windows"),
-                    donate_argnums=(0, 1),
+                    static_argnames=("layout", "stage_order", "seg_caps", "n_windows"),
+                    donate_argnums=(0,),
                 )
                 return jitted.lower(
-                    *sds, *topo_s,
+                    words_s, *topo_s,
+                    layout=layout,
                     stage_order=chain.stage_order,
                     seg_caps=caps,
                     n_windows=self.n_windows,
@@ -1540,14 +1605,15 @@ class EpochAnalyzer:
 
             def build():
                 jitted = jax.jit(
-                    _analyze_batch_jax,
+                    _analyze_words_jax,
                     static_argnames=(
-                        "stage_order", "n_windows", "n_hosts", "impl",
+                        "layout", "stage_order", "n_windows", "n_hosts", "impl",
                         "fused", "merge_plan", "qos_on",
                     ),
                 )
                 return jitted.lower(
-                    *sds, bits_s, *topo_bs,
+                    words_s, bits_s, *topo_bs,
+                    layout=layout,
                     stage_order=self._stage_order,
                     n_windows=self.n_windows,
                     n_hosts=self.flat.n_hosts,
@@ -1573,11 +1639,12 @@ class EpochAnalyzer:
         the device-resident packed dispatch — on-device sort, donated
         staging buffers, AOT executable; other pipeline dispatches run
         the full-plane graph through the AOT cache; non-pipeline
-        analyzers launch the classic jitted path.  All three record the
+        analyzers launch the classic jitted path.  All three place one
+        staged word plane (one host→device transfer) and record the
         stage/transfer/compile/compute split in the pending stats.
         """
-        P, S = self.flat.n_pools, self.flat.n_switches
         H = self.flat.n_hosts
+        V = H * self.flat.n_pools
         pairs = self._clean_pairs(traces, lat_scales)
         if not pairs:
             return PendingBatch(self, None, DispatchStats(rows=0))
@@ -1589,41 +1656,34 @@ class EpochAnalyzer:
             chain = self._chain_plan
             caps = None
             if chain is not None:
-                buf, pack, caps = st.stage_packed(
+                buf, caps = st.stage_packed(
                     traces, b_bucket, n_bucket, chain.enter_stage,
-                    len(chain.stage_order),
+                    len(chain.stage_order), scale_width=V,
                 )
             else:
-                buf = st.stage(traces, b_bucket, n_bucket)
-                pack = None
-            np_dtype = np.dtype(jnp.dtype(self.dtype).name)
-            scale_buf = np.ones((b_bucket, H * P), np_dtype)
+                buf = st.stage(traces, b_bucket, n_bucket, scale_width=V)
+            scale = buf["scale"]
+            scale.fill(1.0)
             for row, (_, sc) in enumerate(pairs):
                 if sc is not None:
-                    scale_buf[row] = sc
+                    scale[row] = sc
             span = np.maximum(buf["span"], self.bw_window_ns)
-            bw_window = np.maximum(span / self.n_windows, 1.0).astype(np_dtype)
+            buf["bw_window"][:] = np.maximum(span / self.n_windows, 1.0)
+            layout = plane_layout(b_bucket, n_bucket, V, sum(caps) if caps else 0)
 
         from repro.distributed.sharding import timed_device_put
 
-        if chain is not None:
-            host_args = (
-                pack["t"], pack["idx"], buf["pool"], buf["bytes"],
-                buf["weight"], buf["valid"], bw_window, scale_buf,
-            )
-        else:
-            host_args = (
-                buf["t"], buf["pool"], buf["bytes"], buf["weight"],
-                buf["host"], buf["qos"], buf["valid"], bw_window, scale_buf,
-            )
         with spans.span("cxlsim.h2d") as h2d:
-            dev_args = timed_device_put(list(host_args))
+            words = timed_device_put(buf["words"])
+        spans.count(spans.H2D_BUFFERS, 1)
 
         compile_s = 0.0
         aot_hit = False
         donated = False
         if self.pipeline:
-            key, build = self._aot_build(chain, caps, b_bucket, n_bucket, dev_args)
+            key, build = self._aot_build(
+                chain, caps, b_bucket, n_bucket, layout, words
+            )
             built: List[float] = []
 
             def build_in_span():
@@ -1636,21 +1696,22 @@ class EpochAnalyzer:
             compile_s = sum(built)
             if chain is not None:
                 out, enqueue_s, jit_compile_s = enqueue_dispatch(
-                    exe, *dev_args, self._pool_lat, self._local_lat, self._route,
+                    exe, words, self._pool_lat, self._local_lat, self._route,
                     self._stt, self._bw,
                 )
-                donated = bool(dev_args[0].is_deleted())
+                donated = bool(words.is_deleted())
             else:
                 out, enqueue_s, jit_compile_s = enqueue_dispatch(
-                    exe, *dev_args, self._bits_table, self._pool_lat,
+                    exe, words, self._bits_table, self._pool_lat,
                     self._local_lat, self._route, self._stt, self._bw,
                     self._disc, self._weights,
                 )
         else:
             out, enqueue_s, jit_compile_s = enqueue_dispatch(
                 self._batch_fn,
-                *dev_args, self._bits_table, self._pool_lat, self._local_lat,
+                words, self._bits_table, self._pool_lat, self._local_lat,
                 self._route, self._stt, self._bw, self._disc, self._weights,
+                layout=layout,
                 stage_order=self._stage_order,
                 n_windows=self.n_windows,
                 n_hosts=H,

@@ -21,6 +21,7 @@ host-side in float64.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Dict, Iterable, Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -30,10 +31,13 @@ __all__ = [
     "PAGE_BYTES",
     "EventStager",
     "MemEvents",
+    "PlaneLayout",
     "Region",
     "RegionMap",
     "concat_events",
     "merge_host_traces",
+    "plane_layout",
+    "plane_words",
     "split_by_host",
     "synthetic_trace",
 ]
@@ -263,6 +267,58 @@ def _bucket_pow2(n: int, floor: int) -> int:
     return v
 
 
+# Columns of the staged word plane, in plane order: the full-plane
+# dispatch's seven [B, N] event columns, or the chain dispatch's packed
+# [B, W] runs and the four [B, N] columns it reads; both end with the [B]
+# window lengths and the [B, V] latency scales.  "f" columns hold float32
+# bit patterns, "b" columns 0/1 words, and "t" columns float32 bits
+# stored transposed: the per-event scale gather reads the scales
+# batch-minor, the layout XLA gives them on a TPU, and a row-major slice
+# of the plane doubled that gather's device time on a TPU v5e (PERF.md §6).
+_FULL_COLUMNS = (
+    ("t", "f"), ("pool", "i"), ("bytes", "f"), ("weight", "f"),
+    ("host", "i"), ("qos", "i"), ("valid", "b"),
+)
+_CHAIN_COLUMNS = (
+    ("t_pack", "f"), ("idx_pack", "i"), ("pool", "i"), ("bytes", "f"),
+    ("weight", "f"), ("valid", "b"),
+)
+
+PlaneLayout = Tuple[Tuple[str, int, Tuple[int, ...], str], ...]
+
+
+@functools.lru_cache(maxsize=256)
+def plane_layout(
+    b_bucket: int, n_bucket: int, scale_width: int, width: int = 0
+) -> PlaneLayout:
+    """``(name, word offset, shape, kind)`` of every column of one staged
+    word plane (kind ``"f"`` float32 bits, ``"i"`` int32, ``"b"`` 0/1,
+    ``"t"`` float32 bits stored transposed).  ``width`` > 0 lays out the
+    chain dispatch (packed runs of that width), 0 the full-plane
+    dispatch.  The host views of :class:`EventStager` and the device-side
+    unpacking read the same offsets, so one transfer carries every column
+    of a dispatch."""
+    entries = [
+        (name, (b_bucket, width if name.endswith("_pack") else n_bucket), kind)
+        for name, kind in (_CHAIN_COLUMNS if width else _FULL_COLUMNS)
+    ] + [
+        ("bw_window", (b_bucket,), "f"),
+        ("scale", (b_bucket, scale_width), "t"),
+    ]
+    out = []
+    off = 0
+    for name, shape, kind in entries:
+        out.append((name, off, shape, kind))
+        off += int(np.prod(shape))
+    return tuple(out)
+
+
+def plane_words(layout: PlaneLayout) -> int:
+    """Total 32-bit words of a plane laid out by :func:`plane_layout`."""
+    _, off, shape, _ = layout[-1]
+    return off + int(np.prod(shape))
+
+
 class EventStager:
     """Reusable host staging buffers for bucketed, batched epoch analysis.
 
@@ -273,6 +329,17 @@ class EventStager:
     owns one buffer set per ``(batch, length)`` bucket and refills it in
     place — steady-state staging performs zero host allocations, and the
     float64 -> analyzer-dtype conversion happens once, during the fill.
+
+    Each buffer set is one int32 word plane (``"words"``, laid out by
+    :func:`plane_layout`) and views into it: every column a dispatch reads
+    is a reshaped slice at a static offset, float columns seen through
+    ``.view(time_dtype)`` (``scale`` through a transposed one) and
+    ``valid`` as 0/1 words.  Filling the views
+    fills the plane, so the analyzer places a dispatch with one host to
+    device transfer; columns the chain dispatch does not read (``t``,
+    ``host``, ``qos``) live beside the plane.  The plane holds 32-bit
+    words, so ``time_dtype`` must be a 32-bit float for :meth:`stage`
+    and :meth:`stage_packed`; :meth:`stage_stack` keeps plain planes.
 
     Not thread-safe: every thread that stages must own its stager.  The
     shared :class:`~repro.core.engine.AnalysisEngine` owns one stager set
@@ -298,9 +365,8 @@ class EventStager:
         # (the engine's double-buffered pipeline) never overwrites host
         # planes an in-flight transfer may still be reading.
         self.slots = max(1, int(slots))
-        self._bufs: Dict[Tuple[int, int, int], Dict[str, np.ndarray]] = {}
+        self._bufs: Dict[Tuple[int, ...], Dict[str, np.ndarray]] = {}
         self._turn: Dict[Tuple[int, int], int] = {}
-        self._pack_bufs: Dict[Tuple[int, int, int], Dict[str, np.ndarray]] = {}
         self._stack_bufs: Dict[Tuple[int, int, int], Dict[str, np.ndarray]] = {}
         self._stack_filled: Dict[Tuple[int, int, int], int] = {}
         self._cap_hwm: Dict[Tuple[int, int, int], Tuple[int, ...]] = {}
@@ -317,25 +383,44 @@ class EventStager:
         self._turn[key] = slot
         return slot
 
-    def buffers(self, b_bucket: int, n_bucket: int) -> Dict[str, np.ndarray]:
-        key = (b_bucket, n_bucket, self._turn.get((b_bucket, n_bucket), 0))
+    def buffers(
+        self, b_bucket: int, n_bucket: int, scale_width: int = 0, width: int = 0
+    ) -> Dict[str, np.ndarray]:
+        """The current slot's buffer set: the word plane of
+        ``plane_layout(b_bucket, n_bucket, scale_width, width)``, its
+        column views, the host-only columns and ``span``."""
+        key = (
+            b_bucket, n_bucket, scale_width, width,
+            self._turn.get((b_bucket, n_bucket), 0),
+        )
         buf = self._bufs.get(key)
         if buf is None:
-            buf = {
-                "t": np.zeros((b_bucket, n_bucket), self.time_dtype),
-                "pool": np.zeros((b_bucket, n_bucket), np.int32),
-                "bytes": np.zeros((b_bucket, n_bucket), self.time_dtype),
-                "weight": np.zeros((b_bucket, n_bucket), self.time_dtype),
-                "host": np.zeros((b_bucket, n_bucket), np.int32),
-                "qos": np.zeros((b_bucket, n_bucket), np.int32),
-                "valid": np.zeros((b_bucket, n_bucket), bool),
-                "span": np.zeros((b_bucket,), np.float64),
-            }
+            if self.time_dtype.itemsize != 4:
+                raise ValueError(
+                    f"the staged word plane holds 32-bit columns; time dtype "
+                    f"{self.time_dtype} does not fit"
+                )
+            layout = plane_layout(b_bucket, n_bucket, scale_width, width)
+            words = np.zeros((plane_words(layout),), np.int32)
+            buf = {"words": words}
+            for name, off, shape, kind in layout:
+                col = words[off : off + int(np.prod(shape))]
+                col = col.reshape(shape[::-1]).T if kind == "t" else col.reshape(shape)
+                buf[name] = col.view(self.time_dtype) if kind in ("f", "t") else col
+            td, i32 = self.time_dtype, np.int32
+            for name, dt in (("t", td), ("host", i32), ("qos", i32)):
+                if name not in buf:  # the chain dispatch does not read it
+                    buf[name] = np.zeros((b_bucket, n_bucket), dt)
+            buf["span"] = np.zeros((b_bucket,), np.float64)
             self._bufs[key] = buf
         return buf
 
     def stage(
-        self, traces: Sequence["MemEvents"], b_bucket: int, n_bucket: int
+        self,
+        traces: Sequence["MemEvents"],
+        b_bucket: int,
+        n_bucket: int,
+        scale_width: int = 0,
     ) -> Dict[str, np.ndarray]:
         """Fill (in place) and return the buffer set for this bucket.
 
@@ -345,24 +430,14 @@ class EventStager:
         case is a 30 µs check plus plain copies).  Rows beyond
         ``len(traces)`` — and the tail of every row beyond its trace's
         event count — are marked invalid; ``span`` holds each epoch's max
-        issue time + 1 (0 for empty rows).
+        issue time + 1 (0 for empty rows).  ``bw_window`` and the
+        ``[B, scale_width]`` ``scale`` columns are the caller's to fill.
         """
         if len(traces) > b_bucket:
             raise ValueError(f"{len(traces)} traces exceed batch bucket {b_bucket}")
         self.rotate(b_bucket, n_bucket)
-        buf = self.buffers(b_bucket, n_bucket)
+        buf = self.buffers(b_bucket, n_bucket, scale_width)
         self._fill_rows(buf, traces, b_bucket)
-        return buf
-
-    def _pack_buffers(self, b_bucket: int, width: int) -> Dict[str, np.ndarray]:
-        key = (b_bucket, width, self._turn.get((b_bucket, width), 0))
-        buf = self._pack_bufs.get(key)
-        if buf is None:
-            buf = {
-                "t": np.zeros((b_bucket, width), self.time_dtype),
-                "idx": np.zeros((b_bucket, width), np.int32),
-            }
-            self._pack_bufs[key] = buf
         return buf
 
     def stage_packed(
@@ -373,9 +448,11 @@ class EventStager:
         enter_stage: np.ndarray,
         n_stages: int,
         cap_floor: int = 16,
-    ) -> Tuple[Dict[str, np.ndarray], Dict[str, np.ndarray], Tuple[int, ...]]:
-        """Pipeline staging: the full planes of :meth:`stage` plus per-stage
-        packed ``(t, idx)`` planes feeding the device-resident chain cascade.
+        scale_width: int = 0,
+    ) -> Tuple[Dict[str, np.ndarray], Tuple[int, ...]]:
+        """Pipeline staging: the columns of :meth:`stage` plus per-stage
+        packed ``t_pack``/``idx_pack`` runs feeding the device-resident
+        chain cascade; returns ``(buffers, caps)``.
 
         ``enter_stage[pool]`` gives the cascade stage position at which an
         event routed to ``pool`` first enters the fabric (-1 = local, never
@@ -392,14 +469,14 @@ class EventStager:
         if len(traces) > b_bucket:
             raise ValueError(f"{len(traces)} traces exceed batch bucket {b_bucket}")
         self.rotate(b_bucket, n_bucket)
-        buf = self.buffers(b_bucket, n_bucket)
-        self._fill_rows(buf, traces, b_bucket)
         enter = np.asarray(enter_stage, np.int32)
         n_stages = int(n_stages)
+        # the caps size the plane, so they come from the traces before the
+        # fill; per-stage counts do not depend on the order of a row
         counts = np.zeros((max(len(traces), 1), n_stages), np.int64)
         depth_rows: List[np.ndarray] = []
         for row, ev in enumerate(traces):
-            d = enter[buf["pool"][row, : ev.n]]
+            d = enter[ev.pool]
             depth_rows.append(d)
             routed = d >= 0
             if routed.any():
@@ -445,27 +522,31 @@ class EventStager:
                 self._cap_slack[cap_key] = 0
                 self._cap_peak.pop(cap_key, None)
         self._cap_hwm[cap_key] = caps
-        width = int(sum(caps))
-        self._turn[(b_bucket, width)] = self._turn.get((b_bucket, n_bucket), 0)
-        pack = self._pack_buffers(b_bucket, width)
-        pack["t"].fill(np.inf)
-        pack["idx"].fill(-1)
+        buf = self.buffers(b_bucket, n_bucket, scale_width, int(sum(caps)))
+        orders = self._fill_rows(buf, traces, b_bucket)
+        t_pack, idx_pack = buf["t_pack"], buf["idx_pack"]
+        t_pack.fill(np.inf)
+        idx_pack.fill(-1)
         for row, d in enumerate(depth_rows):
+            if orders[row] is not None:
+                d = d[orders[row]]  # the row was staged in time order
             off = 0
             for p in range(n_stages):
                 sel = np.flatnonzero(d == p)
                 m = sel.shape[0]
-                pack["t"][row, off : off + m] = buf["t"][row, sel]
-                pack["idx"][row, off : off + m] = sel
+                t_pack[row, off : off + m] = buf["t"][row, sel]
+                idx_pack[row, off : off + m] = sel
                 off += caps[p]
-        return buf, pack, caps
+        return buf, caps
 
     @staticmethod
     def _fill_rows(
         buf: Dict[str, np.ndarray], traces: Sequence["MemEvents"], b_bucket: int
-    ) -> None:
+    ) -> List[Optional[np.ndarray]]:
         """Fill one ``[B, N]`` buffer view (shared by :meth:`stage` and the
-        per-session planes of :meth:`stage_stack`)."""
+        per-session planes of :meth:`stage_stack`).  Returns, per trace,
+        the stable sort it was staged in, or None where it was monotone."""
+        orders: List[Optional[np.ndarray]] = [None] * len(traces)
         for row in range(b_bucket):
             ev = traces[row] if row < len(traces) else None
             n = ev.n if ev is not None else 0
@@ -476,6 +557,7 @@ class EventStager:
                     )
                 else:
                     order = np.argsort(ev.t_ns, kind="stable")
+                    orders[row] = order
                     t, pool, nbytes, weight, host, qos = (
                         ev.t_ns[order], ev.pool[order], ev.bytes_[order],
                         ev.weight[order], ev.host[order], ev.qos[order],
@@ -497,6 +579,7 @@ class EventStager:
             buf["host"][row, n:] = 0
             buf["qos"][row, n:] = 0
             buf["valid"][row, n:] = False
+        return orders
 
     def stack_buffers(
         self, k_bucket: int, b_bucket: int, n_bucket: int
@@ -504,11 +587,13 @@ class EventStager:
         key = (k_bucket, b_bucket, n_bucket)
         buf = self._stack_bufs.get(key)
         if buf is None:
-            flat = self.buffers(b_bucket, n_bucket)  # dtype source of truth
+            td, i32 = self.time_dtype, np.int32
+            dtypes = (td, i32, td, td, i32, i32, bool)
             buf = {
-                f: np.zeros((k_bucket,) + flat[f].shape, flat[f].dtype)
-                for f in self._FIELDS + ("span",)
+                f: np.zeros((k_bucket, b_bucket, n_bucket), dt)
+                for f, dt in zip(self._FIELDS, dtypes)
             }
+            buf["span"] = np.zeros((k_bucket, b_bucket), np.float64)
             self._stack_bufs[key] = buf
         return buf
 

@@ -30,6 +30,7 @@ from jax import monitoring
 
 __all__ = [
     "BACKEND_COMPILE",
+    "H2D_BUFFERS",
     "compile_seconds",
     "count",
     "reset",
@@ -38,6 +39,8 @@ __all__ = [
 ]
 
 BACKEND_COMPILE = "cxlsim.compile.backend"
+# arrays each analyzer dispatch places on the device (one staged word plane)
+H2D_BUFFERS = "cxlsim.h2d.buffers"
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 _recording = jax.profiler.TraceAnnotation.is_enabled

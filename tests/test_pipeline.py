@@ -17,6 +17,7 @@ Covers the PR's contract end to end:
     same numbers as synchronous dispatch.
 """
 
+import dataclasses
 import re
 
 import numpy as np
@@ -26,6 +27,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.analyzer import (
+    DelayBreakdown,
     DispatchStats,
     EpochAnalyzer,
     FineGrainedSimulator,
@@ -34,7 +36,14 @@ from repro.core.analyzer import (
     plan_chain,
 )
 from repro.core.engine import AnalysisEngine
-from repro.core.events import EventStager, MemEvents, merge_host_traces, synthetic_trace
+from repro.core.events import (
+    EventStager,
+    MemEvents,
+    merge_host_traces,
+    plane_layout,
+    plane_words,
+    synthetic_trace,
+)
 from repro.core.topology import (
     chained_topology,
     figure1_topology,
@@ -222,20 +231,21 @@ def test_pipeline_graph_merges_pad_segments_without_a_loop():
     B, N, caps = 4, 2048, (1024, 16, 16)
     W = sum(caps)
     V, S = np.asarray(flat.route).shape
+    layout = plane_layout(B, N, V, W)
     sds = jax.ShapeDtypeStruct
     f32, i32 = jnp.float32, jnp.int32
     args = (
-        sds((B, W), f32), sds((B, W), i32), sds((B, N), i32), sds((B, N), f32),
-        sds((B, N), f32), sds((B, N), jnp.bool_), sds((B,), f32), sds((B, V), f32),
+        sds((plane_words(layout),), i32),
         sds((V,), f32), sds((), f32), sds((V, S), f32), sds((S,), f32), sds((S,), f32),
     )
     jitted = jax.jit(
         _analyze_pipeline_jax,
-        static_argnames=("stage_order", "seg_caps", "n_windows"),
-        donate_argnums=(0, 1),
+        static_argnames=("layout", "stage_order", "seg_caps", "n_windows"),
+        donate_argnums=(0,),
     )
     lowered = jitted.lower(
-        *args, stage_order=plan.stage_order, seg_caps=caps, n_windows=32
+        *args, layout=layout, stage_order=plan.stage_order, seg_caps=caps,
+        n_windows=32,
     )
     hlo = lowered.compile().as_text()
     loops = [ln for ln in hlo.splitlines() if re.search(r"\bwhile\(", ln)]
@@ -274,17 +284,17 @@ def test_stage_packed_segments_are_sorted_runs():
     assert plan is not None
     st = EventStager()
     traces = [_trace(flat, 200, s) for s in range(3)]
-    buf, pack, caps = st.stage_packed(
+    buf, caps = st.stage_packed(
         traces, 4, 256, plan.enter_stage, len(plan.stage_order)
     )
-    assert sum(caps) == pack["t"].shape[1]
+    assert sum(caps) == buf["t_pack"].shape[1]
     off = 0
     for c in caps:
-        seg = pack["t"][:, off : off + c]
+        seg = buf["t_pack"][:, off : off + c]
         assert np.all(seg[:, 1:] >= seg[:, :-1])  # per-depth runs sorted free
         off += c
     # pads: -1 idx iff +inf key
-    np.testing.assert_array_equal(pack["idx"] < 0, np.isinf(pack["t"]))
+    np.testing.assert_array_equal(buf["idx_pack"] < 0, np.isinf(buf["t_pack"]))
 
 
 def test_memevents_build_avoids_list_roundtrip(rng):
@@ -419,6 +429,137 @@ def test_dispatch_stats_timing_fields_populated(rng):
     assert st.compile_s > 0  # first dispatch carries the lowering
     pipe.analyze_batch([_trace(flat, 300, 2)])
     assert pipe.last_dispatch.compile_s == 0.0  # hits are free
+
+
+# --------------------------------------------------------------------------- #
+# one staged word plane per dispatch
+# --------------------------------------------------------------------------- #
+
+
+def _busy_trace(flat, n, seed):
+    """Bursty epochs of 16 KiB events: every delay class is well above f32
+    time resolution, bandwidth included."""
+    tr = synthetic_trace(n, flat.n_pools, epoch_ns=2e5, seed=seed, burstiness=0.8)
+    return dataclasses.replace(tr, bytes_=tr.bytes_ * 256)
+
+
+def _plane_case(name):
+    """(flat, pipeline, traces, lat_scales) of a figure-1 chain or a 4-host
+    pooled fabric, dispatched by the chain, full-plane or jitted path."""
+    if name == "figure1_chain":
+        flat = figure1_topology().flatten()
+        traces = [_busy_trace(flat, 600 + 37 * i, 20 + i) for i in range(3)]
+        pipeline = True
+    else:
+        flat = pooled_topology(n_hosts=4).flatten()
+        traces = [
+            merge_host_traces(
+                [_busy_trace(flat, 300 + 11 * h + 5 * e, 40 + 4 * e + h) for h in range(4)]
+            )
+            for e in range(3)
+        ]
+        pipeline = name == "pooled4_full_plane"
+    V = flat.n_hosts * flat.n_pools
+    scales = [None, np.linspace(0.5, 1.0, V), None]
+    return flat, pipeline, traces, scales
+
+
+PLANE_CASES = ("figure1_chain", "pooled4_full_plane", "pooled4_jit")
+
+
+@pytest.mark.parametrize("name", PLANE_CASES)
+def test_launch_batch_places_one_buffer_per_dispatch(name, monkeypatch, tmp_path):
+    from repro.core import spans
+    from repro.distributed import sharding
+
+    flat, pipeline, traces, scales = _plane_case(name)
+    an = EpochAnalyzer(flat, n_windows=32, pipeline=pipeline)
+    assert (an._chain_plan is not None) == (name == "figure1_chain")
+    placed = []
+    real_put = sharding.timed_device_put
+
+    def counting_put(tree, *args, **kwargs):
+        placed.append(len(jax.tree.leaves(tree)))
+        return real_put(tree, *args, **kwargs)
+
+    monkeypatch.setattr(sharding, "timed_device_put", counting_put)
+    an.analyze_batch(traces, scales)  # warm
+    spans.reset()
+    with jax.profiler.trace(str(tmp_path)):
+        for _ in range(3):
+            an.analyze_batch(traces, scales)
+    assert placed == [1] * 4
+    assert spans.traced_totals()[spans.H2D_BUFFERS] == (3, 0.0)
+    assert an.last_dispatch.donated == (name == "figure1_chain")
+
+
+@pytest.mark.parametrize("chain", [True, False], ids=["chain", "full_plane"])
+def test_word_plane_unpacks_bit_identical(chain):
+    from repro.core.analyzer import unpack_plane
+
+    flat = figure1_topology().flatten()
+    plan = plan_chain(flat)
+    B, N, V = 4, 512, flat.n_pools
+    st = EventStager()
+    # three real rows and one empty: +inf/-1 pads, 0/1 valid, zeroed tails
+    traces = [_trace(flat, n, 60 + n) for n in (300, 511, 17)]
+    if chain:
+        buf, caps = st.stage_packed(
+            traces, B, N, plan.enter_stage, len(plan.stage_order), scale_width=V
+        )
+        assert np.isinf(buf["t_pack"]).any() and (buf["idx_pack"] == -1).any()
+    else:
+        buf, caps = st.stage(traces, B, N, scale_width=V), ()
+    layout = plane_layout(B, N, V, sum(caps))
+    assert buf["words"].shape == (plane_words(layout),)
+    f32max, i32 = np.finfo(np.float32).max, np.iinfo(np.int32)
+    buf["bytes"][0, :4] = [-0.0, np.inf, -np.inf, f32max]
+    buf["pool"][1, :3] = [i32.min, i32.max, -1]
+    buf["weight"][2, :2] = [-0.0, np.finfo(np.float32).smallest_subnormal]
+    buf["bw_window"][:] = [1.0, -0.0, 3e38, 1e-30]
+    buf["scale"][:] = np.linspace(-2.0, 2.0, B * V, dtype=np.float32).reshape(B, V)
+    buf["scale"][3, 0] = -0.0
+    got = jax.jit(unpack_plane, static_argnums=1)(jnp.asarray(buf["words"]), layout)
+    assert sorted(got) == sorted(name for name, *_ in layout)
+    for name, _, shape, kind in layout:
+        g, want = np.asarray(got[name]), buf[name]
+        assert g.shape == shape, name
+        if kind == "b":
+            assert g.dtype == np.bool_
+            np.testing.assert_array_equal(g, want != 0)
+            assert int(want.sum()) == 300 + 511 + 17
+        else:
+            assert g.dtype == want.dtype, name
+            np.testing.assert_array_equal(g.view(np.int32), want.view(np.int32))
+
+
+def test_word_plane_refuses_a_time_dtype_of_another_width():
+    flat = figure1_topology().flatten()
+    with pytest.raises(ValueError, match="32-bit"):
+        EventStager(np.float16).stage([_trace(flat, 10, 0)], 1, 16)
+
+
+@pytest.mark.parametrize("name", PLANE_CASES)
+def test_word_plane_dispatch_matches_oracle(name):
+    flat, pipeline, traces, scales = _plane_case(name)
+    an = EpochAnalyzer(flat, n_windows=32, pipeline=pipeline)
+    got = an.analyze_batch(traces, scales)
+    ref = DelayBreakdown.zero(flat.n_pools, flat.n_switches, flat.n_hosts)
+    for tr, sc in zip(traces, scales):
+        # the analyzer's effective window: the epoch's span over n_windows
+        window = max(float(tr.t_ns.max()) + 1.0, an.bw_window_ns) / 32
+        ref = ref + analyze_ref(
+            flat, tr, bw_window_ns=window, lat_scale=sc, n_windows=32
+        )
+    assert ref.bandwidth_ns > 0 and ref.congestion_ns > 0
+    assert got.latency_ns == pytest.approx(ref.latency_ns, rel=1e-4)
+    assert got.congestion_ns == pytest.approx(ref.congestion_ns, rel=1e-3)
+    assert got.bandwidth_ns == pytest.approx(ref.bandwidth_ns, rel=1e-3)
+    assert got.total_ns == pytest.approx(ref.total_ns, rel=1e-3)
+    np.testing.assert_allclose(got.per_host_latency_ns, ref.per_host_latency_ns, rtol=1e-4)
+    np.testing.assert_allclose(
+        got.per_host_congestion_ns, ref.per_host_congestion_ns, rtol=5e-3
+    )
 
 
 # --------------------------------------------------------------------------- #

@@ -583,7 +583,7 @@ def test_stage_packed_caps_decay_after_idle_streak():
             pool=np.ones(n, np.int64),
             bytes_=np.full(n, 64.0),
         )
-        _, _, caps = stager.stage_packed([ev], 1, 4096, enter, 1)
+        _, caps = stager.stage_packed([ev], 1, 4096, enter, 1)
         return caps
 
     burst_caps = stage(2000)
@@ -613,14 +613,14 @@ def test_stage_packed_caps_grow_from_the_floor():
             pool=np.r_[np.zeros(n_local, np.int64), np.ones(n_routed, np.int64)],
             bytes_=np.full(n, 64.0),
         )
-        _, pack, caps = stager.stage_packed([ev], 1, 512, enter, 1)
-        return pack, caps
+        buf, caps = stager.stage_packed([ev], 1, 512, enter, 1)
+        return buf, caps
 
     _, caps = stage(100, 0)
     assert caps == (16,)
-    pack, caps = stage(100, 128)
+    buf, caps = stage(100, 128)
     assert caps == (128,)
-    assert int((pack["idx"][0] >= 0).sum()) == 128
+    assert int((buf["idx_pack"][0] >= 0).sum()) == 128
 
 
 def test_stage_packed_oscillating_workload_never_decays():
@@ -633,7 +633,7 @@ def test_stage_packed_oscillating_workload_never_decays():
             pool=np.ones(n, np.int64),
             bytes_=np.full(n, 64.0),
         )
-        _, _, caps = stager.stage_packed([ev], 1, 4096, enter, 1)
+        _, caps = stager.stage_packed([ev], 1, 4096, enter, 1)
         return caps
 
     big = stage(2000)

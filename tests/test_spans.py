@@ -40,7 +40,7 @@ SPAN_OF = {
     "wait_s": "cxlsim.wait",
     "d2h_s": "cxlsim.d2h",
 }
-COUNTERS = {"cxlsim.slots", "cxlsim.events", spans.BACKEND_COMPILE}
+COUNTERS = {"cxlsim.slots", "cxlsim.events", spans.BACKEND_COMPILE, spans.H2D_BUFFERS}
 
 
 def _tenants(n=2, layers=24):

@@ -16,6 +16,7 @@ import pytest
 
 from repro.core import EpochAnalyzer, Topology, figure1_topology, pooled_topology
 from repro.core.analyzer import _analyze_batch_jax, _analyze_pipeline_jax
+from repro.core.events import plane_layout, plane_words
 from repro.core.topology import chained_topology
 from repro.kernels import ops
 from repro.kernels.congestion import stage_scan
@@ -123,17 +124,17 @@ def test_pipeline_chain_dispatch_compiles(sds):
     order = an._chain_plan.stage_order  # the RC is a stage too
     caps = (N // len(order),) * (len(order) - 1)
     caps += (N - sum(caps),)
-    args = [
-        sds((B, N), F32), sds((B, N), I32),  # packed runs (donated)
-        sds((B, N), I32), sds((B, N), F32), sds((B, N), F32), sds((B, N), jnp.bool_),
-        sds((B,), F32), sds((B, V), F32),
-    ] + _leaves(an, sds)
+    layout = plane_layout(B, N, V, sum(caps))
+    args = [sds((plane_words(layout),), I32)] + _leaves(an, sds)  # donated plane
     jitted = jax.jit(
         _analyze_pipeline_jax,
-        static_argnames=("stage_order", "seg_caps", "n_windows"),
-        donate_argnums=(0, 1),
+        static_argnames=("layout", "stage_order", "seg_caps", "n_windows"),
+        donate_argnums=(0,),
     )
-    _compile(jitted, *args, stage_order=order, seg_caps=caps, n_windows=an.n_windows)
+    _compile(
+        jitted, *args, layout=layout, stage_order=order, seg_caps=caps,
+        n_windows=an.n_windows,
+    )
 
 
 @pytest.mark.parametrize("impl", ["inline", "pallas"])
