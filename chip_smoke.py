@@ -470,7 +470,8 @@ def phase_four_chips(n_racks: int = 32, n_tenants: int = 192) -> None:
             f"{st.devices_used} shard_rows={st.shard_rows} rows={st.rows} "
             f"padded_fraction={st.padded_fraction!r} warm frontier wall_s={wall!r}"
         )
-        log_split(f"{name}:{which}", dataclasses.asdict(st), lowerings() - low0)
+        split = dataclasses.asdict(st) | {"compute_s": st.compute_s}  # a property
+        log_split(f"{name}:{which}", split, lowerings() - low0)
         fallback = [str(w.message) for w in caught if "falling back" in str(w.message)]
         check(not fallback, f"sub-mesh fallback: {fallback}")
     check(

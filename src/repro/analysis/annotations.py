@@ -148,7 +148,7 @@ def axes(*pos_specs: str, **kw_specs: str) -> Callable[[F], F]:
     keyword specs bind by parameter name::
 
         @axes("K,B,N", stts="K,S", class_weights="S,C")
-        def _analyze_multi_jax(xs, stts, route, ...): ...
+        def _analyze_stack_jax(xs, stts, route, ...): ...
 
     A spec is a comma-separated axis list.  Tokens are axis *names*
     (``K``, ``B``, ``N`` — unified across all parameters of one call, so a

@@ -91,8 +91,8 @@ class CheckConfig:
     axes_required: Tuple[str, ...] = (
         "_analyze_jax",
         "_analyze_batch_jax",
-        "_analyze_multi_jax",
-        "_analyze_fleet_jax",
+        "_analyze_words_jax",
+        "_analyze_stack_jax",
         "_analyze_sweep_jax",
         "_analyze_pipeline_jax",
         "qos_cascade_dyn",

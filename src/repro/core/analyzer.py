@@ -30,21 +30,32 @@ form with a cumulative max:  let ``f_i = cummax(arr_i − STT·rank_i)``; then
 scan, which is what makes the epoch analyzer vectorizable (and, in
 :mod:`repro.kernels.congestion`, a Pallas kernel).
 
-The production pipeline (``fused=True``, the default) runs four stages per
-batch of epochs, entirely on device, with a single host round-trip:
+Every jitted surface prices an epoch the same way: the two traced helpers
+:func:`_price_latency` and :func:`_price_bandwidth` own the latency and
+windowed-bandwidth formulas, and each surface differs only in how it
+queues (the congestion cascade) and what it stacks.  From one epoch up:
 
-  1. **sort** — one stable argsort per epoch (padded entries sort last);
-  2. **fused cascade** — every switch stage's serial queue in one pass
-     (:func:`repro.kernels.ref.serial_queue_cascade` / the multi-stage
-     Pallas kernel).  The array stays physically sorted by *current* time:
-     after each stage the two sorted runs (queued vs untouched events) are
-     re-merged with rank arithmetic, so no further sorts are needed while
-     still matching ``analyze_ref``'s per-stage re-sort exactly;
-  3. **windowed bandwidth** — segment-sums over static window counts on the
-     post-congestion times;
-  4. **device accumulation** — per-epoch breakdowns are summed over the
-     batch on device; only six scalars/small vectors cross the host
-     boundary per ``analyze_batch`` call.
+  * :func:`_analyze_jax` — one epoch on the full padded plane.  Events
+    arrive time-sorted (the :class:`~repro.core.events.EventStager`
+    contract: the host sorts only traces that are not sorted already), the
+    fused cascade (:func:`repro.kernels.ref.serial_queue_cascade` / the
+    Pallas stage kernel) runs every switch stage's serial queue in one
+    pass, re-merging the queued and untouched sorted runs with rank
+    arithmetic instead of re-sorting, and bandwidth windows are summed on
+    the post-congestion times;
+  * :func:`_analyze_batch_jax` — B epochs vmapped and summed on device,
+    and :func:`_analyze_words_jax`, the same on one staged int32 word
+    plane (one host→device transfer per dispatch);
+  * :func:`_analyze_pipeline_jax` — the chain path: single-host chain
+    fabrics (:func:`plan_chain`) run a compact suffix cascade on packed
+    per-stage runs, sorted on device;
+  * :func:`_analyze_stack_jax` — K batches stacked ``[K, B, N]`` with
+    per-row numeric topology leaves: coalesced engine sessions (one row
+    broadcast to K) and fleet racks (one row each);
+  * :func:`_analyze_sweep_jax` — K scenarios over U deduplicated cascades.
+
+Each dispatch returns the ten :class:`DelayBreakdown` leaves, reduced on
+device, in one device→host copy.
 
 Choosing ``impl``:
 
@@ -60,9 +71,11 @@ Choosing ``impl``:
     slow, used by tests to validate the kernel on CPU.
   * ``'ref'`` (``analyze_ref``) — numpy float64; the oracle, not jitted.
 
-``fused=False`` preserves the pre-fusion per-switch argsort loop; it exists
-as the benchmark baseline (``benchmarks/analyzer_scaling.py``) and as a
-cross-check, not for production use.
+``fused=False`` keeps the per-switch argsort loop for congestion (latency
+and bandwidth still go through the shared helpers).  It is the fallback
+for fabrics of more than 31 cascade stages, whose masks do not fit the
+fused cascade's 31-bit route word: :class:`EpochAnalyzer` switches to it
+on its own there.
 """
 
 from __future__ import annotations
@@ -257,6 +270,18 @@ class DelayBreakdown:
             np.zeros((n_hosts,)),
             np.zeros((n_hosts,)),
             np.zeros((n_hosts,)),
+        )
+
+    @staticmethod
+    def from_leaves(leaves: Sequence[Any], k: Optional[int] = None) -> "DelayBreakdown":
+        """The breakdown of a dispatch's ten host-side output leaves (the
+        field order above), or of row ``k`` of stacked ``[K, ...]`` ones."""
+        if k is not None:
+            leaves = [x[k] for x in leaves]
+        lat, cong, bw, *arrays = leaves
+        return DelayBreakdown(
+            float(lat), float(cong), float(bw),
+            *(np.array(a, np.float64) for a in arrays),
         )
 
 
@@ -636,6 +661,121 @@ def unpack_plane(words: jnp.ndarray, layout: PlaneLayout) -> Dict[str, jnp.ndarr
     return cols
 
 
+def _price_latency(
+    vp: jnp.ndarray,  # [N] i32 virtual pool (host * P + pool)
+    pool: jnp.ndarray,  # [N] i32 physical pool
+    host: Optional[jnp.ndarray],  # [N] i32 (unused when n_hosts == 1)
+    weight: jnp.ndarray,  # [N] statistical multiplicity
+    valid: jnp.ndarray,  # [N] bool
+    lat_scale: jnp.ndarray,  # [V] device-cache latency scale (ones: no cache)
+    pool_latency_ns: jnp.ndarray,  # [V]
+    local_latency_ns: jnp.ndarray,  # []
+    n_hosts: int,  # static
+):
+    """One epoch's latency delay: ``(per_event_lat [N], latency [],
+    per_pool_lat [P], per_host_lat [H])``.  Each event pays its pool's
+    latency above local DRAM, times its device-cache scale (hits are
+    charged at device-DRAM latency, :mod:`~repro.core.cache`; ones give
+    the no-cache graph bitwise) and its weight."""
+    P = pool_latency_ns.shape[0] // n_hosts
+    per_event_lat = (
+        jnp.maximum(pool_latency_ns[vp] - local_latency_ns, 0.0)
+        * lat_scale[vp]
+        * weight
+    )
+    per_event_lat = jnp.where(valid, per_event_lat, 0.0)
+    f32 = per_event_lat.dtype
+    # one-hot contraction: XLA CPU scatter-add (segment_sum) costs ~10x
+    # more than an [N, P] einsum at pool counts this small
+    pool_onehot = (pool[:, None] == jnp.arange(P, dtype=pool.dtype)).astype(f32)
+    per_pool_lat = jnp.einsum("n,np->p", per_event_lat, pool_onehot, precision=_HI)
+    latency = per_event_lat.sum()
+    if n_hosts == 1:
+        per_host_lat = latency[None]
+    else:
+        host_onehot = (host[:, None] == jnp.arange(n_hosts, dtype=host.dtype)).astype(f32)
+        per_host_lat = jnp.einsum("n,nh->h", per_event_lat, host_onehot, precision=_HI)
+    return per_event_lat, latency, per_pool_lat, per_host_lat
+
+
+def _price_bandwidth(
+    t_fin: jnp.ndarray,  # [N] post-queue times
+    lat_e: jnp.ndarray,  # [N] per-event latency
+    vp_e: jnp.ndarray,  # [N] i32 virtual pool
+    nbytes_e: jnp.ndarray,  # [N] payload bytes
+    valid_e: jnp.ndarray,  # [N] bool
+    bw_window_ns: jnp.ndarray,  # [] window length
+    route: jnp.ndarray,  # [V, S]
+    switch_bw: jnp.ndarray,  # [S] bytes/ns
+    n_windows: int,  # static
+    n_hosts: int,  # static
+):
+    """One epoch's bandwidth delay: ``(bandwidth [], per_switch_bw [S],
+    per_host_bw [H])``.  The five event arrays may come in any order (slot
+    order after a cascade, input order, or a compact suffix): each event
+    lands in the window of its observed time (post-queue time plus
+    latency, overflow clamped into the last window), one segment sum over
+    (window, virtual pool) and a ``[W, V] @ [V, S]`` product give each
+    switch's bytes per window, and a window whose bytes exceed bandwidth
+    × window is stretched to bytes / bandwidth.  A host's share of a
+    window's stretch is its share of the window's bytes."""
+    V, S = route.shape
+    P = V // n_hosts
+    t_obs = jnp.where(valid_e, t_fin + lat_e, 0.0)
+    win = jnp.minimum((t_obs / bw_window_ns).astype(jnp.int32), n_windows - 1)
+    win = jnp.where(valid_e, win, n_windows - 1)
+    key = win * V + vp_e
+    wp = jax.ops.segment_sum(
+        jnp.where(valid_e, nbytes_e, 0.0), key, num_segments=n_windows * V
+    ).reshape(n_windows, V)
+    if n_hosts == 1:
+        wbytes = jnp.matmul(wp, route, precision=_HI)  # [W, S]
+    else:
+        wph = wp.reshape(n_windows, n_hosts, P)
+        route_h = route.reshape(n_hosts, P, S)
+        wbytes_h = jnp.einsum(
+            "whp,hps->whs", wph, route_h, precision=_HI,
+        )  # [W, H, S]
+        wbytes = wbytes_h.sum(axis=1)
+    # bw <= 0 means an unconstrained component (analyze_ref skips it);
+    # unguarded 0/0 windows would poison totals with NaN
+    bw_safe = jnp.where(switch_bw > 0, switch_bw, 1.0)
+    stretch = jnp.maximum(wbytes / bw_safe[None, :] - bw_window_ns, 0.0)
+    stretch = jnp.where(switch_bw[None, :] > 0, stretch, 0.0)
+    per_switch_bw = stretch.sum(axis=0)
+    bandwidth = per_switch_bw.sum()
+    if n_hosts == 1:
+        per_host_bw = bandwidth[None]
+    else:
+        denom = jnp.maximum(wbytes, jnp.asarray(1e-30, wbytes.dtype))
+        per_host_bw = jnp.einsum("ws,whs->h", stretch / denom, wbytes_h, precision=_HI)
+    return bandwidth, per_switch_bw, per_host_bw
+
+
+def _congestion_leaves(psd, stage_arr, n_switches: int, n_hosts: int, qos_on: bool):
+    """A cascade's per-stage queueing delays as breakdown leaves:
+    ``(congestion [], per_switch_cong [S], per_host_cong [H],
+    per_class_cong [C])``.  ``psd`` is ``[stages, H, C]`` from the QoS
+    cascade, else ``[stages, H]`` (``[stages]`` on one host); a FIFO
+    fabric's per-class leaf is the degenerate ``[congestion]``."""
+    zeros = jnp.zeros((n_switches,), psd.dtype)
+    if qos_on:
+        per_switch_cong = zeros.at[stage_arr].set(psd.sum(axis=(1, 2)))
+        per_class_cong = psd.sum(axis=(0, 1))
+        congestion = per_switch_cong.sum()
+        per_host_cong = congestion[None] if n_hosts == 1 else psd.sum(axis=(0, 2))
+        return congestion, per_switch_cong, per_host_cong, per_class_cong
+    if n_hosts == 1:
+        per_switch_cong = zeros.at[stage_arr].set(psd)
+        congestion = per_switch_cong.sum()
+        per_host_cong = congestion[None]
+    else:
+        per_switch_cong = zeros.at[stage_arr].set(psd.sum(axis=1))
+        per_host_cong = psd.sum(axis=0)
+        congestion = per_switch_cong.sum()
+    return congestion, per_switch_cong, per_host_cong, congestion[None]
+
+
 @axes("P", "V", "", "V,S", "S", "S")
 def _analyze_pipeline_jax(
     words: jnp.ndarray,  # [P] i32 staged word plane (chain layout) — DONATED
@@ -673,32 +813,23 @@ def _analyze_pipeline_jax(
     """
     c = unpack_plane(words, layout)
     t_pack, idx_pack = c["t_pack"], c["idx_pack"]
-    V = pool_latency_ns.shape[0]
     S = switch_stt_ns.shape[0]
-    f32 = t_pack.dtype
     stage_arr = jnp.asarray(stage_order, jnp.int32)
     stts = switch_stt_ns[stage_arr]
 
     def one(tp1, ip1, pool1, nbytes1, weight1, valid1, bww1, scale1):
-        # latency: identical to the fused full-plane formulation
-        per_event_lat = (
-            jnp.maximum(pool_latency_ns[pool1] - local_latency_ns, 0.0)
-            * scale1[pool1]
-            * weight1
+        per_event_lat, latency, per_pool_lat, _ = _price_latency(
+            pool1, pool1, None, weight1, valid1, scale1, pool_latency_ns,
+            local_latency_ns, n_hosts=1,
         )
-        per_event_lat = jnp.where(valid1, per_event_lat, 0.0)
-        pool_onehot = (
-            pool1[:, None] == jnp.arange(V, dtype=pool1.dtype)
-        ).astype(f32)
-        per_pool_lat = jnp.einsum("n,np->p", per_event_lat, pool_onehot, precision=_HI)
-        latency = per_event_lat.sum()
 
         # congestion: compact suffix cascade (merge + scan fused)
         from repro.kernels import ops as kops  # deferred: avoid cycles
 
         t_fin, idx_fin, dsums = kops.chain_cascade(tp1, ip1, stts, seg_caps)
-        per_switch_cong = jnp.zeros((S,), f32).at[stage_arr].set(dsums)
-        congestion = per_switch_cong.sum()
+        congestion, per_switch_cong, _, _ = _congestion_leaves(
+            dsums, stage_arr, S, n_hosts=1, qos_on=False
+        )
 
         # bandwidth from the compact array: payloads gathered through the
         # staged-row positions the cascade carried along
@@ -707,23 +838,14 @@ def _analyze_pipeline_jax(
         lat_e = jnp.take(per_event_lat, safe)
         vp_e = jnp.take(pool1, safe)
         nbytes_e = jnp.take(nbytes1, safe)
-        t_obs = jnp.where(real, t_fin + lat_e, 0.0)
-        win = jnp.minimum((t_obs / bww1).astype(jnp.int32), n_windows - 1)
-        win = jnp.where(real, win, n_windows - 1)
-        key = win * V + vp_e
-        wp = jax.ops.segment_sum(
-            jnp.where(real, nbytes_e, 0.0), key, num_segments=n_windows * V
-        ).reshape(n_windows, V)
-        wbytes = jnp.matmul(wp, route, precision=_HI)  # [n_windows, S]
-        bw_safe = jnp.where(switch_bw > 0, switch_bw, 1.0)
-        stretch = jnp.maximum(wbytes / bw_safe[None, :] - bww1, 0.0)
-        stretch = jnp.where(switch_bw[None, :] > 0, stretch, 0.0)
-        per_switch_bw_d = stretch.sum(axis=0)
-        bandwidth = per_switch_bw_d.sum()
+        bandwidth, per_switch_bw, _ = _price_bandwidth(
+            t_fin, lat_e, vp_e, nbytes_e, real, bww1, route, switch_bw,
+            n_windows, n_hosts=1,
+        )
 
         return (
             latency, congestion, bandwidth,
-            per_pool_lat, per_switch_cong, per_switch_bw_d,
+            per_pool_lat, per_switch_cong, per_switch_bw,
             latency[None], congestion[None], bandwidth[None],
             congestion[None],
             t_fin, idx_fin,
@@ -768,7 +890,7 @@ def _analyze_jax(
     n_hosts: int,  # static
     bw_window_ns: jnp.ndarray,  # []
     impl: str = "inline",  # 'inline' | 'pallas' | 'pallas_interpret'
-    fused: bool = True,  # False: legacy per-stage argsort loop (benchmarks)
+    fused: bool = True,  # False: per-stage argsort loop (beyond 31 stages)
     merge_plan=None,  # static merge schedule from plan_cascade (fused only)
     qos_on: bool = False,  # static: route congestion through the QoS cascade
 ):
@@ -802,31 +924,16 @@ def _analyze_jax(
             "QoS disciplines require the fused cascade (fused=True)"
         )
 
-    # -- latency ----------------------------------------------------------- #
-    # device-cache hits are charged at device-DRAM latency via the per-vp
-    # scale (core/cache.py); ones => bitwise the historical no-cache graph
-    per_event_lat = (
-        jnp.maximum(pool_latency_ns[vp] - local_latency_ns, 0.0)
-        * lat_scale[vp]
-        * weight
+    per_event_lat, latency, per_pool_lat, per_host_lat = _price_latency(
+        vp, pool, host, weight, valid, lat_scale, pool_latency_ns,
+        local_latency_ns, n_hosts,
     )
-    per_event_lat = jnp.where(valid, per_event_lat, 0.0)
-    if fused:
-        # one-hot contraction: XLA CPU scatter-add (segment_sum) costs ~10x
-        # more than an [N, P] einsum at pool counts this small
-        pool_onehot = (pool[:, None] == jnp.arange(P, dtype=pool.dtype)).astype(f32)
-        per_pool_lat = jnp.einsum("n,np->p", per_event_lat, pool_onehot, precision=_HI)
-    else:
-        per_pool_lat = jax.ops.segment_sum(per_event_lat, pool, num_segments=P)
-    latency = per_event_lat.sum()
-    if n_hosts == 1:
-        per_host_lat = latency[None]
-    else:
-        host_onehot = (host[:, None] == jnp.arange(n_hosts, dtype=host.dtype)).astype(f32)
-        per_host_lat = jnp.einsum("n,nh->h", per_event_lat, host_onehot, precision=_HI)
 
     big = jnp.asarray(jnp.finfo(f32).max / 4, f32)
     t_cur = jnp.where(valid, t, big)
+    # bandwidth sees the events in the cascade's final slot order: input
+    # order unless the cascade merged runs (no merges: skip the gathers)
+    lat_e, vp_e, nbytes_e, valid_e = per_event_lat, vp, nbytes, valid
 
     if fused:
         # -- congestion: fused single-sort cascade -------------------------- #
@@ -847,16 +954,6 @@ def _analyze_jax(
                 hosts=None if n_hosts == 1 else host,
                 n_hosts=n_hosts,
             )
-            # psd is [S_stages, H, C]: host- and class-segmented queueing delay
-            per_switch_cong = jnp.zeros((S,), f32).at[stage_arr].set(
-                psd.sum(axis=(1, 2))
-            )
-            per_class_cong = psd.sum(axis=(0, 1))
-            congestion = per_switch_cong.sum()
-            if n_hosts == 1:
-                per_host_cong = congestion[None]
-            else:
-                per_host_cong = psd.sum(axis=(0, 2))
             # the QoS cascade's fold is data-driven (always runs), so slot
             # order never matches input order
             has_merges = True
@@ -870,49 +967,18 @@ def _analyze_jax(
                 hosts=None if n_hosts == 1 else host,
                 n_hosts=n_hosts,
             )
-            if n_hosts == 1:
-                per_switch_cong = jnp.zeros((S,), f32).at[stage_arr].set(psd)
-                congestion = per_switch_cong.sum()
-                per_host_cong = congestion[None]
-            else:
-                # psd is [S_stages, H]: host-segmented per-stage queueing delay
-                per_switch_cong = jnp.zeros((S,), f32).at[stage_arr].set(
-                    psd.sum(axis=1)
-                )
-                per_host_cong = psd.sum(axis=0)
-                congestion = per_switch_cong.sum()
-            per_class_cong = congestion[None]
             has_merges = merge_plan is None or any(len(ops) for ops in merge_plan)
+        congestion, per_switch_cong, per_host_cong, per_class_cong = (
+            _congestion_leaves(psd, stage_arr, S, n_hosts, qos_on)
+        )
         if has_merges:
-            # bandwidth runs in final slot order; gather payloads through
-            # the cascade's permutation (slot k held input event slot_idx[k])
+            # gather payloads through the cascade's permutation (slot k
+            # held input event slot_idx[k])
             lat_e = per_event_lat[slot_idx]
             vp_e, nbytes_e = vp[slot_idx], nbytes[slot_idx]
             valid_e = valid[slot_idx]
-        else:
-            # no merges scheduled: slot order == input order, skip gathers
-            lat_e, vp_e, nbytes_e, valid_e = per_event_lat, vp, nbytes, valid
-        # -- bandwidth: one segment-sum over (window, vpool), then a tiny
-        #    [W, V] @ [V, S] matmul distributes virtual pools onto switches - #
-        t_obs = jnp.where(valid_e, t_fin + lat_e, 0.0)
-        win = jnp.minimum((t_obs / bw_window_ns).astype(jnp.int32), n_windows - 1)
-        win = jnp.where(valid_e, win, n_windows - 1)
-        key = win * V + vp_e
-        wp = jax.ops.segment_sum(
-            jnp.where(valid_e, nbytes_e, 0.0), key, num_segments=n_windows * V
-        ).reshape(n_windows, V)
-        if n_hosts == 1:
-            wbytes = jnp.matmul(wp, route, precision=_HI)  # [W, S]
-            wbytes_h = None
-        else:
-            wph = wp.reshape(n_windows, n_hosts, P)
-            route_h = route.reshape(n_hosts, P, S)
-            wbytes_h = jnp.einsum(
-                "whp,hps->whs", wph, route_h, precision=_HI,
-            )  # [W, H, S]
-            wbytes = wbytes_h.sum(axis=1)
     else:
-        # -- congestion: legacy per-stage argsort loop (seed baseline) ------ #
+        # -- congestion: per-stage argsort loop (beyond 31 stages) ---------- #
         per_switch_list = [jnp.zeros((), f32)] * S
         per_host_cong = jnp.zeros((n_hosts,), f32)
         for s in stage_order:
@@ -943,38 +1009,15 @@ def _analyze_jax(
         per_class_cong = congestion[None]
         if n_hosts == 1:
             per_host_cong = congestion[None]
+        t_fin = t_cur
 
-        # -- bandwidth: windowed stretch (seed formulation) ----------------- #
-        t_obs = jnp.where(valid, t_cur + per_event_lat, 0.0)
-        win = jnp.minimum((t_obs / bw_window_ns).astype(jnp.int32), n_windows - 1)
-        win = jnp.where(valid, win, n_windows - 1)
-        traversed = route[vp, :] * valid[:, None].astype(f32)  # [N, S]
-        contrib = traversed * nbytes[:, None]  # [N, S]
-        wbytes = jax.ops.segment_sum(contrib, win, num_segments=n_windows)  # [W, S]
-        if n_hosts == 1:
-            wbytes_h = None
-        else:
-            key = win * n_hosts + host
-            wbytes_h = jax.ops.segment_sum(
-                contrib, key, num_segments=n_windows * n_hosts
-            ).reshape(n_windows, n_hosts, S)
-
-    # bw <= 0 means an unconstrained component (analyze_ref skips it)
-    bw_safe = jnp.where(switch_bw > 0, switch_bw, 1.0)
-    stretch = jnp.maximum(wbytes / bw_safe[None, :] - bw_window_ns, 0.0)
-    stretch = jnp.where(switch_bw[None, :] > 0, stretch, 0.0)
-    per_switch_bw_d = stretch.sum(axis=0)
-    bandwidth = per_switch_bw_d.sum()
-    if n_hosts == 1:
-        per_host_bw = bandwidth[None]
-    else:
-        # window stretch attributed to hosts by their byte share in the window
-        denom = jnp.maximum(wbytes, jnp.asarray(1e-30, f32))
-        per_host_bw = jnp.einsum("ws,whs->h", stretch / denom, wbytes_h, precision=_HI)
-
+    bandwidth, per_switch_bw, per_host_bw = _price_bandwidth(
+        t_fin, lat_e, vp_e, nbytes_e, valid_e, bw_window_ns, route, switch_bw,
+        n_windows, n_hosts,
+    )
     return (
         latency, congestion, bandwidth,
-        per_pool_lat, per_switch_cong, per_switch_bw_d,
+        per_pool_lat, per_switch_cong, per_switch_bw,
         per_host_lat, per_host_cong, per_host_bw,
         per_class_cong,
     )
@@ -1072,63 +1115,10 @@ def _analyze_words_jax(
 
 @axes(
     "K,B,N", "K,B,N", "K,B,N", "K,B,N", "K,B,N", "K,B,N", "K,B,N",
-    "K,B", "K,B,V", "V", "V", "", "V,S", "S", "S", "S", "S,C",
-)
-def _analyze_multi_jax(
-    t: jnp.ndarray,  # [K, B, N] K sessions' stacked epoch batches
-    pool: jnp.ndarray,  # [K, B, N]
-    nbytes: jnp.ndarray,  # [K, B, N]
-    weight: jnp.ndarray,  # [K, B, N]
-    host: jnp.ndarray,  # [K, B, N]
-    qos: jnp.ndarray,  # [K, B, N]
-    valid: jnp.ndarray,  # [K, B, N]
-    bw_window_ns: jnp.ndarray,  # [K, B]
-    lat_scale: jnp.ndarray,  # [K, B, V]
-    bits_table: jnp.ndarray,  # [V] shared (same topology across sessions)
-    pool_latency_ns: jnp.ndarray,
-    local_latency_ns: jnp.ndarray,
-    route: jnp.ndarray,
-    switch_stt_ns: jnp.ndarray,
-    switch_bw: jnp.ndarray,
-    disc_code: jnp.ndarray,  # [S] shared
-    class_weights: jnp.ndarray,  # [S, C] shared
-    stage_order: Tuple[int, ...],
-    n_windows: int,
-    n_hosts: int,
-    impl: str = "inline",
-    fused: bool = True,
-    merge_plan=None,
-    qos_on: bool = False,
-):
-    """K sessions × B epochs in one dispatch — per-SESSION totals on device.
-
-    The cross-session analogue of :func:`_analyze_batch_jax`: the session
-    axis is a plain vmap over the per-batch analysis (sessions share the
-    route matrix, merge plan and numeric leaves — the same structural-
-    sharing requirement the scenario sweep's ``[K, B, N]`` stack imposes),
-    and each session's epochs are reduced on device, so the host sees one
-    ``[K, ...]`` transfer however many sessions coalesced."""
-
-    def one(t1, pool1, nbytes1, weight1, host1, qos1, valid1, bww1, scale1):
-        return _analyze_batch_jax(
-            t1, pool1, nbytes1, weight1, host1, qos1, valid1, bww1, scale1,
-            bits_table, pool_latency_ns, local_latency_ns, route,
-            switch_stt_ns, switch_bw, disc_code, class_weights,
-            stage_order=stage_order, n_windows=n_windows, n_hosts=n_hosts,
-            impl=impl, fused=fused, merge_plan=merge_plan, qos_on=qos_on,
-        )
-
-    return jax.vmap(one)(
-        t, pool, nbytes, weight, host, qos, valid, bw_window_ns, lat_scale
-    )
-
-
-@axes(
-    "K,B,N", "K,B,N", "K,B,N", "K,B,N", "K,B,N", "K,B,N", "K,B,N",
     "K,B", "K,B,V", "V", "K,V", "K", "V,S", "K,S", "K,S", "K,S", "K,S,C",
 )
-def _analyze_fleet_jax(
-    t: jnp.ndarray,  # [K, B, N] K racks' stacked epoch batches
+def _analyze_stack_jax(
+    t: jnp.ndarray,  # [K, B, N] K stacked epoch batches
     pool: jnp.ndarray,  # [K, B, N]
     nbytes: jnp.ndarray,  # [K, B, N]
     weight: jnp.ndarray,  # [K, B, N]
@@ -1137,13 +1127,13 @@ def _analyze_fleet_jax(
     valid: jnp.ndarray,  # [K, B, N]
     bw_window_ns: jnp.ndarray,  # [K, B]
     lat_scale: jnp.ndarray,  # [K, B, V]
-    bits_table: jnp.ndarray,  # [V] shared (one rack structure)
-    pool_latency_ns: jnp.ndarray,  # [K, V] per-rack numeric leaves
+    bits_table: jnp.ndarray,  # [V] shared (one fabric structure)
+    pool_latency_ns: jnp.ndarray,  # [K, V] per-row numeric leaves
     local_latency_ns: jnp.ndarray,  # [K]
     route: jnp.ndarray,  # [V, S] shared (structure)
     switch_stt_ns: jnp.ndarray,  # [K, S]
     switch_bw: jnp.ndarray,  # [K, S]
-    disc_code: jnp.ndarray,  # [K, S] per-rack QoS policies (numeric leaves)
+    disc_code: jnp.ndarray,  # [K, S] per-row QoS policies (numeric leaves)
     class_weights: jnp.ndarray,  # [K, S, C]
     stage_order: Tuple[int, ...],
     n_windows: int,
@@ -1153,17 +1143,17 @@ def _analyze_fleet_jax(
     merge_plan=None,
     qos_on: bool = False,
 ):
-    """K racks × B epochs in one dispatch, per-RACK numeric topologies.
+    """K epoch batches × B epochs in one dispatch, per-row totals on device.
 
-    The fleet-scale variant of :func:`_analyze_multi_jax`: the leading
-    axis is a rack (its merged multi-tenant timeline), and the *numeric*
-    topology leaves carry the rack axis too — racks may run different
-    expander latencies/bandwidths/STTs (:class:`~repro.core.topology.
-    FlatTopologyStack` rows) while sharing one structure, so the route
-    matrix, route-word table and cascade merge plan stay static and the
-    whole fleet compiles once.  Per-rack epoch reduction happens on
-    device; sharding the rack axis over a ('data',) mesh keeps the host
-    transfer at one ``[K, ...]`` vector.
+    The stacked form of :func:`_analyze_batch_jax`: the leading axis is a
+    coalesced engine session (:meth:`EpochAnalyzer.analyze_batch_multi`,
+    its analyzer's one row of numeric leaves broadcast to K) or a fleet
+    rack (:class:`~repro.core.fleet.FleetSim`, one row of
+    :class:`~repro.core.topology.FlatTopologyStack` leaves each).  Rows
+    share one structure, so the route matrix, route-word table and
+    cascade merge plan stay static and every K compiles once; each row's
+    epochs are reduced on device, and sharding the leading axis over a
+    ``('data',)`` mesh keeps the host transfer at one ``[K, ...]`` tuple.
     """
 
     def one(t1, pool1, nbytes1, weight1, host1, qos1, valid1, bww1, scale1,
@@ -1232,9 +1222,10 @@ def _analyze_sweep_jax(
        ``U == K`` and nothing is lost.
     2. **K scenario reductions.**  Each scenario gathers its cascade's
        slot-ordered outputs, derives per-event pools **on device** from its
-       row of the placement matrix (the cheap pool-gather), prices latency
-       against its row of the stacked topology leaves (+ cache scale), and
-       windows bandwidth on the shared post-congestion times.  Cheap
+       row of the placement matrix (the cheap pool-gather), and prices
+       each epoch's latency and bandwidth (:func:`_price_latency`,
+       :func:`_price_bandwidth`) against its row of the stacked topology
+       leaves (+ cache scale) on the shared post-congestion times.  Cheap
        elementwise/gather/segment-sum work only — no sorts, no scans.
 
     Structure — route matrix, route-word table, stage order, merge plan —
@@ -1302,91 +1293,35 @@ def _analyze_sweep_jax(
         t_fin, region_e = t_fin_u[u], region_u[u]
         nbytes_e, weight_e = nbytes_u[u], weight_u[u]
         valid_e, host_e = valid_u[u], host_u[u]
-        bwk = bw_window[g]  # [B]
 
         pool_e = jnp.where(valid_e, assign_k[region_e], 0)
         vp_e = pool_e if n_hosts == 1 else host_e * P + pool_e
 
-        # latency: pool gather + cache scale (ones => exact no-cache)
-        scale_e = jnp.take_along_axis(scale_k, vp_e, axis=1)  # [B, N]
-        per_event_lat = (
-            jnp.maximum(plat_k[vp_e] - llat_k, 0.0) * scale_e * weight_e
-        )
-        per_event_lat = jnp.where(valid_e, per_event_lat, 0.0)
-        latency = per_event_lat.sum()
-        pool_onehot = (pool_e[:, :, None] == jnp.arange(P, dtype=pool_e.dtype)).astype(f32)
-        per_pool_lat = jnp.einsum(
-            "bn,bnp->p", per_event_lat, pool_onehot, precision=_HI,
-        )
-        if n_hosts == 1:
-            per_host_lat = latency[None]
-        else:
-            host_onehot = (host_e[:, :, None] == jnp.arange(n_hosts, dtype=host_e.dtype)).astype(f32)
-            per_host_lat = jnp.einsum(
-                "bn,bnh->h", per_event_lat, host_onehot, precision=_HI,
+        # latency and bandwidth per epoch, summed over the scenario's B:
+        # windows on the shared post-congestion times plus this scenario's
+        # latency, priced against its own leaves (+ cache scale)
+        def price_epoch(t1, vp1, pool1, host1, w1, b1, v1, scale1, bww1):
+            lat1, latency1, ppl1, phl1 = _price_latency(
+                vp1, pool1, host1, w1, v1, scale1, plat_k, llat_k, n_hosts
             )
+            bw1 = _price_bandwidth(
+                t1, lat1, vp1, b1, v1, bww1, route, sbw_k, n_windows, n_hosts
+            )
+            return (latency1, ppl1, phl1) + bw1
+
+        (latency, per_pool_lat, per_host_lat,
+         bandwidth, per_switch_bw, per_host_bw) = (
+            x.sum(axis=0)
+            for x in jax.vmap(price_epoch)(
+                t_fin, vp_e, pool_e, host_e, weight_e, nbytes_e, valid_e,
+                scale_k, bw_window[g],
+            )
+        )
 
         # congestion: shared with every scenario of the same cascade
-        psd = psd_u[u]  # [B, Sst] | [B, Sst, H] | [B, Sst, H, C] (qos_on)
-        if qos_on:
-            per_switch_cong = jnp.zeros((S,), f32).at[stage_arr].set(
-                psd.sum(axis=(0, 2, 3))
-            )
-            congestion = per_switch_cong.sum()
-            per_class_cong = psd.sum(axis=(0, 1, 2))
-            per_host_cong = (
-                congestion[None] if n_hosts == 1 else psd.sum(axis=(0, 1, 3))
-            )
-        elif n_hosts == 1:
-            per_switch_cong = jnp.zeros((S,), f32).at[stage_arr].set(psd.sum(axis=0))
-            congestion = per_switch_cong.sum()
-            per_host_cong = congestion[None]
-            per_class_cong = congestion[None]
-        else:
-            per_switch_cong = jnp.zeros((S,), f32).at[stage_arr].set(
-                psd.sum(axis=(0, 2))
-            )
-            per_host_cong = psd.sum(axis=(0, 1))
-            congestion = per_switch_cong.sum()
-            per_class_cong = congestion[None]
-
-        # bandwidth: windows on the shared post-congestion times + this
-        # scenario's latency component, one segment-sum per scenario
-        t_obs = jnp.where(valid_e, t_fin + per_event_lat, 0.0)
-        win = jnp.minimum((t_obs / bwk[:, None]).astype(jnp.int32), n_windows - 1)
-        win = jnp.where(valid_e, win, n_windows - 1)
-        B = t_obs.shape[0]
-        b_ix = jnp.arange(B, dtype=jnp.int32)[:, None]
-        key = (b_ix * n_windows + win) * V + vp_e
-        wp = jax.ops.segment_sum(
-            jnp.where(valid_e, nbytes_e, 0.0).reshape(-1),
-            key.reshape(-1),
-            num_segments=B * n_windows * V,
-        ).reshape(B, n_windows, V)
-        if n_hosts == 1:
-            wbytes = jnp.matmul(wp, route, precision=_HI)  # [B, W, S]
-            wbytes_h = None
-        else:
-            wph = wp.reshape(B, n_windows, n_hosts, P)
-            route_h = route.reshape(n_hosts, P, S)
-            wbytes_h = jnp.einsum("bwhp,hps->bwhs", wph, route_h, precision=_HI)
-            wbytes = wbytes_h.sum(axis=2)
-        # bw <= 0 means an unconstrained component (analyze_ref skips it);
-        # unguarded 0/0 windows would poison totals with NaN
-        sbw_safe = jnp.where(sbw_k > 0, sbw_k, 1.0)
-        stretch = jnp.maximum(
-            wbytes / sbw_safe[None, None, :] - bwk[:, None, None], 0.0
+        congestion, per_switch_cong, per_host_cong, per_class_cong = (
+            _congestion_leaves(psd_u[u].sum(axis=0), stage_arr, S, n_hosts, qos_on)
         )
-        stretch = jnp.where(sbw_k[None, None, :] > 0, stretch, 0.0)
-        per_switch_bw = stretch.sum(axis=(0, 1))
-        bandwidth = per_switch_bw.sum()
-        if n_hosts == 1:
-            per_host_bw = bandwidth[None]
-        else:
-            denom = jnp.maximum(wbytes, jnp.asarray(1e-30, f32))
-            per_host_bw = jnp.einsum(
-                "bws,bwhs->h", stretch / denom, wbytes_h, precision=_HI,
-            )
 
         return (
             latency, congestion, bandwidth,
@@ -1425,23 +1360,11 @@ class PendingBatch:
         # chain dispatch's trailing plane stays on device and is simply
         # dropped
         host, wait_s, d2h_s = collect_dispatch(self.out[:10])
-        lat, cong, bw, ppl, psc, psb, phl, phc, phb, pcc = host
         stats = dataclasses.replace(self.stats, wait_s=wait_s, d2h_s=d2h_s)
         a.last_dispatch = stats
         self.stats = stats
         self.out = None
-        return DelayBreakdown(
-            float(lat),
-            float(cong),
-            float(bw),
-            ppl.astype(np.float64),
-            psc.astype(np.float64),
-            psb.astype(np.float64),
-            phl.astype(np.float64),
-            phc.astype(np.float64),
-            phb.astype(np.float64),
-            pcc.astype(np.float64),
-        )
+        return DelayBreakdown.from_leaves(host)
 
 
 class EpochAnalyzer:
@@ -1454,8 +1377,10 @@ class EpochAnalyzer:
     :meth:`analyze_batch` stacks B bucketed epochs into ``[B, N]`` arrays and
     runs a single jitted, vmapped dispatch whose per-epoch breakdowns are
     summed **on device** — one host round-trip per batch instead of one per
-    epoch.  :meth:`analyze` is the B=1 special case.  See the module
-    docstring for the pipeline stages and the ``impl`` / ``fused`` knobs.
+    epoch.  :meth:`analyze` is the B=1 special case, and
+    :meth:`analyze_batch_multi` stacks K such batches in one dispatch.
+    See the module docstring for the surfaces and the ``impl`` / ``fused``
+    knobs.
     """
 
     def __init__(
@@ -1497,7 +1422,7 @@ class EpochAnalyzer:
         if self.fused and flat.n_switches > 31:
             # the fused cascade encodes one stage per switch (incl. per-host
             # RCs) in a 31-bit route word; very wide fabrics fall back to
-            # the legacy per-stage loop — slower, but any host count works
+            # the per-stage argsort loop — slower, but any host count works
             self.fused = False
         if self.qos_on and not self.fused:
             raise ValueError(
@@ -1519,7 +1444,7 @@ class EpochAnalyzer:
         self._batch_fn = jax.jit(
             _analyze_words_jax, static_argnames=_static + ("layout",)
         )
-        self._multi_fn = jax.jit(_analyze_multi_jax, static_argnames=_static)
+        self._stack_fn = jax.jit(_analyze_stack_jax, static_argnames=_static)
         self.pipeline = bool(pipeline)
         self._chain_plan: Optional[ChainPlan] = None
         self._aot: Optional[AotDispatchCache] = None
@@ -1791,19 +1716,19 @@ class EpochAnalyzer:
         The multi-session stacked entry point the shared engine coalesces
         through: ``groups[k]`` is session k's epoch list and comes back as
         its own :class:`DelayBreakdown`, all from a single ``[K, B, N]``
-        jitted dispatch (sessions vmapped over the per-batch analysis, the
-        same stacking discipline as the scenario sweep — shapes bucketed by
-        :func:`bucket_pow2` on every axis so repeated coalescings reuse the
-        compile cache).  Every session must share this analyzer's topology
-        and window config (the engine's dispatch key guarantees it).
+        dispatch (:meth:`_dispatch_stack`, with this analyzer's numeric
+        leaves broadcast to K).  Every session must share this analyzer's
+        topology and window config (the engine's dispatch key guarantees
+        it).  Sessions with no events come back zero; with one live
+        session this is :meth:`analyze_batch`.
 
         ``mesh`` (defaulting to the analyzer's own) shards the session axis
         with ``NamedSharding`` over ``('data',)``: the K leading axis is
         padded to a multiple of the device count so shards stay uniform,
-        stacked inputs are placed pre-sharded, the topology leaves
-        replicate, and per-shard epoch reduction still happens on device —
-        the host transfer stays one ``[K, ...]`` vector regardless of how
-        many devices participate.  With one device (or K == 1) the path is
+        stacked inputs are placed pre-sharded, the structure replicates,
+        and per-shard epoch reduction still happens on device — the host
+        transfer stays one ``[K, ...]`` vector regardless of how many
+        devices participate.  With one device (or K == 1) the path is
         bitwise identical to the unsharded dispatch.
 
         Restricted to ``impl='inline'``: the session axis vmaps the fused
@@ -1840,73 +1765,103 @@ class EpochAnalyzer:
                 stager=stager,
             )
             return out
+        # the one row of numeric leaves, broadcast to the K sessions
+        leaves = tuple(
+            np.broadcast_to(np.asarray(a), (len(rows),) + a.shape)
+            for a in (
+                self._pool_lat, self._local_lat, self._stt, self._bw,
+                self._disc, self._weights,
+            )
+        )
+        bds = self._dispatch_stack(
+            [[tr for tr, _ in cleaned[i]] for i in rows],
+            leaves,
+            scale_groups=[[sc for _, sc in cleaned[i]] for i in rows],
+            stager=stager,
+            mesh=mesh,
+        )
+        for i, bd in zip(rows, bds):
+            out[i] = bd
+        return out
+
+    def _dispatch_stack(
+        self,
+        groups: Sequence[Sequence[MemEvents]],
+        leaves: Sequence[np.ndarray],
+        scale_groups: Optional[Sequence[Sequence[Optional[np.ndarray]]]] = None,
+        stager: Optional[EventStager] = None,
+        mesh=None,
+        qos_on: Optional[bool] = None,
+    ) -> List[DelayBreakdown]:
+        """ONE ``[K, B, N]`` dispatch of K epoch batches on this analyzer's
+        fabric structure (:func:`_analyze_stack_jax`) → K breakdowns; the
+        dispatch's :class:`DispatchStats` lands in :attr:`last_dispatch`.
+
+        ``leaves`` are the numeric topology leaves with one row per batch:
+        ``pool_latency_ns [K, V]``, ``local_latency_ns [K]``,
+        ``switch_stt_ns [K, S]``, ``switch_bw [K, S]``, discipline codes
+        ``[K, S]`` (int32) and class weights ``[K, S, C]``, floats in the
+        analyzer's dtype.  ``scale_groups`` pairs each epoch with a device-
+        cache latency scale (None: ones); ``qos_on`` overrides the
+        analyzer's own (a fleet arbitrates by its racks' policies).  Every
+        axis is bucketed by :func:`bucket_pow2` and K is then padded to
+        the mesh's shard count; padded rows are all-invalid planes with
+        row 0's leaves."""
         from repro.distributed.sharding import (
             pad_to_multiple, replicated, resolve_data_mesh, shard_rows,
         )
 
+        K = len(groups)
         mesh, n_shards = resolve_data_mesh(
-            mesh if mesh is not None else self.mesh,
-            len(rows),
-            what="coalesced session dispatch",
+            mesh if mesh is not None else self.mesh, K, what="stacked dispatch"
         )
         with spans.span("cxlsim.stage") as stage:
-            n_bucket = self._bucket(
-                max(tr.n for i in rows for tr, _ in cleaned[i])
-            )
-            b_bucket = self._bucket(max(len(cleaned[i]) for i in rows), floor=1)
-            k_bucket = pad_to_multiple(self._bucket(len(rows), floor=1), n_shards)
+            n_bucket = self._bucket(max((tr.n for g in groups for tr in g), default=0))
+            b_bucket = self._bucket(max(len(g) for g in groups), floor=1)
+            k_bucket = pad_to_multiple(self._bucket(K, floor=1), n_shards)
             st = stager if stager is not None else self._stager
-            buf = st.stage_stack(
-                [[tr for tr, _ in cleaned[i]] for i in rows],
-                k_bucket, b_bucket, n_bucket,
-            )
+            buf = st.stage_stack(groups, k_bucket, b_bucket, n_bucket)
             np_dtype = np.dtype(jnp.dtype(self.dtype).name)
-            scale_buf = np.ones((k_bucket, b_bucket, H * P), np_dtype)
-            for k, i in enumerate(rows):
-                for row, (_, sc) in enumerate(cleaned[i]):
+            scale = np.ones((k_bucket, b_bucket, self.flat.route.shape[0]), np_dtype)
+            for k, scales in enumerate(scale_groups or ()):
+                for row, sc in enumerate(scales):
                     if sc is not None:
-                        scale_buf[k, row] = sc
+                        scale[k, row] = sc
             span = np.maximum(buf["span"], self.bw_window_ns)
             bw_window = np.maximum(span / self.n_windows, 1.0).astype(np_dtype)
+            pad = np.where(np.arange(k_bucket) < K, np.arange(k_bucket), 0)
         if mesh is not None:
             self.sharded_dispatches += 1
         put_k = lambda a: shard_rows(mesh, jnp.asarray(a))
-        put_r = lambda a: replicated(mesh, a)
         with spans.span("cxlsim.h2d") as h2d:
-            dev_k = [
+            planes = [
                 put_k(buf[f])
                 for f in ("t", "pool", "bytes", "weight", "host", "qos", "valid")
-            ] + [put_k(bw_window), put_k(scale_buf)]
-            dev_r = [
-                put_r(a)
-                for a in (
-                    self._bits_table, self._pool_lat, self._local_lat,
-                    self._route, self._stt, self._bw, self._disc, self._weights,
-                )
             ]
+            plat, llat, stt, sbw, disc, cw = (put_k(a[pad]) for a in leaves)
+            bits, route = (replicated(mesh, a) for a in (self._bits_table, self._route))
         res, enqueue_s, compile_s = enqueue_dispatch(
-            self._multi_fn,
-            *dev_k,
-            *dev_r,
+            self._stack_fn,
+            *planes, put_k(bw_window), put_k(scale),
+            bits, plat, llat, route, stt, sbw, disc, cw,
             stage_order=self._stage_order,
             n_windows=self.n_windows,
-            n_hosts=H,
+            n_hosts=self.flat.n_hosts,
             impl=self.impl,
             fused=self.fused,
             merge_plan=self._merge_plan,
-            qos_on=self.qos_on,
+            qos_on=self.qos_on if qos_on is None else qos_on,
         )
-        # one [K, ...] transfer for every coalesced session
+        # one [K, ...] transfer for every stacked batch
         host, wait_s, d2h_s = collect_dispatch(res)
-        lat, cong, bw, ppl, psc, psb, phl, phc, phb, pcc = host
         slots = k_bucket * b_bucket * n_bucket
-        events = sum(tr.n for i in rows for tr, _ in cleaned[i])
+        events = sum(tr.n for g in groups for tr in g)
         count_dispatch(slots, events)
         self.last_dispatch = DispatchStats(
             devices_used=n_shards,
             shard_rows=k_bucket // n_shards if mesh is not None else 0,
-            rows=len(rows),
-            padded_fraction=float(k_bucket - len(rows)) / k_bucket,
+            rows=K,
+            padded_fraction=float(k_bucket - K) / k_bucket,
             stage_s=stage.seconds,
             transfer_s=h2d.seconds,
             compile_s=compile_s,
@@ -1915,22 +1870,9 @@ class EpochAnalyzer:
             d2h_s=d2h_s,
             slots=slots,
             events=events,
-            qos_classes=self.flat.n_qos_classes,
+            qos_classes=leaves[-1].shape[-1],
         )
-        for k, i in enumerate(rows):
-            out[i] = DelayBreakdown(
-                float(lat[k]),
-                float(cong[k]),
-                float(bw[k]),
-                ppl[k].astype(np.float64),
-                psc[k].astype(np.float64),
-                psb[k].astype(np.float64),
-                phl[k].astype(np.float64),
-                phc[k].astype(np.float64),
-                phb[k].astype(np.float64),
-                pcc[k].astype(np.float64),
-            )
-        return out
+        return [DelayBreakdown.from_leaves(host, k) for k in range(K)]
 
 
 def analyze_any(

@@ -24,9 +24,11 @@ has:
     are a region→pool gather; per-host epoch timelines merge onto the
     rack's fabric clock exactly like
     :class:`~repro.core.fabric.FabricSession`'s merged rounds;
-  * the R racks stack into ONE ``[R, B, N]`` jitted dispatch
-    (:func:`~repro.core.analyzer._analyze_fleet_jax`) whose leading axis
-    shards across JAX devices over a ``('data',)`` mesh
+  * the R racks stack into ONE ``[R, B, N]`` jitted dispatch — the
+    engine's coalesced-session dispatch
+    (:func:`~repro.core.analyzer._analyze_stack_jax`) with one row of
+    numeric leaves per rack — whose leading axis shards across JAX
+    devices over a ``('data',)`` mesh
     (:func:`~repro.launch.mesh.make_data_mesh`), with per-rack epoch
     reduction on device — one ``[R, ...]`` host transfer for the whole
     fleet, however many devices participate.
@@ -50,22 +52,11 @@ from __future__ import annotations
 import dataclasses
 from typing import Dict, List, Optional, Sequence, Tuple
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import spans
-from .analyzer import (
-    DelayBreakdown,
-    DispatchStats,
-    _analyze_fleet_jax,
-    bucket_pow2,
-    collect_dispatch,
-    count_dispatch,
-    enqueue_dispatch,
-    plan_cascade,
-)
-from .events import EventStager, MemEvents, RegionMap, concat_events
+from .analyzer import DelayBreakdown, DispatchStats, EpochAnalyzer
+from .events import MemEvents, RegionMap, concat_events
 from .topology import (
     QosSpec,
     Topology,
@@ -340,9 +331,8 @@ class FleetSim:
             self.topology.pools[self.shared_pool].capacity_bytes
         )
 
-        bits_pool, self._merge_plan, self._stage_order = plan_cascade(flat)
-        self._bits_table = jnp.asarray(bits_pool)
-        self._route = jnp.asarray(flat.route, dtype)
+        # the stacked dispatch over the shared rack structure
+        self._analyzer = EpochAnalyzer(flat, self.bw_window_ns, self.n_windows, dtype)
         # numeric leaves, one row per rack (structure shared by construction)
         self._leaf_stack = flatten_stack(self.topology, self.rack_overrides)
         # per-rack QoS arbitration policies: disciplines and class weights
@@ -369,14 +359,6 @@ class FleetSim:
         self.qos_on = bool(flat.has_qos) or bool(
             rack_qos is not None and any(s is not None for s in rack_qos)
         )
-        self._fleet_jit = jax.jit(
-            _analyze_fleet_jax,
-            static_argnames=(
-                "stage_order", "n_windows", "n_hosts", "impl", "fused",
-                "merge_plan", "qos_on",
-            ),
-        )
-        self._stager = EventStager(self._np_dtype)
         self._skeletons: Dict[str, TraceSkeleton] = {}
         self.dispatch_count = 0
         self.last_dispatch = DispatchStats()
@@ -550,107 +532,28 @@ class FleetSim:
         self, rack_traces: List[List[MemEvents]], tiles: int, mesh
     ) -> List[DelayBreakdown]:
         """ONE ``[K, B, N]`` fleet dispatch (K = tiles × n_racks)."""
-        from repro.distributed.sharding import (
-            pad_to_multiple, replicated, resolve_data_mesh, shard_rows,
-        )
-
-        flat = self.flat
-        P, S, H = flat.n_pools, flat.n_switches, flat.n_hosts
-        V = H * P
-        K = len(rack_traces)
-        assert K == tiles * self.n_racks
-        mesh, n_shards = resolve_data_mesh(
-            mesh if mesh is not None else self.mesh, K, what="fleet dispatch"
-        )
-        n_max = max((tr.n for rows in rack_traces for tr in rows), default=1)
-        B = max(len(rows) for rows in rack_traces)
-        n_bucket = bucket_pow2(max(n_max, 1))
-        b_bucket = bucket_pow2(B, floor=1)
-        k_bucket = pad_to_multiple(bucket_pow2(K, floor=1), n_shards)
-        with spans.span("cxlsim.stage") as stage:
-            buf = self._stager.stage_stack(rack_traces, k_bucket, b_bucket, n_bucket)
-            span = np.maximum(buf["span"], self.bw_window_ns)
-            bw_window = np.maximum(span / self.n_windows, 1.0)
-            scale = np.ones((k_bucket, b_bucket, V), self._np_dtype)
-
+        assert len(rack_traces) == tiles * self.n_racks
         ls = self._leaf_stack
-
-        def pad_k(a: np.ndarray) -> np.ndarray:
-            tiled = np.concatenate([a] * tiles, axis=0) if tiles > 1 else a
-            if k_bucket == tiled.shape[0]:
-                return tiled
-            return np.concatenate(
-                [tiled, np.repeat(tiled[:1], k_bucket - tiled.shape[0], axis=0)],
-                axis=0,
+        leaves = tuple(
+            np.tile(np.asarray(a, dt), (tiles,) + (1,) * (np.ndim(a) - 1))
+            for a, dt in (
+                (ls.pool_latency_ns, self._np_dtype),
+                (ls.local_latency_ns, self._np_dtype),
+                (ls.switch_stt_ns, self._np_dtype),
+                (ls.switch_bandwidth_gbps, self._np_dtype),
+                (self._disc_stack, np.int32),
+                (self._weights_stack, self._np_dtype),
             )
-
+        )
         self.dispatch_count += 1
-        put_k = lambda a: shard_rows(mesh, jnp.asarray(a))
-        put_r = lambda a: replicated(mesh, a)
-        with spans.span("cxlsim.h2d") as h2d:
-            dev_args = (
-                put_k(buf["t"]),
-                put_k(buf["pool"]),
-                put_k(buf["bytes"]),
-                put_k(buf["weight"]),
-                put_k(buf["host"]),
-                put_k(buf["qos"]),
-                put_k(buf["valid"]),
-                put_k(jnp.asarray(bw_window, self.dtype)),
-                put_k(scale),
-                put_r(self._bits_table),
-                put_k(pad_k(np.asarray(ls.pool_latency_ns, self._np_dtype))),
-                put_k(pad_k(np.asarray(ls.local_latency_ns, self._np_dtype))),
-                put_r(self._route),
-                put_k(pad_k(np.asarray(ls.switch_stt_ns, self._np_dtype))),
-                put_k(pad_k(np.asarray(ls.switch_bandwidth_gbps, self._np_dtype))),
-                put_k(pad_k(self._disc_stack)),
-                put_k(pad_k(self._weights_stack)),
-            )
-        out, enqueue_s, compile_s = enqueue_dispatch(
-            self._fleet_jit,
-            *dev_args,
-            stage_order=self._stage_order,
-            n_windows=self.n_windows,
-            n_hosts=H,
-            impl="inline",
-            fused=True,
-            merge_plan=self._merge_plan,
+        bds = self._analyzer._dispatch_stack(
+            rack_traces,
+            leaves,
+            mesh=mesh if mesh is not None else self.mesh,
             qos_on=self.qos_on,
         )
-        host, wait_s, d2h_s = collect_dispatch(out)
-        lat, cong, bw, ppl, psc, psb, phl, phc, phb, pcc = host
-        slots = k_bucket * b_bucket * n_bucket
-        events = sum(tr.n for rows in rack_traces for tr in rows)
-        count_dispatch(slots, events)
-        self.last_dispatch = DispatchStats(
-            devices_used=n_shards,
-            shard_rows=k_bucket // n_shards if mesh is not None else 0,
-            rows=K,
-            padded_fraction=float(k_bucket - K) / k_bucket,
-            stage_s=stage.seconds,
-            transfer_s=h2d.seconds,
-            compile_s=compile_s,
-            enqueue_s=enqueue_s,
-            wait_s=wait_s,
-            d2h_s=d2h_s,
-            slots=slots,
-            events=events,
-            qos_classes=self.n_qos_classes,
-        )
-        return [
-            DelayBreakdown(
-                float(lat[k]), float(cong[k]), float(bw[k]),
-                ppl[k].astype(np.float64),
-                psc[k].astype(np.float64),
-                psb[k].astype(np.float64),
-                phl[k].astype(np.float64),
-                phc[k].astype(np.float64),
-                phb[k].astype(np.float64),
-                pcc[k].astype(np.float64),
-            )
-            for k in range(K)
-        ]
+        self.last_dispatch = self._analyzer.last_dispatch
+        return bds
 
     def _report_from(
         self,

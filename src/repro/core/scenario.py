@@ -631,7 +631,6 @@ class ScenarioSuite:
             qos_on=qos_on,
         )
         host, wait_s, d2h_s = collect_dispatch(out)
-        lat, cong, bw, ppl, psc, psb, phl, phc, phb, pcc = host
         # every scenario row prices its granularity group's [B, N] plane
         valid = host_r[4]
         slots = Kp * int(np.prod(valid.shape[1:]))
@@ -652,19 +651,7 @@ class ScenarioSuite:
             events=events,
             qos_classes=C,
         )
-        breakdowns = [
-            DelayBreakdown(
-                float(lat[k]), float(cong[k]), float(bw[k]),
-                ppl[k].astype(np.float64),
-                psc[k].astype(np.float64),
-                psb[k].astype(np.float64),
-                phl[k].astype(np.float64),
-                phc[k].astype(np.float64),
-                phb[k].astype(np.float64),
-                pcc[k].astype(np.float64),
-            )
-            for k in range(K)
-        ]
+        breakdowns = [DelayBreakdown.from_leaves(host, k) for k in range(K)]
         native = float(sum(skels[0].native_ns))
         return SweepResult(
             scenarios=scenarios,

@@ -20,7 +20,12 @@ from repro.core.events import synthetic_trace
 from repro.core.fleet import FleetSim, synthetic_tenant
 from repro.core.policy import ClassMapPolicy, InterleavePolicy
 from repro.core.scenario import Scenario, ScenarioSuite
-from repro.core.topology import TopologyOverride, figure1_topology, pooled_topology
+from repro.core.topology import (
+    TopologyOverride,
+    figure1_topology,
+    flatten_stack,
+    pooled_topology,
+)
 from repro.distributed.sharding import (
     pad_to_multiple,
     resolve_data_mesh,
@@ -133,6 +138,55 @@ def test_analyze_batch_multi_fewer_rows_than_devices_warns_and_matches(data_mesh
     for x, y in zip(a, b):
         assert x.latency_ns == y.latency_ns
     assert sharded.last_dispatch.devices_used == 5
+
+
+_LEAVES = (
+    "latency_ns", "congestion_ns", "bandwidth_ns", "per_pool_latency_ns",
+    "per_switch_congestion_ns", "per_switch_bandwidth_ns",
+    "per_host_latency_ns", "per_host_congestion_ns", "per_host_bandwidth_ns",
+    "per_class_congestion_ns",
+)
+
+
+@pytest.mark.parametrize("case", ["shared_leaves", "per_rack_overrides"])
+def test_stacked_dispatch_rows_match_analyze_batch(case):
+    """The one stacked [K, B, N] dispatch serves both callers: the engine's
+    coalesced sessions (the analyzer's one row of leaves broadcast to K)
+    and the fleet's racks (one overridden row each).  Every row matches
+    analyze_batch on that row's own topology."""
+    topo = pooled_topology(n_hosts=2)
+    flat = topo.flatten()
+    groups = _session_groups(flat, 3, b=3, n=300)
+    groups[1] = groups[1][:2]  # ragged epoch counts
+    if case == "shared_leaves":
+        an = EpochAnalyzer(flat, n_windows=64)
+        got = an.analyze_batch_multi(groups)
+        flats = [flat] * 3
+        stats = an.last_dispatch
+    else:
+        overrides = [
+            None,
+            TopologyOverride(pools={"shared_pool": {"latency_ns": 400.0}}),
+            TopologyOverride(
+                switches={"fabric_sw": {"stt_ns": 9.0, "bandwidth_gbps": 4.0}}
+            ),
+        ]
+        fleet = FleetSim(n_racks=3, rack_topology=topo, rack_overrides=overrides)
+        got = fleet._dispatch(groups, tiles=1, mesh=None)
+        stack = flatten_stack(topo, overrides)
+        flats = [stack.member(k) for k in range(3)]
+        stats = fleet.last_dispatch
+    assert (stats.rows, stats.padded_fraction) == (3, 0.25)
+    assert stats.slots == 4 * 4 * 512
+    for bd, g, f in zip(got, groups, flats):
+        ref = EpochAnalyzer(f, n_windows=64).analyze_batch(g)
+        for leaf in _LEAVES:
+            np.testing.assert_allclose(
+                getattr(bd, leaf), getattr(ref, leaf), rtol=1e-6, err_msg=leaf
+            )
+    if case == "per_rack_overrides":  # the overrides reach their own rows
+        assert got[1].latency_ns > got[0].latency_ns
+        assert got[2].congestion_ns > got[0].congestion_ns
 
 
 # --------------------------------------------------------------------------- #

@@ -13,6 +13,7 @@ from repro.core import (
     ClassMapPolicy,
     DeviceCacheConfig,
     DeviceCacheModel,
+    EpochAnalyzer,
     HotnessTieredPolicy,
     InterleavePolicy,
     LocalOnlyPolicy,
@@ -384,6 +385,29 @@ def test_sweep_matches_sequential_analyze_ref(epoch_mode):
         np.testing.assert_allclose(
             got.per_pool_latency_ns, ref.per_pool_latency_ns, rtol=1e-4, atol=1.0
         )
+
+
+def test_chain_full_plane_and_sweep_price_latency_alike():
+    """One chain-eligible workload that all three graphs take — the chain
+    dispatch, the full-plane word-plane dispatch and the scenario sweep —
+    gives the same latency leaves: the pricing lives in one helper."""
+    rm, phases = _workload(seed=5)
+    suite = ScenarioSuite(figure1_topology(), rm, phases, epoch_mode="layer")
+    pol = ClassMapPolicy({"opt_state": "cxl_pool2", "kvcache": "cxl_pool1"})
+    swept = suite.run([Scenario(policy=pol, name="off")]).breakdowns[0]
+    pol.place(rm, suite.base_flat)
+    traces, _, _ = synthesize_step_trace(
+        phases, rm, granularity_bytes=pol.granularity_bytes, epoch_mode="layer"
+    )
+    chain = EpochAnalyzer(suite.base_flat, n_windows=suite.n_windows, pipeline=True)
+    plane = EpochAnalyzer(suite.base_flat, n_windows=suite.n_windows)
+    assert chain._chain_plan is not None and plane._chain_plan is None
+    assert swept.latency_ns > 0
+    for bd in (chain.analyze_batch(traces), plane.analyze_batch(traces)):
+        for leaf in ("latency_ns", "per_pool_latency_ns", "per_host_latency_ns"):
+            np.testing.assert_allclose(
+                getattr(bd, leaf), getattr(swept, leaf), rtol=1e-6, err_msg=leaf
+            )
 
 
 def test_sweep_reuses_compile_cache_across_runs():

@@ -4,6 +4,7 @@ dispatches (including a real ``[K, B, N]`` analyzer surface), and the
 bitwise-neutrality guarantees of the annotation layer and the
 ``repro.core.units`` helpers.
 """
+import dataclasses
 import inspect
 from pathlib import Path
 
@@ -13,6 +14,7 @@ import numpy as np
 import pytest
 
 from repro.analysis import registered_checkers, run_checks
+from repro.analysis.framework import CheckConfig
 from repro.analysis.annotations import (
     AxisContractError,
     axes,
@@ -26,12 +28,21 @@ REPO = Path(__file__).resolve().parent.parent
 FIXTURES = REPO / "tests" / "fixtures" / "simlint"
 
 
+# the axes corpus names its own dispatch surface (a standalone stand-in,
+# not one of the repo's): the fixtures check the rule, not the repo's list
+FIXTURE_CONFIG = dataclasses.replace(
+    CheckConfig(),
+    axes_required=CheckConfig().axes_required + ("_analyze_multi_jax",),
+)
+
+
 def _check(*names, checkers=None, strict=False):
     return run_checks(
         [FIXTURES / n for n in names],
         root=FIXTURES,
         strict=strict,
         checker_names=checkers,
+        config=FIXTURE_CONFIG,
     )
 
 
@@ -239,8 +250,8 @@ def test_sanitizer_innermost_scope_wins():
 
 def test_sanitizer_detects_transposed_real_analyzer_dispatch():
     """The acceptance scenario: a [K, B, N] plane fed as [B, K, N] into the
-    real multi-session surface trips the contract before any compute."""
-    from repro.core.analyzer import _analyze_multi_jax
+    real stacked surface trips the contract before any compute."""
+    from repro.core.analyzer import _analyze_stack_jax
 
     K, B, N, V, S, C = 2, 3, 4, 2, 2, 1
     f32 = jnp.float32
@@ -257,24 +268,24 @@ def test_sanitizer_detects_transposed_real_analyzer_dispatch():
         bw_window_ns=jnp.full((K, B), 1e6, f32),
         lat_scale=jnp.ones((K, B, V), f32),
         bits_table=jnp.zeros((V,), i32),
-        pool_latency_ns=jnp.ones((V,), f32),
-        local_latency_ns=jnp.float32(100.0),
+        pool_latency_ns=jnp.ones((K, V), f32),
+        local_latency_ns=jnp.full((K,), 100.0, f32),
         route=jnp.zeros((V, S), f32),
-        switch_stt_ns=jnp.ones((S,), f32),
-        switch_bw=jnp.ones((S,), f32),
-        disc_code=jnp.zeros((S,), i32),
-        class_weights=jnp.ones((S, C), f32),
+        switch_stt_ns=jnp.ones((K, S), f32),
+        switch_bw=jnp.ones((K, S), f32),
+        disc_code=jnp.zeros((K, S), i32),
+        class_weights=jnp.ones((K, S, C), f32),
         stage_order=(0, 1),
         n_windows=1,
         n_hosts=1,
     )
     with AxisSanitizer(record_only=True) as san:
         try:
-            _analyze_multi_jax(**kwargs)
+            _analyze_stack_jax(**kwargs)
         except Exception:
             pass  # downstream shape errors are expected; the record matters
     assert san.violations, "transposed [B,K,N] dispatch went undetected"
-    assert "_analyze_multi_jax" in san.violations[0]
+    assert "_analyze_stack_jax" in san.violations[0]
 
 
 # --------------------------------------------------------------------------- #

@@ -171,6 +171,25 @@ def test_batch_dispatch_qos_fabric_pallas_compiles(sds):
     )
 
 
+def test_stack_dispatch_compiles(sds):
+    """The one stacked [K, B, N] surface that coalesced engine sessions and
+    fleet racks share, its numeric leaves one row per K, on a 4-host pool."""
+    flat = pooled_topology(n_hosts=4).flatten()
+    an = EpochAnalyzer(flat)
+    K, V, (S, C) = 4, flat.route.shape[0], an._weights.shape
+    plane = [sds((K, B, N), t) for t in (F32, I32, F32, F32, I32, I32, jnp.bool_)]
+    args = plane + [sds((K, B), F32), sds((K, B, V), F32), sds((V,), I32)]
+    args += [
+        sds((K, V), F32), sds((K,), F32), sds(an._route.shape, F32),
+        sds((K, S), F32), sds((K, S), F32), sds((K, S), I32), sds((K, S, C), F32),
+    ]
+    _compile(
+        an._stack_fn, *args, stage_order=an._stage_order, n_windows=an.n_windows,
+        n_hosts=flat.n_hosts, impl="inline", fused=True, merge_plan=an._merge_plan,
+        qos_on=False,
+    )
+
+
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
 def test_flash_attention_compiles(sds, dtype):
     q = sds((1, 16, 1024, 128), dtype)
